@@ -32,6 +32,8 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default=None,
                     help="also write the CSV summary to this file")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     quick = os.environ.get("REPRO_BENCH_QUICK", "0") == "1"
     only = os.environ.get("REPRO_BENCH_ONLY", "")

@@ -1,10 +1,10 @@
 """Bounded-exhaustive model checker for the fused-pipeline DMA schedule.
 
-``kernels/sgns_fused_pipe.kernel_schedule`` is the single source of
-truth both pipelined kernels (``pallas_fused_pipe`` and
-``pallas_fused_tiered``) execute: an unrolled sequence of
-``(op, block, slot, guard)`` events whose guards are resolved against
-the planner's hazard flags. Its safety argument — matched start/wait
+``kernels/sgns_fused_pipe.kernel_schedule`` is the static expansion of
+the per-position template (``position_template``) that both pipelined
+kernels (``pallas_fused_pipe`` and ``pallas_fused_tiered``) loop over:
+an unrolled sequence of ``(op, block, slot, guard)`` events whose
+guards are resolved against the planner's hazard flags. Its safety argument — matched start/wait
 pairs under every hazard outcome, slot-recycling waits serializing
 buffer reuse, a ``ring_depth - 1`` look-behind window sufficing for
 chain fidelity — was previously exercised by hand-picked hazard

@@ -3,8 +3,11 @@
 Every Pallas engine config implies a VMEM-resident working set the
 Mosaic compiler will demand at lowering time: scratch rings, pinned
 table prefixes, whole resident tables, plan operands. On CI (interpret
-mode) an over-budget config runs fine and only fails weeks later on
-real TPU hardware — the ROADMAP's open Mosaic item. This pass computes
+mode) an over-budget config runs fine and only fails when compiled for
+a TPU. For the pipe and tiered engines the terms are the kernel's own
+scratch sizes (``sgns_fused_pipe.vmem_terms``) at the chip's lane-padded
+row width; ``tests/test_tpu_compile.py`` compiles them for a described
+v5e, which checks the real allocation. This pass computes
 the footprint *statically* from the engine dials
 ``(block_pairs, ring_depth, hot_rows)`` and the model shape
 ``(V, d, K, B)``, so bad configs are rejected at plan time:
@@ -63,10 +66,6 @@ class VmemEstimate:
                 f"({parts or 'no VMEM-resident requirement'})")
 
 
-def _nblocks(B: int, blk: int) -> int:
-    return -(-B // blk)
-
-
 def estimate_vmem(engine, *, vocab_size: int, dim: int, negatives: int,
                   batch: int) -> VmemEstimate:
     """Static VMEM footprint of one step of ``engine`` at this shape.
@@ -107,19 +106,14 @@ def estimate_vmem(engine, *, vocab_size: int, dim: int, negatives: int,
         terms["row_scratch"] = (blk * (K + 2) + 1) * d * F32
         terms["noise_tables"] = 2 * V * F32
     elif name in ("pallas_fused_pipe", "pallas_fused_tiered"):
+        # the kernel's own scratch sizes, at the chip's lane-padded row
+        # width (its per-block plan metadata lives in HBM and SMEM)
+        from repro.kernels.sgns_fused_pipe import row_width, vmem_terms
         blk = _pick_block_pairs(B, eng.block_pairs)
-        S = eng.ring_depth
-        nb = _nblocks(B, blk)
-        shape.update(block_pairs=blk, ring_depth=S)
-        terms["ring_w"] = S * blk * d * F32
-        terms["ring_c"] = S * blk * (K + 1) * d * F32
-        # VMEM plan operands: uw, uc, w_pos, cp_pos, cn_pos, mask
-        terms["plan_operands"] = nb * blk * (2 * K + 5) * F32
-        if name == "pallas_fused_tiered":
-            hot = max(0, min(int(eng.hot_rows), V))
-            shape["hot_rows"] = hot
-            terms["hot_prefix"] = 2 * (hot + 1) * d * F32
-            terms["block_ids"] = nb * blk * (K + 2) * F32
+        hot = max(0, min(int(eng.hot_rows), V))
+        shape.update(block_pairs=blk, ring_depth=eng.ring_depth, hot_rows=hot)
+        terms.update(vmem_terms(blk, K, row_width(d, interpret=False),
+                                eng.ring_depth, hot))
     else:   # future engines: unknown ⇒ no static claim
         pass
     return VmemEstimate(eng.describe(), shape, terms)

@@ -19,13 +19,13 @@ The synchronized strawman (`sync_train_epoch`) is conventional
 data-parallel SGNS: one table, batch sharded, gradient all-reduced every
 step — the TPU-native equivalent of the paper's Hogwild/MLLib baselines.
 
-Both run on one CPU device for tests (``vmap`` backend) and lower to the
-production mesh for the dry-run (``shard_map`` backend).
+The ``vmap`` backend runs every worker on one device; the ``shard_map``
+backend shards them over a ``worker`` mesh of every device (one host
+with several chips, several hosts, or the dry-run's simulated mesh).
 """
 
 from __future__ import annotations
 
-import inspect
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -36,27 +36,6 @@ from jax.sharding import Mesh, PartitionSpec as P, NamedSharding
 from repro.core import sgns
 from repro.core.engine import get_engine
 from repro.core.sgns import SGNSConfig
-
-# --- shard_map compat: jax >= 0.6 exposes jax.shard_map(check_vma=...);
-# jax 0.4.x has jax.experimental.shard_map.shard_map(check_rep=...).
-try:
-    _shard_map_impl = jax.shard_map
-except AttributeError:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = inspect.signature(_shard_map_impl).parameters
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, on any supported jax."""
-    kw = {}
-    if "check_vma" in _SHARD_MAP_PARAMS:
-        kw["check_vma"] = False
-    elif "check_rep" in _SHARD_MAP_PARAMS:
-        kw["check_rep"] = False
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
-
 
 # ---------------------------------------------------------------------------
 # Single-worker epoch: scan over a fixed number of steps.
@@ -69,9 +48,11 @@ def make_worker_epoch(cfg: SGNSConfig, total_steps: int, engine="sparse"):
     parameter apply. ``neg_table`` is the worker's *own* unigram^0.75
     noise table in the layout ``engine.table_kind`` names — a ``(V,)``
     CDF or a ``{'prob', 'alias'}`` Vose table (each sub-model draws from
-    its own sample's noise distribution, paper §3.2).
+    its own sample's noise distribution, paper §3.2). The scan carries
+    the tables in the engine's packed layout, converted once per call.
     """
-    step = get_engine(engine).make_step(cfg, total_steps)
+    engine = get_engine(engine)
+    step = engine.make_packed_step(cfg, total_steps)
 
     def epoch_fn(params, centers, contexts, neg_table, key, step0):
         def body(carry, xs):
@@ -82,8 +63,9 @@ def make_worker_epoch(cfg: SGNSConfig, total_steps: int, engine="sparse"):
             return (params, key, i + 1), loss
 
         (params, _, _), losses = jax.lax.scan(
-            body, (params, key, jnp.int32(0)), (centers, contexts))
-        return params, losses
+            body, (engine.pack(params), key, jnp.int32(0)),
+            (centers, contexts))
+        return engine.unpack(params, cfg), losses
 
     return epoch_fn
 
@@ -154,9 +136,9 @@ class AsyncShardTrainer:
 
     def _sharded(self, epoch_fn):
         spec = P("worker")
-        return shard_map_compat(
+        return jax.shard_map(
             jax.vmap(epoch_fn),  # local worker block (n/devices per device)
-            mesh=self.mesh,
+            mesh=self.mesh, check_vma=False,
             # spec is a pytree prefix, so the alias table's {prob, alias}
             # leaves pick up the worker sharding too.
             in_specs=(spec,) * 6,
@@ -165,7 +147,10 @@ class AsyncShardTrainer:
 
     def _jit_epoch(self):
         """Build + jit the epoch once; chunked streaming calls it many
-        times per epoch, so the jit cache must live on the trainer."""
+        times per epoch, so the jit cache must live on the trainer. The
+        stacked tables are donated: the epoch's result replaces them, and
+        a second copy of every worker's tables would not fit one chip
+        at the paper's shape."""
         if self._jitted is None:
             epoch_fn = self._epoch_fn()
             if self.backend == "vmap":
@@ -175,7 +160,7 @@ class AsyncShardTrainer:
                 fn = self._sharded(epoch_fn)
             else:
                 raise ValueError(self.backend)
-            object.__setattr__(self, "_jitted", jax.jit(fn))
+            object.__setattr__(self, "_jitted", jax.jit(fn, donate_argnums=0))
         return self._jitted
 
     def device_chunk(self, centers, contexts):
@@ -296,8 +281,8 @@ def make_sync_epoch(cfg: SGNSConfig, neg_table, total_steps: int,
     if mesh is None:
         return jax.jit(epoch_fn)
 
-    return jax.jit(shard_map_compat(
-        epoch_fn, mesh=mesh,
+    return jax.jit(jax.shard_map(
+        epoch_fn, mesh=mesh, check_vma=False,
         in_specs=(P(), P(None, data_axis), P(None, data_axis), P(), P()),
         out_specs=(P(), P())))
 
@@ -345,8 +330,8 @@ def make_periodic_sync_epoch(cfg: SGNSConfig, neg_table,
         return params, jax.lax.pmean(losses, axis_name=data_axis)
 
     spec_b = P(None, None, data_axis)
-    return jax.jit(shard_map_compat(
-        epoch_fn, mesh=mesh,
+    return jax.jit(jax.shard_map(
+        epoch_fn, mesh=mesh, check_vma=False,
         in_specs=(P(), spec_b, spec_b, P(), P()),
         out_specs=(P(), P())))
 

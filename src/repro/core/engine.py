@@ -67,6 +67,12 @@ sampler: ``"sparse"``, ``"sparse:alias"``, ``"pallas:cdf"``. The fused
 engines always sample in-kernel from alias tables (``"alias"`` is their
 only valid sampler, and their default).
 
+Every Pallas engine takes ``interpret=None`` (Mosaic on a TPU, the
+interpreter elsewhere). Under Mosaic, ``pallas``, ``pallas_fused_pipe``
+and ``pallas_fused_tiered`` compile; ``pallas_fused`` and
+``pallas_fused_hbm`` (and so ``sequential=True``) raise when their step
+is built, naming the missing lowering.
+
 Engines are frozen dataclasses, so they hash/compare by value and are
 safe as jit static arguments or cache keys.
 """
@@ -80,11 +86,7 @@ import jax
 from repro.core import sgns
 from repro.core.sgns import SGNSConfig
 from repro.data.pairs import negative_sampler_fn
-
-
-def _auto_interpret() -> bool:
-    """Pallas interpret mode everywhere but on a real TPU backend."""
-    return jax.default_backend() != "tpu"
+from repro.kernels.mode import require_interpret, resolve_interpret
 
 
 @dataclass(frozen=True)
@@ -115,6 +117,20 @@ class UpdateEngine:
         """Returns ``step(params, centers, contexts, neg_table, key,
         step_idx) -> (params, mean_loss)``."""
         raise NotImplementedError
+
+    def pack(self, params: dict) -> dict:
+        """``(V, d)`` tables → the layout :meth:`make_packed_step`'s
+        step carries (identity unless a kernel needs its own layout)."""
+        return params
+
+    def unpack(self, packed: dict, cfg: SGNSConfig) -> dict:
+        """Inverse of :meth:`pack`."""
+        return packed
+
+    def make_packed_step(self, cfg: SGNSConfig, total_steps: int):
+        """:meth:`make_step` on packed tables — what a scan of steps
+        carries, so that the layout is converted once per scan."""
+        return self.make_step(cfg, total_steps)
 
     def validate(self, *, vocab_size: int | None = None) -> None:
         """Check dials that only make sense against a model shape
@@ -187,9 +203,8 @@ class PallasEngine(SparseEngine):
         (interpret-mode off-TPU unless overridden)."""
         from repro.kernels import ops
 
-        interpret = self.interpret if self.interpret is not None \
-            else _auto_interpret()
-        return ops.make_row_grad_fn(interpret=interpret, block_b=self.block_b)
+        return ops.make_row_grad_fn(interpret=self.interpret,
+                                    block_b=self.block_b)
 
 
 @dataclass(frozen=True)
@@ -218,10 +233,9 @@ class FusedPallasEngine(UpdateEngine):
     def make_step(self, cfg: SGNSConfig, total_steps: int):
         """Single-kernel step: in-kernel draw + forward + row grads +
         apply, both tables VMEM-resident."""
-        from repro.kernels.sgns_fused import sgns_fused_step
+        from repro.kernels.sgns_fused import NO_MOSAIC, sgns_fused_step
 
-        interpret = self.interpret if self.interpret is not None \
-            else _auto_interpret()
+        interpret = require_interpret(self.interpret, self.name, NO_MOSAIC)
 
         def step(params, centers, contexts, neg_table, key, step_idx):
             lr = sgns.linear_lr(step_idx, total_steps, cfg)
@@ -261,10 +275,10 @@ class FusedHBMPallasEngine(FusedPallasEngine):
     def make_step(self, cfg: SGNSConfig, total_steps: int):
         """Per-block kernel-chain step against HBM-resident tables
         (DMA gather/RMW-scatter of touched rows only)."""
-        from repro.kernels.sgns_fused_hbm import sgns_fused_hbm_step
+        from repro.kernels.sgns_fused_hbm import (
+            NO_MOSAIC, sgns_fused_hbm_step)
 
-        interpret = self.interpret if self.interpret is not None \
-            else _auto_interpret()
+        interpret = require_interpret(self.interpret, self.name, NO_MOSAIC)
 
         def step(params, centers, contexts, neg_table, key, step_idx):
             lr = sgns.linear_lr(step_idx, total_steps, cfg)
@@ -309,22 +323,54 @@ class FusedPipePallasEngine(FusedHBMPallasEngine):
                 f"b+1 must overlap scatters of block b), got "
                 f"{self.ring_depth}")
 
-    def make_step(self, cfg: SGNSConfig, total_steps: int):
-        """One pipelined-kernel step (multi-slot DMA ring, deduped row
-        traffic); ``sequential=True`` falls back to the HBM oracle."""
+    hot_rows = 0    # the tiered engine's field; the pure pipeline has none
+
+    def pack(self, params):
+        """Tables → the kernels' ``(V, 1, dp)`` row layout
+        (:func:`repro.kernels.sgns_fused_pipe.to_row_layout`)."""
+        if self.sequential:
+            return params
+        from repro.kernels.sgns_fused_pipe import to_row_layout
+
+        interpret = resolve_interpret(self.interpret)
+        return {k: to_row_layout(v, interpret) for k, v in params.items()}
+
+    def unpack(self, packed, cfg):
+        if self.sequential:
+            return packed
+        from repro.kernels.sgns_fused_pipe import from_row_layout
+
+        return {k: from_row_layout(v, cfg.dim) for k, v in packed.items()}
+
+    def make_packed_step(self, cfg: SGNSConfig, total_steps: int):
+        """One pipelined-kernel step on row-layout tables (multi-slot DMA
+        ring, deduped row traffic, the hot tier when ``hot_rows > 0``);
+        ``sequential=True`` falls back to the HBM oracle."""
         if self.sequential:
             return FusedHBMPallasEngine.make_step(self, cfg, total_steps)
-        from repro.kernels.sgns_fused_pipe import sgns_fused_pipe_step
+        from repro.kernels.sgns_fused_pipe import fused_pipe_rows_step
 
-        interpret = self.interpret if self.interpret is not None \
-            else _auto_interpret()
+        interpret = resolve_interpret(self.interpret)
 
-        def step(params, centers, contexts, neg_table, key, step_idx):
+        def step(tables, centers, contexts, neg_table, key, step_idx):
             lr = sgns.linear_lr(step_idx, total_steps, cfg)
-            return sgns_fused_pipe_step(
-                params, centers, contexts, neg_table, key, lr,
+            return fused_pipe_rows_step(
+                tables, centers, contexts, neg_table, key, lr,
                 negatives=cfg.negatives, block_pairs=self.block_pairs,
-                ring_depth=self.ring_depth, interpret=interpret)
+                ring_depth=self.ring_depth,
+                hot_rows=min(self.hot_rows, tables["W"].shape[0]),
+                interpret=interpret)
+
+        return step
+
+    def make_step(self, cfg: SGNSConfig, total_steps: int):
+        """:meth:`make_packed_step` with the layout converted around
+        each step."""
+        packed_step = self.make_packed_step(cfg, total_steps)
+
+        def step(params, *args):
+            packed, loss = packed_step(self.pack(params), *args)
+            return self.unpack(packed, cfg), loss
 
         return step
 
@@ -374,26 +420,6 @@ class FusedTieredPallasEngine(FusedPipePallasEngine):
                 f"vocab_size={vocab_size}; the hot tier is a prefix of "
                 f"the (V, d) table — use hot_rows <= V (hot_rows=V is "
                 f"fully VMEM-resident)")
-
-    def make_step(self, cfg: SGNSConfig, total_steps: int):
-        """One tiered-kernel step (VMEM hot prefix + cold DMA ring);
-        ``sequential=True`` falls back to the HBM oracle."""
-        if self.sequential:
-            return FusedHBMPallasEngine.make_step(self, cfg, total_steps)
-        from repro.kernels.sgns_fused_tiered import sgns_fused_tiered_step
-
-        interpret = self.interpret if self.interpret is not None \
-            else _auto_interpret()
-
-        def step(params, centers, contexts, neg_table, key, step_idx):
-            lr = sgns.linear_lr(step_idx, total_steps, cfg)
-            return sgns_fused_tiered_step(
-                params, centers, contexts, neg_table, key, lr,
-                negatives=cfg.negatives, block_pairs=self.block_pairs,
-                hot_rows=self.hot_rows, ring_depth=self.ring_depth,
-                interpret=interpret)
-
-        return step
 
 
 ENGINES: dict[str, type[UpdateEngine]] = {

@@ -105,13 +105,16 @@ def sparse_row_grads_per_pair(
     Returns (loss (B,), dW_rows (B,d), dC_pos_rows (B,d),
     dC_neg_rows (B,K,d)).
     """
+    # Products and sums, not einsums: Mosaic has no lowering for these
+    # batched contractions, and the elementwise form is exact f32 on
+    # every backend (no reduced-precision matmul passes).
     s_pos = jnp.sum(w * c_pos, axis=-1)
-    s_neg = jnp.einsum("bd,bkd->bk", w, c_neg)
+    s_neg = jnp.sum(w[:, None, :] * c_neg, axis=-1)
     loss = -jax.nn.log_sigmoid(s_pos) - jnp.sum(
         jax.nn.log_sigmoid(-s_neg), axis=-1)
     g_pos = jax.nn.sigmoid(s_pos) - 1.0                # (B,)
     g_neg = jax.nn.sigmoid(s_neg)                      # (B,K)
-    d_w = g_pos[:, None] * c_pos + jnp.einsum("bk,bkd->bd", g_neg, c_neg)
+    d_w = g_pos[:, None] * c_pos + jnp.sum(g_neg[..., None] * c_neg, axis=1)
     d_cp = g_pos[:, None] * w
     d_cn = g_neg[..., None] * w[:, None, :]
     return loss, d_w, d_cp, d_cn

@@ -8,12 +8,19 @@
   negative sampling (counter-based PRNG), forward, row grads and
   scatter-add apply in a single VMEM pass. Powers the ``pallas_fused``
   update engine.
+* ``sgns_fused_hbm`` / ``sgns_fused_pipe`` / ``sgns_fused_tiered`` — the
+  step against HBM-resident tables: the serial-DMA oracle, the
+  pipelined kernel, and its frequency-tiered entry point.
 * ``swa_decode`` — flash-style single-token sliding-window decode
   attention (online softmax, VMEM scratch accumulators) — the hot op of
   the long_500k shape for dense archs.
 
-Kernels are validated in ``interpret=True`` mode on CPU (the kernel body
-runs in Python) and target TPU Mosaic unchanged.
+Every entry point takes ``interpret=None``: compiled by Mosaic on a TPU,
+interpreted elsewhere (``mode.resolve_interpret``). The CPU tests run
+them in interpret mode. On a TPU, ``sgns_update``, ``sgns_fused_pipe``
+and ``sgns_fused_tiered`` compile; ``sgns_fused`` and ``sgns_fused_hbm``
+gather by vectors of ids, which Mosaic cannot lower, and raise when
+built to compile (``mode.require_interpret``).
 """
 
 from repro.kernels.ops import (

@@ -3,8 +3,8 @@
 ``sgns_row_grads`` is a drop-in for
 :func:`repro.core.sgns.sparse_row_grads`; the ``pallas`` update engine
 (``repro.core.engine``) routes the sparse step's row gradients through
-it. On CPU we run the kernel in interpret mode; on TPU the same code
-compiles to Mosaic.
+it. ``interpret=None`` compiles the kernel with Mosaic on a TPU and runs
+it in interpret mode elsewhere.
 """
 
 from __future__ import annotations
@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.mode import resolve_interpret
 from repro.kernels.sgns_update import sgns_row_grads_kernel, _pick_block_b
 from repro.kernels import ref
 
@@ -27,7 +28,7 @@ def sgns_row_grads(
     c_pos: jax.Array,
     c_neg: jax.Array,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_b: int | None = None,
 ):
     """Kernel-backed row grads with automatic lane/batch padding.
@@ -48,12 +49,14 @@ def sgns_row_grads(
     pad2 = lambda a: jnp.pad(a, ((0, Bp - B), (0, Dp - D)))
     pad3 = lambda a: jnp.pad(a, ((0, Bp - B), (0, 0), (0, Dp - D)))
     loss, dw, dcp, dcn = sgns_row_grads_kernel(
-        pad2(w), pad2(c_pos), pad3(c_neg), block_b=bt, interpret=interpret)
+        pad2(w), pad2(c_pos), pad3(c_neg), block_b=bt,
+        interpret=resolve_interpret(interpret))
     mean_loss = jnp.sum(loss[:B]) / B
     return mean_loss, dw[:B, :D], dcp[:B, :D], dcn[:B, :, :D]
 
 
-def make_row_grad_fn(interpret: bool = True, block_b: int | None = None):
+def make_row_grad_fn(interpret: bool | None = None,
+                     block_b: int | None = None):
     """row_grad_fn for AsyncShardTrainer / train_step_sparse."""
 
     def fn(w, c_pos, c_neg):
@@ -70,7 +73,7 @@ def sgns_apply_step(
     contexts: jax.Array,
     negatives: jax.Array,
     lr: jax.Array,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Full fused step: gather → kernel → scatter-add (the production path)."""
     w = params["W"][centers]

@@ -14,8 +14,8 @@ the XLA level too.
 
 PRNG: a stateless counter hash (two rounds of the lowbias32 avalanche
 mix) keyed by the step's ``(2,)`` uint32 PRNG key. It is plain uint32
-arithmetic, so the *same* draw runs under Mosaic and under interpret
-mode, and :func:`fused_negative_ids` reproduces it outside the kernel —
+arithmetic (the uniform is cast through int32, which Mosaic lowers), and
+:func:`fused_negative_ids` reproduces it outside the kernel —
 that is what lets the equivalence tests feed identical negatives to the
 ``sparse`` reference. (``pltpu.prng_random_bits`` would be faster on TPU
 but is neither available in interpret mode nor replayable off-device.)
@@ -27,7 +27,9 @@ accumulating scatter-adds (duplicate ids add up).
 Capacity: both ``(V, d)`` tables ride through the kernel whole, so this
 variant targets per-worker sub-model tables that fit VMEM-adjacent
 memory (the paper's 300k×500 tables need the blocked HBM-streaming
-variant — see ROADMAP). Interpret mode has no such limit.
+variant). Interpret mode has no such limit, and it is the only mode:
+the row gathers and scatter-adds by vectors of ids have no Mosaic
+lowering (``NO_MOSAIC``).
 """
 
 from __future__ import annotations
@@ -37,6 +39,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.mode import require_interpret
+
+# Why the VMEM-resident kernel and its sampler have no Mosaic lowering.
+NO_MOSAIC = ("it gathers and scatter-adds table rows, and draws alias-table "
+              "entries, by vectors of ids, and Mosaic has no vector gather "
+              "or scatter on VMEM values")
 
 
 # ---------------------------------------------------------------------------
@@ -61,8 +70,10 @@ def counter_uniforms(seed: jax.Array, counters: jax.Array) -> jax.Array:
     """
     seed = seed.astype(jnp.uint32)
     bits = _mix32(_mix32(counters.astype(jnp.uint32) ^ seed[0]) + seed[1])
-    # top 24 bits → exactly representable uniforms in [0, 1)
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1 / (1 << 24))
+    # top 24 bits → exactly representable uniforms in [0, 1); through
+    # int32 (exact below 2**24), since Mosaic has no uint32 → f32 cast
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(1 / (1 << 24))
 
 
 def alias_draw_from_counters(
@@ -162,9 +173,10 @@ def sgns_fused_step(
     lr: jax.Array,
     *,
     negatives: int = 5,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[dict, jax.Array]:
-    """One whole SGNS step in a single ``pallas_call``.
+    """One whole SGNS step in a single ``pallas_call`` (interpret mode
+    only: see ``NO_MOSAIC``).
 
     params: ``{"W": (V, d), "C": (V, d)}``; centers/contexts ``(B,)``
     int32; table: ``{"prob": (V,), "alias": (V,)}`` Vose alias table of
@@ -173,6 +185,7 @@ def sgns_fused_step(
     ``train_step_sparse`` fed the ids :func:`fused_negative_ids` yields
     for the same key.
     """
+    interpret = require_interpret(interpret, "sgns_fused_step", NO_MOSAIC)
     V, d = params["W"].shape
     B = centers.shape[0]
     out = pl.pallas_call(
@@ -202,7 +215,7 @@ def _sampler_kernel(seed_ref, prob_ref, alias_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("shape", "interpret"))
 def sample_negatives_fused(
     table: dict, key: jax.Array, shape: tuple[int, ...],
-    *, interpret: bool = True,
+    *, interpret: bool | None = None,
 ) -> jax.Array:
     """Draw negative ids with the *kernel's* sampler, via pallas_call.
 
@@ -211,6 +224,8 @@ def sample_negatives_fused(
     to validate the in-kernel draw path itself, and as the fused
     engine's reference draw outside the kernel.
     """
+    interpret = require_interpret(interpret, "sample_negatives_fused",
+                                  NO_MOSAIC)
     return pl.pallas_call(
         _sampler_kernel,
         out_shape=jax.ShapeDtypeStruct(shape, jnp.int32),

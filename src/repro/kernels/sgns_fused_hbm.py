@@ -62,17 +62,17 @@ The row gradients use the exact expressions of
 just to tolerance.
 
 Hardware notes: this kernel keeps the *unpipelined* start→wait-per-row
-DMA discipline — correct everywhere, the shape Mosaic lowers, and the
-simplest possible oracle for the pipelined engine's bit-equivalence
-tests. The DMA-overlap optimization it deliberately leaves on the table
-lives in ``sgns_fused_pipe.py``: a ring of VMEM row buffers with
+DMA discipline — the simplest possible oracle for the pipelined
+engine's bit-equivalence tests. It runs in interpret mode only: Mosaic
+cannot lower its in-kernel draw (alias-table gathers by a vector of
+ids), its DMA row ids (vector values, not SMEM scalars) or its
+single-row slices of ``(8, 128)``-tiled tables (``NO_MOSAIC``). The
+DMA-overlap optimization it deliberately leaves on the table lives in ``sgns_fused_pipe.py``: a ring of VMEM row buffers with
 per-slot semaphores, touched-row dedup (one DMA per unique row per
 block instead of per-pair RMW round-trips), and planner-computed
 scatter-before-regather hazard ordering. This kernel remains the
 ``sequential=True`` path (word2vec's per-pair apply order is inherently
-serial) and the fallback reference; real-TPU Mosaic validation of both
-is tracked in ROADMAP. Interpret mode (the CI gate) executes the same
-DMA semantics on CPU.
+serial) and the fallback reference.
 """
 
 from __future__ import annotations
@@ -85,7 +85,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sgns import sparse_row_grads_per_pair
+from repro.kernels.mode import require_interpret
 from repro.kernels.sgns_fused import _as_seed, alias_draw_from_counters
+
+# Why this kernel has no Mosaic lowering (the pipelined kernel in
+# sgns_fused_pipe.py is the one that compiles).
+NO_MOSAIC = ("its in-kernel draw gathers alias-table entries by a vector "
+              "of ids, its DMA row ids are vector values rather than SMEM "
+              "scalars, and it slices single rows of (8, 128)-tiled (V, d) "
+              "tables")
 
 
 def _pick_block_pairs(B: int, block_pairs: int) -> int:
@@ -239,7 +247,7 @@ def sgns_fused_hbm_step(
     negatives: int = 5,
     block_pairs: int = 256,
     sequential: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[dict, jax.Array]:
     """One SGNS step with HBM-resident parameter tables.
 
@@ -251,8 +259,10 @@ def sgns_fused_hbm_step(
     tail invocation when B is not a multiple), each DMA-gathering /
     RMW-scattering only its own block's touched rows.
     ``sequential=True`` applies word2vec's per-pair update order inside
-    each block invocation.
+    each block invocation. Interpret mode only (see ``NO_MOSAIC``).
     """
+    interpret = require_interpret(interpret, "sgns_fused_hbm_step",
+                                  NO_MOSAIC)
     V, d = params["W"].shape
     B = centers.shape[0]
     K = negatives
@@ -274,12 +284,12 @@ def sgns_fused_hbm_step(
                 pl.BlockSpec(memory_space=pltpu.VMEM),  # contexts block
                 pl.BlockSpec(memory_space=pltpu.VMEM),  # prob
                 pl.BlockSpec(memory_space=pltpu.VMEM),  # alias
-                pl.BlockSpec(memory_space=pltpu.ANY),   # W (HBM)
-                pl.BlockSpec(memory_space=pltpu.ANY),   # C (HBM)
+                pl.BlockSpec(memory_space=pl.ANY),   # W (HBM)
+                pl.BlockSpec(memory_space=pl.ANY),   # C (HBM)
             ],
             out_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pltpu.VMEM),
             ],
             out_shape=[

@@ -25,10 +25,11 @@ with a **multi-slot DMA pipeline** in a single ``pallas_call`` per step:
   the unique-row count;
 * a **pure-JAX block planner** (:func:`plan_blocks`) that computes the
   dedup, the pair→buffer-slot index maps, and the scatter-before-
-  regather **hazard flags** outside the kernel, and a static
-  :func:`kernel_schedule` that both the kernel body and the unit tests
-  iterate — the schedule (slot assignment, gather/compute/scatter/wait
-  ordering, hazard guards) is testable entirely without Pallas.
+  regather **hazard flags** outside the kernel, and a per-position
+  schedule template (:func:`position_template`) that the kernel body
+  loops over and :func:`kernel_schedule` expands for the unit tests —
+  the schedule (slot assignment, gather/compute/scatter/wait ordering,
+  hazard guards) is testable entirely without Pallas.
 
 Hazard ordering: with the chain semantics, block *b*'s gathers must
 observe every earlier block's applied updates. Pipelining reorders block
@@ -75,13 +76,18 @@ exactly once.
 
 Hardware notes: every DMA is started on a slot semaphore and waited
 exactly once, with matched start/wait structure under every hazard
-outcome (the guards partition the hazard-outcome space), so the kernel
-lowers the same way under Mosaic and interpret mode. Interpret mode (the
-CI gate) executes the schedule's DMA semantics serially on CPU — the
-overlap itself is a hardware property; real-TPU Mosaic validation stays
-open on ROADMAP. ``sequential=True`` (word2vec's per-pair apply order)
-is inherently unpipelineable and is served by the unpipelined kernel —
-see :class:`repro.core.engine.FusedPipePallasEngine`.
+outcome (the guards partition the hazard-outcome space). The same
+kernel body compiles under Mosaic and runs in interpret mode (the CPU
+tests), which executes the DMAs serially. What Mosaic requires shapes
+it: tables in a ``(V, 1, dp)`` row layout (:func:`to_row_layout`),
+scalars read from SMEM (the per-block metadata is DMA'd into an SMEM
+ring), rows moved one at a time between the DMA ring and the compute
+rows, the schedule walked as a loop over positions
+(:func:`position_template`), and vmap folded into the kernel's worker
+grid (:func:`_worker_kernel`). ``sequential=True`` (word2vec's per-pair
+apply order) is inherently unpipelineable and is served by the
+unpipelined kernel, in interpret mode only — see
+:class:`repro.core.engine.FusedPipePallasEngine`.
 """
 
 from __future__ import annotations
@@ -96,6 +102,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sgns import sparse_row_grads_per_pair
 from repro.kernels.sgns_fused import _as_seed, fused_negative_ids
+from repro.kernels.mode import resolve_interpret
 from repro.kernels.sgns_fused_hbm import _pick_block_pairs
 
 NUM_SLOTS = 2   # default ring depth: gathers of b+1 overlap scatters of b
@@ -264,14 +271,51 @@ def plan_blocks(
 
 
 # ---------------------------------------------------------------------------
-# The static pipeline schedule — the single source of truth iterated by
-# the kernel body (hazard guards become pl.when) and by the tests
-# (hazard guards resolved against a concrete hazard vector).
+# The pipeline schedule — one per-position template, the single source of
+# truth for the kernel body (which loops over positions, hazard guards
+# becoming pl.when) and for the tests and the DMA model checker (which
+# expand it into the static event list and resolve the guards against a
+# concrete hazard vector).
 # ---------------------------------------------------------------------------
+def position_template(num_slots: int = NUM_SLOTS):
+    """The events of pipeline position b, relative to b, as ``(op, block
+    offset, guard)``; each guard condition is ``(hazard offset, want)``.
+    An event exists at b when its block and every hazard it reads lie in
+    ``[0, nblocks)``. ``op`` ∈ {gather, wait_gather, compute, scatter,
+    wait_scatter}."""
+    S = num_slots
+    if S < 2:
+        raise ValueError(f"ring needs at least 2 slots, got {S}")
+    # slot-recycling default drain of block b+1-S, whose buffers block
+    # b+1 is about to gather into — fires iff no hazard in its window
+    # hazard[b+2-S .. b] drained it earlier
+    ev = [("wait_scatter", 1 - S, tuple((f, False) for f in range(2 - S, 1))),
+          ("gather", 1, ((1, False),)),
+          ("wait_gather", 0, ()), ("compute", 0, ()), ("scatter", 0, ())]
+    # hazard path: drain every still-outstanding write-back (oldest
+    # first) before issuing block b+1's gathers — block b-t is
+    # outstanding here iff no flag in hazard[b-t+1 .. b] fired (which
+    # would have drained it already)
+    for t in range(S - 2, -1, -1):
+        ev.append(("wait_scatter", -t,
+                   tuple((f, False) for f in range(1 - t, 1)) + ((1, True),)))
+    ev.append(("gather", 1, ((1, True),)))
+    return ev
+
+
+def schedule_tail(nblocks: int, num_slots: int = NUM_SLOTS):
+    """Events after the last position: blocks whose slot-recycling
+    default lies past it drain on "no later hazard fired" (the
+    partition remainder)."""
+    S = num_slots
+    return [("wait_scatter", j, j % S,
+             tuple((f, False) for f in range(j + 1, nblocks)) or None)
+            for j in range(max(0, nblocks - S + 1), nblocks)]
+
+
 def kernel_schedule(nblocks: int, num_slots: int = NUM_SLOTS):
     """The unrolled pipeline as ``(op, block, slot, guard)`` events.
 
-    ``op`` ∈ {gather, wait_gather, compute, scatter, wait_scatter};
     ``guard`` is ``None`` (unconditional) or a tuple of ``(b, want)``
     conditions meaning "only when bool(hazard[b]) == want for every
     condition". For each block, the guards over its wait_scatter sites
@@ -289,43 +333,16 @@ def kernel_schedule(nblocks: int, num_slots: int = NUM_SLOTS):
       before block ``j+S``'s gathers reuse block j's buffer slot).
     """
     S = num_slots
-    if S < 2:
-        raise ValueError(f"ring needs at least 2 slots, got {S}")
-
-    def clear(lo, hi):
-        """'hazard[lo..hi] all clear' conditions (empty → unconditional)."""
-        g = tuple((f, False) for f in range(lo, hi + 1))
-        return g or None
-
+    template = position_template(S)
     ev = [("gather", 0, 0, None)]
     for b in range(nblocks):
-        s = b % S
-        g = b + 1
-        j = g - S
-        if j >= 0:
-            # slot-recycling default drain of the block whose buffers
-            # block g is about to gather into — fires iff no hazard in
-            # j's window drained it earlier
-            ev.append(("wait_scatter", j, j % S, clear(j + 1, j + S - 1)))
-        if g < nblocks:
-            ev.append(("gather", g, g % S, ((g, False),)))
-        ev.append(("wait_gather", b, s, None))
-        ev.append(("compute", b, s, None))
-        ev.append(("scatter", b, s, None))
-        if g < nblocks:
-            # hazard path: drain every still-outstanding write-back
-            # (oldest first) before issuing block g's gathers — block
-            # j2 is outstanding here iff no flag in hazard[j2+1 .. b]
-            # fired (which would have drained it already)
-            for j2 in range(max(0, g - S + 1), b + 1):
-                pre = tuple((f, False) for f in range(j2 + 1, b + 1))
-                ev.append(("wait_scatter", j2, j2 % S, pre + ((g, True),)))
-            ev.append(("gather", g, g % S, ((g, True),)))
-    # tail: blocks whose slot-recycling default lies past the last
-    # position drain on "no later hazard fired" (partition remainder)
-    for j in range(max(0, nblocks - S + 1), nblocks):
-        ev.append(("wait_scatter", j, j % S, clear(j + 1, nblocks - 1)))
-    return ev
+        for op, off, guard in template:
+            blk = b + off
+            if 0 <= blk < nblocks and all(0 <= b + f < nblocks
+                                          for f, _ in guard):
+                ev.append((op, blk, blk % S,
+                           tuple((b + f, w) for f, w in guard) or None))
+    return ev + schedule_tail(nblocks, S)
 
 
 def resolve_schedule(hazard, num_slots: int = NUM_SLOTS):
@@ -347,48 +364,214 @@ def plan_row_traffic(plan: PipelinePlan, hot_rows: int = 0) -> int:
     return 2 * int(plan.n_w.sum() + plan.n_c.sum()) + 4 * int(hot_rows)
 
 
+
+
 # ---------------------------------------------------------------------------
-# Kernel plumbing shared with the tiered sibling
-# (kernels/sgns_fused_tiered.py): the per-block row-DMA runner and the
-# guarded schedule executor.
+# Table row layout. Mosaic DMAs a table row only as a leading-dim slice
+# whose lane extent is a multiple of 128: a (V, d) f32 table is tiled
+# (8, 128), so one row is an unaligned sublane slice, and the paper's
+# d = 500 is an unaligned lane extent. The kernels therefore take each
+# table as (V, 1, dp), dp the 128-lane round-up of d on the chip and d
+# itself in interpret mode (no tiling there, so the CPU results stay
+# bit-identical to the reference). Pad lanes start at zero and stay
+# zero: every update of a pad lane is a product with a zero pad lane.
 # ---------------------------------------------------------------------------
-def make_row_dma_runner(uw_ref, uc_ref, n_w_ref, n_c_ref,
-                        w_hbm, c_hbm, buf_w, buf_c, gsem, ssem):
-    """Returns ``run_rows(b, s, gather, wait)``: matched start/wait
-    loops over block b's valid (cold) rows — each valid uw/uc slot is
-    one row DMA (HBM→slot buffer for gathers, buffer→HBM for the
-    write-back scatters)."""
+LANES = 128
+
+
+def row_width(d: int, interpret: bool) -> int:
+    """Lane width of one table row in the kernels' HBM layout."""
+    return d if interpret else -(-d // LANES) * LANES
+
+
+def to_row_layout(table: jax.Array, interpret: bool) -> jax.Array:
+    """``(..., V, d)`` → ``(..., V, 1, dp)`` (zero pad lanes)."""
+    d = table.shape[-1]
+    pad = row_width(d, interpret) - d
+    if pad:
+        table = jnp.pad(table, [(0, 0)] * (table.ndim - 1) + [(0, pad)])
+    return table[..., None, :]
+
+
+def from_row_layout(table: jax.Array, d: int) -> jax.Array:
+    """``(..., V, 1, dp)`` → ``(..., V, d)``."""
+    return table[..., 0, :d]
+
+
+# ---------------------------------------------------------------------------
+# Per-block int32 metadata. Mosaic reads scalars (DMA row ids, buffer
+# positions, loop bounds, hazard flags) only from SMEM, which holds a
+# few blocks' worth, not a step's: the metadata stays in HBM and each
+# block's row is DMA'd into an SMEM ring slot when its gathers issue.
+# ---------------------------------------------------------------------------
+def meta_layout(blk: int, K: int, hot: bool) -> tuple[dict, int]:
+    """Offsets within one block's metadata row, and its length padded
+    to whole 128-lane tiles (the DMA moves whole tiles): the DMA lists
+    ``uw | uc``, the buffer positions ``w_pos | cp_pos | cn_pos`` and,
+    with a hot tier, the ids ``cen | ctx | neg``."""
+    sizes = {"uw": blk, "uc": blk * (K + 1), "w_pos": blk, "cp_pos": blk,
+             "cn_pos": blk * K}
+    if hot:
+        sizes.update(cen=blk, ctx=blk, neg=blk * K)
+    off, n = {}, 0
+    for name, size in sizes.items():
+        off[name] = n
+        n += size
+    return off, -(-n // LANES) * LANES
+
+
+def block_meta(plan: PipelinePlan, hot: bool) -> tuple[jax.Array, jax.Array]:
+    """``(meta (nblocks, 1, M), counts (3·nblocks,))``: the metadata rows
+    in :func:`meta_layout` order (the unit dim gives each row its own
+    tile, as for the tables), and ``n_w | n_c | hazard``."""
+    parts = [plan.uw, plan.uc, plan.w_pos, plan.cp_pos, plan.cn_pos]
+    if hot:
+        parts += [plan.cen, plan.ctx, plan.neg]
+    meta = jnp.concatenate(parts, axis=1)
+    _, M = meta_layout(plan.block_pairs, plan.cn_pos.shape[1]
+                       // plan.block_pairs, hot)
+    meta = jnp.pad(meta, ((0, 0), (0, M - meta.shape[1])))
+    return (meta[:, None, :],
+            jnp.concatenate([plan.n_w, plan.n_c, plan.hazard]))
+
+
+# ---------------------------------------------------------------------------
+# The kernel. One grid step per worker; every table row moves by DMA
+# between the HBM table and a VMEM ring slot, and between the ring and
+# the compute rows by single-row loads and stores (Mosaic has no vector
+# gather or scatter on VMEM values). Operands:
+#   lr (n,) f32, counts (n·3·nblocks,) i32 SMEM | meta (n, nblocks, 1, M)
+#   i32 HBM | W, C (n, V, 1, dp) HBM, aliased to the first two outputs
+# outputs: W', C' (HBM) | per-block masked loss sums (n·nblocks,) SMEM
+# scratch: metadata ring (S, 1, M) SMEM | bufW (S, R_W, 1,
+#          dp), bufC (S, R_C, 1, dp) | compute rows w, cp (blk, dp), cn
+#          (blk, K, dp) | gather, scatter DMA semaphore rings (S,) | one
+#          metadata DMA semaphore | [hot tier: hotW, hotC (kH, 1, dp),
+#          two prefix DMA semaphores]
+# ---------------------------------------------------------------------------
+def _sync_copy(src, dst, sem):
+    dma = pltpu.make_async_copy(src, dst, sem)
+    dma.start()
+    dma.wait()
+
+
+def _hbm_pipe_kernel(nblocks, blk, K, num_slots, kH, B,
+                     lr_ref, cnt_ref, meta_hbm, _w_in, _c_in,
+                     w_hbm, c_hbm, loss_ref,
+                     meta_s, buf_w, buf_c, rows_w, rows_cp, rows_cn,
+                     gsem, ssem, msem, *hot):
+    wk = pl.program_id(0)
+    off, _ = meta_layout(blk, K, kH > 0)
+    dp = buf_w.shape[-1]
+    lr = lr_ref[wk]
+
+    hot_w, hot_c, hsem = hot if kH else (None, None, None)
+
+    def count(i):       # n_w | n_c | hazard of this worker
+        return cnt_ref[wk * 3 * nblocks + i]
+
+    if kH:
+        # Pin the hot tier: one bulk prefix DMA per table, VMEM-resident
+        # for the whole step. Disjoint from every cold row (ids ≥ kH), so
+        # it needs no hazard ordering against the cold pipeline.
+        pins = [pltpu.make_async_copy(t.at[wk, pl.ds(0, kH)], h, hsem.at[i])
+                for i, (t, h) in enumerate(((w_hbm, hot_w), (c_hbm, hot_c)))]
+        for dma in pins:
+            dma.start()
+        for dma in pins:
+            dma.wait()
+
+    def meta(s, name, j):
+        return meta_s[s, 0, off[name] + j]
+
     def run_rows(b, s, gather, wait):
-        def w_dma(j):
-            pair = (w_hbm.at[uw_ref[b, j]], buf_w.at[s, j])
-            src, dst = pair if gather else pair[::-1]
-            return pltpu.make_async_copy(src, dst, (gsem if gather
-                                                    else ssem).at[s])
+        """Matched start/wait loops over block b's valid (cold) rows."""
+        if gather and not wait:
+            # block b's metadata → ring slot s; it stays until block
+            # b+S's gathers, which come after this block's last wait
+            _sync_copy(meta_hbm.at[wk, b], meta_s.at[s], msem)
+        sem = (gsem if gather else ssem).at[s]
 
-        def c_dma(j):
-            pair = (c_hbm.at[uc_ref[b, j]], buf_c.at[s, j])
-            src, dst = pair if gather else pair[::-1]
-            return pltpu.make_async_copy(src, dst, (gsem if gather
-                                                    else ssem).at[s])
-
-        def go(dma):
-            def body(j, _):
-                d_ = dma(j)
-                d_.wait() if wait else d_.start()
-                return 0
+        def rows(table, buf, name):
+            def body(j, carry):
+                hbm_row = table.at[wk, meta(s, name, j)]
+                src, dst = ((hbm_row, buf.at[s, j]) if gather
+                            else (buf.at[s, j], hbm_row))
+                dma = pltpu.make_async_copy(src, dst, sem)
+                dma.wait() if wait else dma.start()
+                return carry
             return body
 
-        jax.lax.fori_loop(0, n_w_ref[b], go(w_dma), 0)
-        jax.lax.fori_loop(0, n_c_ref[b], go(c_dma), 0)
+        jax.lax.fori_loop(0, count(b), rows(w_hbm, buf_w, "uw"), 0)
+        jax.lax.fori_loop(0, count(nblocks + b), rows(c_hbm, buf_c, "uc"), 0)
 
-    return run_rows
+    def compute(b, s):
+        def at_row(j, id_name, pos_name, hot_table, buf, fn):
+            """fn(row ref) on the row pair j's element lives in: the
+            pinned hot prefix for a hot id, else its ring-slot row."""
+            def cold():
+                fn(buf.at[s, pl.ds(meta(s, pos_name, j), 1)])
+            if not kH:
+                return cold()
+            i = meta(s, id_name, j)
+            pl.when(i < kH)(lambda: fn(hot_table.at[pl.ds(i, 1)]))
+            pl.when(i >= kH)(cold)
 
+        def gather_pair(j, carry):
+            def put(dst, idx):
+                def fn(row):
+                    dst[idx] = row[...].reshape((1,) * (dst.ndim - 1) + (dp,))
+                return fn
+            one = pl.ds(j, 1)
+            at_row(j, "cen", "w_pos", hot_w, buf_w,
+                   put(rows_w, (one, slice(None))))
+            at_row(j, "ctx", "cp_pos", hot_c, buf_c,
+                   put(rows_cp, (one, slice(None))))
+            for k in range(K):
+                at_row(j * K + k, "neg", "cn_pos", hot_c, buf_c,
+                       put(rows_cn, (one, pl.ds(k, 1), slice(None))))
+            return carry
 
-def execute_schedule(nblocks, num_slots, hz_ref, run_rows, compute):
-    """Walk :func:`kernel_schedule`, resolving guards against the SMEM
-    hazard flags with ``pl.when`` (conjunction of the guard conditions).
-    ``run_rows`` is a :func:`make_row_dma_runner` closure; ``compute``
-    is the per-block compute callback ``compute(b, s)``."""
+        jax.lax.fori_loop(0, blk, gather_pair, 0)
+        # the exact expressions of the sparse reference — what the
+        # bit-equivalence contract stands on
+        loss, d_w, d_cp, d_cn = sparse_row_grads_per_pair(
+            rows_w[...], rows_cp[...], rows_cn[...])
+        m = (jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)
+             < B - b * blk).astype(jnp.float32)            # (blk, 1)
+        rows_w[...] = -lr * (d_w * m)
+        rows_cp[...] = -lr * (d_cp * m)
+        rows_cn[...] = -lr * (d_cn * m[:, :, None])
+        loss_ref[wk * nblocks + b] = jnp.sum(loss[:, None] * m)
+
+        # Same accumulation order as the reference's scatter-adds (W,
+        # then C-context, then C-negatives pair-major): duplicate rows
+        # add the same addends to the same base values in the same
+        # order, so the single write-back per row is bit-identical.
+        def add(src, idx):
+            def fn(row):
+                row[...] = row[...] + src[idx].reshape(row.shape)
+            return fn
+
+        def scatter_w(j, carry):
+            at_row(j, "cen", "w_pos", hot_w, buf_w,
+                   add(rows_w, (pl.ds(j, 1), slice(None))))
+            return carry
+
+        def scatter_cp(j, carry):
+            at_row(j, "ctx", "cp_pos", hot_c, buf_c,
+                   add(rows_cp, (pl.ds(j, 1), slice(None))))
+            return carry
+
+        def scatter_cn(j, carry):
+            for k in range(K):
+                at_row(j * K + k, "neg", "cn_pos", hot_c, buf_c,
+                       add(rows_cn, (pl.ds(j, 1), pl.ds(k, 1), slice(None))))
+            return carry
+
+        for body in (scatter_w, scatter_cp, scatter_cn):
+            jax.lax.fori_loop(0, blk, body, 0)
+
     ops = {
         "gather": lambda b, s: run_rows(b, s, gather=True, wait=False),
         "wait_gather": lambda b, s: run_rows(b, s, gather=True, wait=True),
@@ -396,65 +579,185 @@ def execute_schedule(nblocks, num_slots, hz_ref, run_rows, compute):
         "scatter": lambda b, s: run_rows(b, s, gather=False, wait=False),
         "wait_scatter": lambda b, s: run_rows(b, s, gather=False, wait=True),
     }
-    for op, b, s, guard in kernel_schedule(nblocks, num_slots):
-        if guard is None:
-            ops[op](b, s)
-        else:
-            pred = None
-            for f, want in guard:
-                c = (hz_ref[f] != 0) if want else (hz_ref[f] == 0)
-                pred = c if pred is None else jnp.logical_and(pred, c)
-            pl.when(pred)(functools.partial(ops[op], b, s))
+
+    def guarded(op, blk, conds):
+        """ops[op] on block blk under the conjunction of conds."""
+        run = functools.partial(ops[op], blk, blk % num_slots)
+        if not conds:
+            return run()
+        pred = conds[0]
+        for c in conds[1:]:
+            pred = jnp.logical_and(pred, c)
+        pl.when(pred)(run)
+
+    def hazard(f, want):
+        flag = count(2 * nblocks + jnp.clip(f, 0, nblocks - 1))
+        return (flag != 0) if want else (flag == 0)
+
+    def in_range(i):
+        return jnp.logical_and(i >= 0, i < nblocks)
+
+    # The schedule as a loop over positions — code size, and so compile
+    # time, independent of the block count. Each template event runs
+    # under its existence test (block and hazards in range) and its
+    # hazard guard, both read from SMEM at run time.
+    template = position_template(num_slots)
+
+    def position(b, carry):
+        for op, off, guard in template:
+            conds = [in_range(b + off)] if off else []
+            conds += [in_range(b + f) for f, _ in guard if f]
+            conds += [hazard(b + f, want) for f, want in guard]
+            guarded(op, b + off, conds)
+        return carry
+
+    ops["gather"](0, 0)
+    jax.lax.fori_loop(0, nblocks, position, 0)
+    for op, blk, _, guard in schedule_tail(nblocks, num_slots):
+        guarded(op, blk, [hazard(f, want) for f, want in guard or ()])
+
+    if kH:
+        # write the hot tier back after every cold write-back has drained
+        # (the schedule's tail waits)
+        outs = [pltpu.make_async_copy(h, t.at[wk, pl.ds(0, kH)], hsem.at[i])
+                for i, (t, h) in enumerate(((w_hbm, hot_w), (c_hbm, hot_c)))]
+        for dma in outs:
+            dma.start()
+        for dma in outs:
+            dma.wait()
 
 
-# ---------------------------------------------------------------------------
-# Kernel body. Operand order:
-#   lr (1,) f32 SMEM | n_w, n_c, hazard (nblocks,) i32 SMEM
-#   uw | uc | w_pos | cp_pos | cn_pos | mask                 [VMEM]
-#   W, C (V, d) HBM (ANY), aliased to the first two outputs
-# outputs: W', C' (ANY) | per-pair masked loss (nblocks, blk) VMEM
-# scratch: bufW (S, R_W, d) | bufC (S, R_C, d) | gather + scatter DMA
-#          semaphore rings (S,)
-# ---------------------------------------------------------------------------
-def _pipe_kernel(nblocks, K, num_slots, lr_ref, n_w_ref, n_c_ref, hz_ref,
-                 uw_ref, uc_ref, wpos_ref, cppos_ref, cnpos_ref, mask_ref,
-                 _w_in, _c_in, w_hbm, c_hbm, loss_ref,
-                 buf_w, buf_c, gsem, ssem):
-    blk = wpos_ref.shape[1]
-    d = buf_w.shape[2]
-    lr = lr_ref[0]
-    run_rows = make_row_dma_runner(uw_ref, uc_ref, n_w_ref, n_c_ref,
-                                   w_hbm, c_hbm, buf_w, buf_c, gsem, ssem)
-
-    def compute(b, s):
-        W_blk = buf_w[s]                                    # (R_W, d)
-        C_blk = buf_c[s]                                    # (R_C, d)
-        w_pos = wpos_ref[b]
-        cp_pos = cppos_ref[b]
-        cn_pos = cnpos_ref[b]
-        w = W_blk[w_pos]                                    # (blk, d)
-        cp = C_blk[cp_pos]                                  # (blk, d)
-        cn = C_blk[cn_pos].reshape(blk, K, d)               # (blk, K, d)
-        # the exact expressions of the sparse reference — what the
-        # bit-equivalence contract stands on
-        loss, d_w, d_cp, d_cn = sparse_row_grads_per_pair(w, cp, cn)
-        m = mask_ref[b]                                     # (blk,)
-        u_w = -lr * (d_w * m[:, None])
-        u_cp = -lr * (d_cp * m[:, None])
-        u_cn = (-lr * (d_cn * m[:, None, None])).reshape(blk * K, d)
-        # same scatter-add order as the reference (W, then C-context,
-        # then C-negatives): duplicate rows accumulate identically, so
-        # the single write-back per row is bit-identical to its RMW chain
-        buf_w[s] = W_blk.at[w_pos].add(u_w)
-        buf_c[s] = C_blk.at[cp_pos].add(u_cp).at[cn_pos].add(u_cn)
-        loss_ref[b] = loss * m
-
-    execute_schedule(nblocks, num_slots, hz_ref, run_rows, compute)
+def vmem_terms(blk: int, K: int, dp: int, num_slots: int, hot_rows: int
+               ) -> dict[str, int]:
+    """Bytes of VMEM scratch the kernel allocates, by buffer: the W and C
+    row rings, the compute rows (the negatives' K padded to 8 sublanes)
+    and the pinned hot prefix of both tables."""
+    return {"ring_w": 4 * num_slots * blk * dp,
+            "ring_c": 4 * num_slots * blk * (K + 1) * dp,
+            "compute_rows": 4 * blk * (2 + -(-K // 8) * 8) * dp,
+            "hot_prefix": 4 * 2 * hot_rows * dp}
 
 
-# ---------------------------------------------------------------------------
+def _pallas_call(n, V, dp, nblocks, blk, K, num_slots, kH, B, interpret):
+    _, M = meta_layout(blk, K, kH > 0)
+    S = num_slots
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    scratch = [
+        pltpu.SMEM((S, 1, M), jnp.int32),                    # metadata ring
+        pltpu.VMEM((S, blk, 1, dp), jnp.float32),            # W row ring
+        pltpu.VMEM((S, blk * (K + 1), 1, dp), jnp.float32),  # C row ring
+        pltpu.VMEM((blk, dp), jnp.float32),                  # compute rows
+        pltpu.VMEM((blk, dp), jnp.float32),
+        pltpu.VMEM((blk, K, dp), jnp.float32),
+        pltpu.SemaphoreType.DMA((S,)),                       # gathers
+        pltpu.SemaphoreType.DMA((S,)),                       # scatters
+        pltpu.SemaphoreType.DMA(()),                         # metadata
+    ]
+    if kH:
+        scratch += [pltpu.VMEM((kH, 1, dp), jnp.float32),    # hot W
+                    pltpu.VMEM((kH, 1, dp), jnp.float32),    # hot C
+                    pltpu.SemaphoreType.DMA((2,))]
+    # Mosaic's own intermediates of the compute (a few (blk, K, dp)
+    # values) come on top of the scratch
+    need = sum(vmem_terms(blk, K, dp, S, kH).values()) + 6 * 4 * blk * 8 * dp
+    table = jax.ShapeDtypeStruct((n, V, 1, dp), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_hbm_pipe_kernel, nblocks, blk, K, S, kH, B),
+        grid=(n,),
+        in_specs=[smem, smem, any_, any_, any_],
+        out_specs=[any_, any_, smem],
+        out_shape=[table, table,
+                   jax.ShapeDtypeStruct((n * nblocks,), jnp.float32)],
+        # in-place tables: operands 3, 4 alias outputs 0, 1
+        input_output_aliases={3: 0, 4: 1},
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(min(max(need, 32 << 20), 120 << 20))),
+        interpret=interpret,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _worker_kernel(V, dp, nblocks, blk, K, num_slots, kH, B, interpret):
+    """The kernel as a function of stacked workers ``(lr (n,), counts, meta,
+    W, C)`` with leading worker axis n. Mosaic cannot lower a
+    vmapped ``pallas_call`` with HBM operands, so vmap over this
+    function folds the mapped axis into the worker axis — the kernel's
+    grid — instead of letting Pallas batch it."""
+    @jax.custom_batching.custom_vmap
+    def stacked(lr, cnt, meta, W, C):
+        return tuple(_pallas_call(W.shape[0], V, dp, nblocks, blk, K,
+                                  num_slots, kH, B, interpret)(
+            lr, cnt.reshape(-1), meta, W, C))
+
+    @stacked.def_vmap
+    def _fold(axis_size, in_batched, *args):
+        args = [a if batched else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, batched in zip(args, in_batched)]
+        outs = stacked(*[a.reshape((-1,) + a.shape[2:]) for a in args])
+        return (tuple(o.reshape((axis_size, -1) + o.shape[1:]) for o in outs),
+                (True,) * len(outs))
+
+    return stacked
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "negatives", "block_pairs", "ring_depth", "interpret"))
+    "negatives", "block_pairs", "ring_depth", "hot_rows", "interpret"))
+def fused_pipe_rows_step(
+    tables: dict,
+    centers: jax.Array,
+    contexts: jax.Array,
+    table: dict,
+    key: jax.Array,
+    lr: jax.Array,
+    *,
+    negatives: int,
+    block_pairs: int,
+    ring_depth: int,
+    hot_rows: int,
+    interpret: bool,
+) -> tuple[dict, jax.Array]:
+    """One step of the pipelined (``hot_rows = 0``) or tiered HBM kernel
+    on tables already in the kernels' row layout
+    (:func:`to_row_layout`) — the form a scan of steps carries, so that
+    the layout is converted once per epoch rather than once per step.
+    ``hot_rows`` must lie in ``[0, V]``."""
+    V, _, dp = tables["W"].shape
+    B = centers.shape[0]
+    K = negatives
+    neg_ids = fused_negative_ids(_as_seed(key), table["prob"], table["alias"],
+                                 (B, K))
+    plan = plan_blocks(centers, contexts, neg_ids, V, block_pairs,
+                       hot_rows=hot_rows, ring_depth=ring_depth)
+    meta, cnt = block_meta(plan, hot_rows > 0)
+    run = _worker_kernel(V, dp, plan.nblocks, plan.block_pairs, K,
+                         ring_depth, hot_rows, B, interpret)
+    W, C, loss = run(jnp.reshape(lr, (1,)).astype(jnp.float32), cnt[None],
+                     meta[None], tables["W"][None], tables["C"][None])
+    # padded pairs were masked to exactly-zero loss, so the batch mean
+    # divides by the true pair count
+    return {"W": W[0], "C": C[0]}, jnp.sum(loss) / B
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "negatives", "block_pairs", "ring_depth", "hot_rows", "interpret"))
+def fused_pipe_step(params, centers, contexts, table, key, lr, *,
+                    negatives, block_pairs, ring_depth, hot_rows,
+                    interpret=None):
+    """:func:`fused_pipe_rows_step` on ``(V, d)`` tables: converts to the
+    row layout and back around the one step."""
+    interpret = resolve_interpret(interpret)
+    d = params["W"].shape[1]
+    rows = {k: to_row_layout(v, interpret) for k, v in params.items()}
+    rows, loss = fused_pipe_rows_step(
+        rows, centers, contexts, table, key, lr, negatives=negatives,
+        block_pairs=block_pairs, ring_depth=ring_depth, hot_rows=hot_rows,
+        interpret=interpret)
+    return {k: from_row_layout(v, d) for k, v in rows.items()}, loss
+
+
 def sgns_fused_pipe_step(
     params: dict,
     centers: jax.Array,
@@ -466,64 +769,20 @@ def sgns_fused_pipe_step(
     negatives: int = 5,
     block_pairs: int = 256,
     ring_depth: int = NUM_SLOTS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[dict, jax.Array]:
     """One SGNS step through the pipelined HBM engine.
 
     Same contract as :func:`repro.kernels.sgns_fused_hbm.sgns_fused_hbm_step`
-    with ``sequential=False`` — and bit-identical to it (and therefore
-    to the per-block ``train_step_sparse`` reference on the replayed
-    negatives) at every ``ring_depth``: the planner replays the same
-    counter-PRNG draw, and the schedule's hazard guards preserve the
-    chain's read-after-write semantics exactly. One ``pallas_call``
-    covers the whole batch.
+    with ``sequential=False`` — and bit-identical to it in interpret mode
+    (and therefore to the per-block ``train_step_sparse`` reference on
+    the replayed negatives) at every ``ring_depth``: the planner replays
+    the same counter-PRNG draw, and the schedule's hazard guards
+    preserve the chain's read-after-write semantics exactly. One
+    ``pallas_call`` covers the whole batch. ``interpret=None`` compiles
+    with Mosaic on a TPU and interprets elsewhere.
     """
-    V, d = params["W"].shape
-    B = centers.shape[0]
-    K = negatives
-    seed = _as_seed(key)
-    neg_ids = fused_negative_ids(seed, table["prob"], table["alias"], (B, K))
-    plan = plan_blocks(centers, contexts, neg_ids, V, block_pairs,
-                       ring_depth=ring_depth)
-    nblocks, blk = plan.nblocks, plan.block_pairs
-    S = ring_depth
-
-    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
-    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        functools.partial(_pipe_kernel, nblocks, K, S),
-        in_specs=[
-            smem(),                                 # lr (1,)
-            smem(), smem(), smem(),                 # n_w, n_c, hazard
-            vmem(), vmem(),                         # uw, uc
-            vmem(), vmem(), vmem(),                 # w_pos, cp_pos, cn_pos
-            vmem(),                                 # mask
-            pl.BlockSpec(memory_space=pltpu.ANY),   # W (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),   # C (HBM)
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            vmem(),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((V, d), params["W"].dtype),
-            jax.ShapeDtypeStruct((V, d), params["C"].dtype),
-            jax.ShapeDtypeStruct((nblocks, blk), jnp.float32),
-        ],
-        # in-place tables: HBM operands 10, 11 alias outputs 0, 1
-        input_output_aliases={10: 0, 11: 1},
-        scratch_shapes=[
-            pltpu.VMEM((S, blk, d), jnp.float32),            # W rows
-            pltpu.VMEM((S, blk * (K + 1), d), jnp.float32),  # C rows
-            pltpu.SemaphoreType.DMA((S,)),                   # gathers
-            pltpu.SemaphoreType.DMA((S,)),                   # scatters
-        ],
-        interpret=interpret,
-    )(jnp.reshape(lr, (1,)).astype(jnp.float32),
-      plan.n_w, plan.n_c, plan.hazard,
-      plan.uw, plan.uc, plan.w_pos, plan.cp_pos, plan.cn_pos, plan.mask,
-      params["W"], params["C"])
-    # padded pairs were masked to exactly-zero loss, so the batch mean
-    # divides by the true pair count
-    return {"W": out[0], "C": out[1]}, jnp.sum(out[2]) / B
+    return fused_pipe_step(params, centers, contexts, table, key, lr,
+                           negatives=negatives, block_pairs=block_pairs,
+                           ring_depth=ring_depth, hot_rows=0,
+                           interpret=interpret)

@@ -28,8 +28,8 @@ build-time-known row index ``hot_rows``:
   path: the :func:`repro.kernels.sgns_fused_pipe.plan_blocks` planner
   (this module's single source of truth for the cold side) dedups,
   position-maps and hazard-flags over cold rows only, and the same
-  :func:`~repro.kernels.sgns_fused_pipe.kernel_schedule` drives the
-  ``ring_depth``-slot DMA ring.
+  schedule (:func:`~repro.kernels.sgns_fused_pipe.position_template`)
+  drives the ``ring_depth``-slot DMA ring.
 
 The result is a tunable dial on the VMEM-vs-HBM cliff:
 ``hot_rows = 0`` is the pure pipeline (the ``pallas_fused_pipe``
@@ -44,35 +44,19 @@ Bit-equivalence contract: identical (interpret mode) to
 per-block sparse reference on the replayed counter-PRNG negatives — at
 **every** hot fraction. Tier routing preserves it exactly: each row id
 belongs to exactly one tier, so each physical row receives exactly the
-reference's update sequence through exactly one path; the other path's
-scatter lands in write-off memory that is never DMA'd back — a hot
-id's cold-side position is a pad slot ``≥ n`` (the sentinel sorts past
-every cold id, and the write-back loop covers only slots ``< n``), and
-a cold id's hot-side index is the spill row at ``kH`` (the prefix
-write-back copies only rows ``[0, kH)``). Gathers select per element
-between the hot VMEM copy and the cold row buffer (``jnp.where`` on
-the tier predicate), so the compute sees bit-identical inputs either
-way.
+reference's update sequence through exactly one path: each pair
+element's row loads and adds go to its tier's copy alone (a scalar branch on
+``id < hot_rows`` per pair element), so the compute sees bit-identical
+inputs either way and duplicates accumulate in the reference's order.
+
+The kernel itself is the pipelined one
+(:func:`repro.kernels.sgns_fused_pipe.fused_pipe_rows_step`) with a
+non-zero hot tier; this module holds the tiered entry point.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from repro.core.sgns import sparse_row_grads_per_pair
-from repro.kernels.sgns_fused import _as_seed, fused_negative_ids
-from repro.kernels.sgns_fused_pipe import (
-    NUM_SLOTS,
-    execute_schedule,
-    make_row_dma_runner,
-    plan_blocks,
-    sgns_fused_pipe_step,
-)
+from repro.kernels.sgns_fused_pipe import NUM_SLOTS, fused_pipe_step
 
 # Bulk hot-prefix DMAs per step beyond the cold pipeline's schedule: two
 # prefix loads at step start (W, C) + two write-backs at step end — the
@@ -81,187 +65,33 @@ from repro.kernels.sgns_fused_pipe import (
 HOT_PREFIX_DMA_OPS = 4
 
 
-# ---------------------------------------------------------------------------
-# Kernel body. Operand order:
-#   lr (1,) f32 SMEM | n_w, n_c, hazard (nblocks,) i32 SMEM
-#   uw | uc | w_pos | cp_pos | cn_pos | mask | cen | ctx | neg   [VMEM]
-#   W, C (V, d) HBM (ANY), aliased to the first two outputs
-# outputs: W', C' (ANY) | per-pair masked loss (nblocks, blk) VMEM
-# scratch: bufW (S, R_W, d) | bufC (S, R_C, d) | hotW, hotC (kH+1, d —
-#          the trailing spill row absorbs cold rows' write-off updates) |
-#          gather + scatter DMA semaphore rings (S,) | hot DMA sems (2,)
-# ---------------------------------------------------------------------------
-def _tiered_kernel(nblocks, K, num_slots, kH,
-                   lr_ref, n_w_ref, n_c_ref, hz_ref,
-                   uw_ref, uc_ref, wpos_ref, cppos_ref, cnpos_ref, mask_ref,
-                   cen_ref, ctx_ref, neg_ref, _w_in, _c_in,
-                   w_hbm, c_hbm, loss_ref,
-                   buf_w, buf_c, hot_w, hot_c, gsem, ssem, hsem):
-    blk = wpos_ref.shape[1]
-    d = buf_w.shape[2]
-    lr = lr_ref[0]
-
-    # Pin the hot tier: one bulk prefix DMA per table, VMEM-resident for
-    # the whole step (the spill row at index kH stays uninitialized —
-    # it only ever absorbs write-off updates). Disjoint from every cold
-    # row (ids ≥ kH), so it needs no hazard ordering against the cold
-    # pipeline.
-    ld_w = pltpu.make_async_copy(w_hbm.at[pl.ds(0, kH)],
-                                 hot_w.at[pl.ds(0, kH)], hsem.at[0])
-    ld_c = pltpu.make_async_copy(c_hbm.at[pl.ds(0, kH)],
-                                 hot_c.at[pl.ds(0, kH)], hsem.at[1])
-    ld_w.start()
-    ld_c.start()
-    ld_w.wait()
-    ld_c.wait()
-
-    run_rows = make_row_dma_runner(uw_ref, uc_ref, n_w_ref, n_c_ref,
-                                   w_hbm, c_hbm, buf_w, buf_c, gsem, ssem)
-
-    def compute(b, s):
-        W_blk = buf_w[s]                                    # (R_W, d)
-        C_blk = buf_c[s]                                    # (R_C, d)
-        cen = cen_ref[b]                                    # (blk,)
-        ctx = ctx_ref[b]                                    # (blk,)
-        neg = neg_ref[b]                                    # (blk·K,)
-        hot_wm = cen < kH                                   # tier predicates
-        hot_cpm = ctx < kH
-        hot_cnm = neg < kH
-        # hot ids are direct indices into the VMEM prefix; cold ids are
-        # routed to the spill row at index kH, which absorbs their
-        # (garbage) hot-side updates and is never written back
-        i_w = jnp.where(hot_wm, cen, jnp.int32(kH))
-        i_cp = jnp.where(hot_cpm, ctx, jnp.int32(kH))
-        i_cn = jnp.where(hot_cnm, neg, jnp.int32(kH))
-        # two-source gathers: per element, the hot VMEM copy or the
-        # cold row buffer — bit-identical inputs either way (the
-        # unselected side reads a spill/pad slot and is discarded)
-        w = jnp.where(hot_wm[:, None], hot_w[i_w], W_blk[wpos_ref[b]])
-        cp = jnp.where(hot_cpm[:, None], hot_c[i_cp], C_blk[cppos_ref[b]])
-        cn = jnp.where(hot_cnm[:, None], hot_c[i_cn],
-                       C_blk[cnpos_ref[b]]).reshape(blk, K, d)
-        # the exact expressions of the sparse reference — what the
-        # bit-equivalence contract stands on
-        loss, d_w, d_cp, d_cn = sparse_row_grads_per_pair(w, cp, cn)
-        m = mask_ref[b]                                     # (blk,)
-        u_w = -lr * (d_w * m[:, None])
-        u_cp = -lr * (d_cp * m[:, None])
-        u_cn = (-lr * (d_cn * m[:, None, None])).reshape(blk * K, d)
-        # dual unmasked scatters, same W → C-context → C-negative order
-        # as the reference: each physical row receives exactly one
-        # path's updates, because the other path's target is write-off
-        # memory — a hot id's cold position is a pad slot ≥ n_w/n_c
-        # (sentinel ids sort past every cold id, and only slots < n are
-        # DMA'd back), and a cold id's hot index is the spill row kH
-        # (the write-back copies only the [0, kH) prefix). Duplicates
-        # accumulate in identical order either way.
-        buf_w[s] = W_blk.at[wpos_ref[b]].add(u_w)
-        buf_c[s] = (C_blk.at[cppos_ref[b]].add(u_cp)
-                         .at[cnpos_ref[b]].add(u_cn))
-        hot_w[...] = hot_w[...].at[i_w].add(u_w)
-        hot_c[...] = (hot_c[...].at[i_cp].add(u_cp)
-                                .at[i_cn].add(u_cn))
-        loss_ref[b] = loss * m
-
-    execute_schedule(nblocks, num_slots, hz_ref, run_rows, compute)
-
-    # write the hot tier back: one bulk prefix DMA per table, after
-    # every cold write-back has drained (the schedule's tail waits)
-    st_w = pltpu.make_async_copy(hot_w.at[pl.ds(0, kH)],
-                                 w_hbm.at[pl.ds(0, kH)], hsem.at[0])
-    st_c = pltpu.make_async_copy(hot_c.at[pl.ds(0, kH)],
-                                 c_hbm.at[pl.ds(0, kH)], hsem.at[1])
-    st_w.start()
-    st_c.start()
-    st_w.wait()
-    st_c.wait()
-
-
-# ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=(
-    "negatives", "block_pairs", "hot_rows", "ring_depth", "interpret"))
 def sgns_fused_tiered_step(
     params: dict,
-    centers: jax.Array,
-    contexts: jax.Array,
+    centers,
+    contexts,
     table: dict,
-    key: jax.Array,
-    lr: jax.Array,
+    key,
+    lr,
     *,
     negatives: int = 5,
     block_pairs: int = 256,
     hot_rows: int = 256,
     ring_depth: int = NUM_SLOTS,
-    interpret: bool = True,
-) -> tuple[dict, jax.Array]:
+    interpret: bool | None = None,
+):
     """One SGNS step through the frequency-tiered hot/cold engine.
 
     Same contract as
     :func:`repro.kernels.sgns_fused_pipe.sgns_fused_pipe_step` — and
-    bit-identical to it (and to ``sgns_fused_hbm_step`` / the per-block
-    sparse reference on the replayed negatives) at every ``hot_rows``
-    setting. ``hot_rows`` is clamped to ``[0, V]``: 0 delegates to the
-    pure pipeline, ``V`` is pure-VMEM-resident (zero per-block row
+    bit-identical to it in interpret mode (and to ``sgns_fused_hbm_step``
+    / the per-block sparse reference on the replayed negatives) at every
+    ``hot_rows`` setting. ``hot_rows`` is clamped to ``[0, V]``: 0 is
+    the pure pipeline, ``V`` is pure-VMEM-resident (zero per-block row
     DMAs). One ``pallas_call`` covers the whole batch.
     """
-    V, d = params["W"].shape
-    kH = max(0, min(int(hot_rows), V))
-    if kH == 0:
-        return sgns_fused_pipe_step(
-            params, centers, contexts, table, key, lr, negatives=negatives,
-            block_pairs=block_pairs, ring_depth=ring_depth,
-            interpret=interpret)
-
-    B = centers.shape[0]
-    K = negatives
-    seed = _as_seed(key)
-    neg_ids = fused_negative_ids(seed, table["prob"], table["alias"], (B, K))
-    plan = plan_blocks(centers, contexts, neg_ids, V, block_pairs,
-                       hot_rows=kH, ring_depth=ring_depth)
-    nblocks, blk = plan.nblocks, plan.block_pairs
-    S = ring_depth
-
-    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
-    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        functools.partial(_tiered_kernel, nblocks, K, S, kH),
-        in_specs=[
-            smem(),                                 # lr (1,)
-            smem(), smem(), smem(),                 # n_w, n_c, hazard
-            vmem(), vmem(),                         # uw, uc
-            vmem(), vmem(), vmem(),                 # w_pos, cp_pos, cn_pos
-            vmem(),                                 # mask
-            vmem(), vmem(), vmem(),                 # cen, ctx, neg ids
-            pl.BlockSpec(memory_space=pltpu.ANY),   # W (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),   # C (HBM)
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            vmem(),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((V, d), params["W"].dtype),
-            jax.ShapeDtypeStruct((V, d), params["C"].dtype),
-            jax.ShapeDtypeStruct((nblocks, blk), jnp.float32),
-        ],
-        # in-place tables: HBM operands 13, 14 alias outputs 0, 1
-        input_output_aliases={13: 0, 14: 1},
-        scratch_shapes=[
-            pltpu.VMEM((S, blk, d), jnp.float32),            # cold W rows
-            pltpu.VMEM((S, blk * (K + 1), d), jnp.float32),  # cold C rows
-            pltpu.VMEM((kH + 1, d), jnp.float32),            # hot W + spill
-            pltpu.VMEM((kH + 1, d), jnp.float32),            # hot C + spill
-            pltpu.SemaphoreType.DMA((S,)),                   # gathers
-            pltpu.SemaphoreType.DMA((S,)),                   # scatters
-            pltpu.SemaphoreType.DMA((2,)),                   # hot prefix
-        ],
-        interpret=interpret,
-    )(jnp.reshape(lr, (1,)).astype(jnp.float32),
-      plan.n_w, plan.n_c, plan.hazard,
-      plan.uw, plan.uc, plan.w_pos, plan.cp_pos, plan.cn_pos, plan.mask,
-      plan.cen, plan.ctx, plan.neg,
-      params["W"], params["C"])
-    # padded pairs were masked to exactly-zero loss, so the batch mean
-    # divides by the true pair count
-    return {"W": out[0], "C": out[1]}, jnp.sum(out[2]) / B
+    V = params["W"].shape[0]
+    return fused_pipe_step(params, centers, contexts, table, key, lr,
+                           negatives=negatives, block_pairs=block_pairs,
+                           ring_depth=ring_depth,
+                           hot_rows=max(0, min(int(hot_rows), V)),
+                           interpret=interpret)

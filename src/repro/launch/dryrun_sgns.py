@@ -257,7 +257,8 @@ def main(argv=None):
     if args.plan_only:
         assert plans, "ingestion planning produced no per-host plans"
         return
-    mesh = make_worker_mesh(args.workers)
+    # one simulated device per worker
+    mesh = make_worker_mesh(args.workers, jax.devices()[:args.workers])
     rows = [run(c, mesh, args.workers, args.steps, args.batch,
                 vmem_budget_mb=args.vmem_budget_mb)
             for c in args.cases.split(",")]

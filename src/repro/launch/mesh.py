@@ -24,10 +24,17 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return jax.make_mesh(shape, axes)
 
 
-def make_worker_mesh(num_workers: int) -> Mesh:
-    """Mesh for the paper's async SGNS training: one axis, one worker per
-    slice, zero collectives inside the step."""
-    return jax.make_mesh((num_workers,), ("worker",))
+def make_worker_mesh(num_workers: int, devices=None) -> Mesh:
+    """Mesh for the paper's async SGNS training: one ``worker`` axis over
+    ``devices`` (default: every device of the runtime), each device
+    training ``num_workers / len(devices)`` workers, zero collectives
+    inside the step."""
+    devices = list(jax.devices() if devices is None else devices)
+    if num_workers % len(devices):
+        raise ValueError(
+            f"{num_workers} workers do not divide over {len(devices)} "
+            f"devices; use a multiple of the device count")
+    return Mesh(np.asarray(devices), ("worker",))
 
 
 def make_smoke_mesh() -> Mesh:
@@ -39,14 +46,16 @@ def multihost_train_kwargs(num_workers: int,
                            processes: int | None = None
                            ) -> tuple[int, dict]:
     """Resolve a CLI ``--processes`` value (None → the jax runtime's)
-    and the extra ``train_submodels`` kwargs a multi-host run needs:
-    per-host ingestion only makes sense under ``shard_map`` on a worker
-    mesh, where the per-chunk input assembly is the sole inter-host
-    exchange. Shared by ``train_sgns`` and ``train_w2v_100m``."""
+    and the extra ``train_submodels`` kwargs a run on several devices
+    needs: the workers are sharded under ``shard_map`` over a worker mesh
+    of every device — on one host with several chips as on several
+    hosts, where the per-chunk input assembly is the sole inter-host
+    exchange. One device keeps the ``vmap`` backend. Shared by
+    ``train_sgns`` and ``train_w2v_100m``."""
     if processes is None:
         processes = jax.process_count()
     kwargs: dict = {}
-    if processes > 1:
+    if processes > 1 or jax.device_count() > 1:
         kwargs = dict(backend="shard_map", mesh=make_worker_mesh(num_workers))
     return processes, kwargs
 

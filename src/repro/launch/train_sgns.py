@@ -9,10 +9,13 @@ prints paper-style scores + timings. ``--engine`` selects the per-step
 update engine (``sparse``, ``dense``, ``pallas``, ``pallas_fused``,
 ``pallas_fused_hbm``, ``pallas_fused_pipe``, ``pallas_fused_tiered``,
 optionally with a sampler suffix like ``sparse:alias``); Pallas engines
-run in interpret mode on CPU, Mosaic on TPU. ``pallas_fused_hbm`` keeps
-the parameter tables HBM-resident and DMA-streams only the touched rows
-per pair block — the engine family for paper-scale (300k×500)
-sub-models; ``pallas_fused_pipe`` is its double-buffered successor
+run in interpret mode on CPU. On a TPU, ``pallas``, ``pallas_fused_pipe``
+and ``pallas_fused_tiered`` compile under Mosaic, while ``pallas_fused``
+and ``pallas_fused_hbm`` raise (no Mosaic lowering).
+``pallas_fused_hbm`` keeps the parameter tables HBM-resident and
+DMA-streams only the touched rows per pair block — the serial oracle
+for paper-scale (300k×500) sub-models; ``pallas_fused_pipe`` is its
+double-buffered successor
 (deduped row DMAs overlapped with compute behind a hazard-ordering
 block planner), and ``pallas_fused_tiered`` adds frequency-tiered
 placement on top (``--hot-rows`` hottest rows pinned VMEM-resident,
@@ -28,6 +31,7 @@ import numpy as np
 from repro.core.driver import run_pipeline, train_sync_baseline
 from repro.core.engine import get_engine
 from repro.core.sgns import SGNSConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import multihost_train_kwargs
 from repro.data.corpus import SemanticCorpusModel
 from repro.eval.benchmarks import BenchmarkSuite, evaluate_all
@@ -109,6 +113,7 @@ def main(argv=None):
                     help="publish a table version every k folded "
                          "sub-models (default 1: a version per worker)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     # engine-dial overrides only when set: passing hot_rows/ring_depth
     # to an engine without those fields is a clear TypeError
     overrides = {k: v for k, v in (("hot_rows", args.hot_rows),

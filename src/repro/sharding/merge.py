@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.async_trainer import shard_map_compat
 from repro.core.merge import gram_block_partials, reduce_gram_partials
 
 
@@ -66,8 +65,8 @@ def mesh_sharded_gram(A: jax.Array, B: jax.Array, mesh, *,
         allp = jax.lax.all_gather(parts, axis, tiled=True)
         return reduce_gram_partials(allp)
 
-    f = shard_map_compat(local, mesh, in_specs=(P(axis), P(axis)),
-                         out_specs=P())
+    f = jax.shard_map(local, mesh=mesh, in_specs=(P(axis), P(axis)),
+                      out_specs=P(), check_vma=False)
     return f(jnp.asarray(A), jnp.asarray(B))
 
 

@@ -111,11 +111,9 @@ def test_planner_that_drops_hazards_is_caught():
 def _psum_lowered():
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.async_trainer import shard_map_compat
-
     mesh = jax.make_mesh((1,), ("w",))
-    f = shard_map_compat(lambda v: jax.lax.psum(v, "w"), mesh,
-                         in_specs=P("w"), out_specs=P())
+    f = jax.shard_map(lambda v: jax.lax.psum(v, "w"), mesh=mesh,
+                      in_specs=P("w"), out_specs=P(), check_vma=False)
     return jax.jit(f).lower(jax.ShapeDtypeStruct((4,), jnp.float32))
 
 
