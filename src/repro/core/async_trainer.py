@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P, NamedSharding
 
+from repro import obs
 from repro.core import sgns
 from repro.core.engine import get_engine
 from repro.core.sgns import SGNSConfig
@@ -41,7 +42,9 @@ from repro.core.sgns import SGNSConfig
 # Single-worker epoch: scan over a fixed number of steps.
 # ---------------------------------------------------------------------------
 def make_worker_epoch(cfg: SGNSConfig, total_steps: int, engine="sparse"):
-    """Returns epoch_fn(params, centers (S,B), contexts (S,B), neg_table, key, step0).
+    """Returns epoch_fn(params, centers (S,B), contexts (S,B), neg_table,
+    key, step0) -> (params, losses (S,), rows): ``rows`` (int32) is the
+    table rows the engine's kernel moved over the S steps.
 
     ``engine`` (an :class:`repro.core.engine.UpdateEngine` or spec
     string) owns the whole per-step compute: negative draw, row grads,
@@ -56,16 +59,21 @@ def make_worker_epoch(cfg: SGNSConfig, total_steps: int, engine="sparse"):
 
     def epoch_fn(params, centers, contexts, neg_table, key, step0):
         def body(carry, xs):
-            params, key, i = carry
+            params, key, i, moved = carry
             c_b, x_b = xs
             key, sub = jax.random.split(key)
-            params, loss = step(params, c_b, x_b, neg_table, sub, step0 + i)
-            return (params, key, i + 1), loss
+            params, loss, rows = step(params, c_b, x_b, neg_table, sub,
+                                      step0 + i)
+            return (params, key, i + 1, moved + rows), loss
 
-        (params, _, _), losses = jax.lax.scan(
-            body, (engine.pack(params), key, jnp.int32(0)),
+        with jax.named_scope(obs.LAYOUT_SCOPE):
+            packed = engine.pack(params)
+        (params, _, _, moved), losses = jax.lax.scan(
+            body, (packed, key, jnp.int32(0), jnp.int32(0)),
             (centers, contexts))
-        return engine.unpack(params, cfg), losses
+        with jax.named_scope(obs.LAYOUT_SCOPE):
+            params = engine.unpack(params, cfg)
+        return params, losses, moved
 
     return epoch_fn
 
@@ -142,7 +150,7 @@ class AsyncShardTrainer:
             # spec is a pytree prefix, so the alias table's {prob, alias}
             # leaves pick up the worker sharding too.
             in_specs=(spec,) * 6,
-            out_specs=(spec, spec),
+            out_specs=(spec,) * 3,
         )
 
     def _jit_epoch(self):
@@ -188,10 +196,21 @@ class AsyncShardTrainer:
 
     def epoch(self, params, centers, contexts, neg_table, key, step0=0):
         """params: (n,V,d) pytree; centers/contexts: (n,S,B);
-        neg_table: (n,V) CDF or {'prob','alias'} of (n,V) alias tables."""
-        keys = jax.random.split(key, self.num_workers)
-        step0 = jnp.full((self.num_workers,), step0, dtype=jnp.int32)
-        return self._jit_epoch()(params, centers, contexts, neg_table, keys, step0)
+        neg_table: (n,V) CDF or {'prob','alias'} of (n,V) alias tables.
+        Returns ``(params, losses)``; counts the chunk's pairs and the
+        rows (and bytes) its kernel moved (``repro.obs``), read back only
+        when a counter is read."""
+        ids = {"step0": step0} if isinstance(step0, int) else {}
+        with obs.span(obs.TRAIN_DISPATCH, **ids):
+            keys = jax.random.split(key, self.num_workers)
+            step0 = jnp.full((self.num_workers,), step0, dtype=jnp.int32)
+            params, losses, rows = self._jit_epoch()(
+                params, centers, contexts, neg_table, keys, step0)
+            obs.count(obs.PAIRS, centers.size)
+            obs.count(obs.ROWS_MOVED, rows)
+            obs.count(obs.BYTES_MOVED, rows,
+                      times=self.engine.row_bytes(self.cfg))
+        return params, losses
 
     def worker_epoch(self, params, centers, contexts, neg_table, key, step0=0):
         """One worker's chunk, un-vmapped: params (V,d) pytree;
@@ -210,8 +229,9 @@ class AsyncShardTrainer:
         if self._jitted_single is None:
             object.__setattr__(self, "_jitted_single",
                                jax.jit(self._epoch_fn()))
-        return self._jitted_single(params, centers, contexts, neg_table,
-                                   key, jnp.int32(step0))
+        params, losses, _ = self._jitted_single(
+            params, centers, contexts, neg_table, key, jnp.int32(step0))
+        return params, losses
 
     def lower_epoch(self, steps: int, batch: int):
         """Lower the sharded epoch for the dry-run, ShapeDtypeStruct only."""

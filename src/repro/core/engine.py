@@ -82,6 +82,7 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 
 import jax
+import jax.numpy as jnp
 
 from repro.core import sgns
 from repro.core.sgns import SGNSConfig
@@ -129,8 +130,15 @@ class UpdateEngine:
 
     def make_packed_step(self, cfg: SGNSConfig, total_steps: int):
         """:meth:`make_step` on packed tables — what a scan of steps
-        carries, so that the layout is converted once per scan."""
-        return self.make_step(cfg, total_steps)
+        carries, so that the layout is converted once per scan. Its step
+        returns ``(params, mean_loss, rows)``: ``rows`` (int32) counts
+        the table rows the step's kernel moves between HBM and VMEM by
+        DMA, 0 where no kernel moves rows by DMA."""
+        return _no_rows(self.make_step(cfg, total_steps))
+
+    def row_bytes(self, cfg: SGNSConfig) -> int:
+        """Bytes of one table row as the packed step moves it."""
+        return 4 * cfg.dim
 
     def validate(self, *, vocab_size: int | None = None) -> None:
         """Check dials that only make sense against a model shape
@@ -141,6 +149,15 @@ class UpdateEngine:
     def describe(self) -> str:
         """Human-readable ``"name:sampler"`` tag (log/bench labels)."""
         return f"{self.name}:{self.sampler}"
+
+
+def _no_rows(step):
+    """``step`` with a count of 0 DMA-moved rows beside its loss."""
+    def packed(*args):
+        params, loss = step(*args)
+        return params, loss, jnp.int32(0)
+
+    return packed
 
 
 @dataclass(frozen=True)
@@ -344,10 +361,12 @@ class FusedPipePallasEngine(FusedHBMPallasEngine):
 
     def make_packed_step(self, cfg: SGNSConfig, total_steps: int):
         """One pipelined-kernel step on row-layout tables (multi-slot DMA
-        ring, deduped row traffic, the hot tier when ``hot_rows > 0``);
-        ``sequential=True`` falls back to the HBM oracle."""
+        ring, deduped row traffic, the hot tier when ``hot_rows > 0``),
+        counting the rows its kernel moves; ``sequential=True`` falls
+        back to the HBM oracle, whose rows are not counted."""
         if self.sequential:
-            return FusedHBMPallasEngine.make_step(self, cfg, total_steps)
+            return _no_rows(FusedHBMPallasEngine.make_step(
+                self, cfg, total_steps))
         from repro.kernels.sgns_fused_pipe import fused_pipe_rows_step
 
         interpret = resolve_interpret(self.interpret)
@@ -369,10 +388,16 @@ class FusedPipePallasEngine(FusedHBMPallasEngine):
         packed_step = self.make_packed_step(cfg, total_steps)
 
         def step(params, *args):
-            packed, loss = packed_step(self.pack(params), *args)
+            packed, loss, _ = packed_step(self.pack(params), *args)
             return self.unpack(packed, cfg), loss
 
         return step
+
+    def row_bytes(self, cfg: SGNSConfig) -> int:
+        """Bytes of one ``(1, dp)`` row of the kernels' layout."""
+        from repro.kernels.sgns_fused_pipe import row_width
+
+        return 4 * row_width(cfg.dim, resolve_interpret(self.interpret))
 
 
 @dataclass(frozen=True)

@@ -11,6 +11,7 @@ RANDOM SAMPLING. No shuffle network phase exists at all.
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 from dataclasses import dataclass
@@ -18,6 +19,7 @@ from typing import Iterator, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.data.corpus import Corpus
 from repro.data.vocab import Vocab
 from repro.data.pairs import extract_pairs
@@ -268,10 +270,11 @@ class PairChunkStream:
                 fill_and_cut(w)
             done += 1
         while num_chunks is None or done < num_chunks:
-            centers = np.empty((n, need), dtype=np.int32)
-            contexts = np.empty((n, need), dtype=np.int32)
-            for w in range(n):
-                fill_and_cut(w, centers, contexts)
+            with obs.span(obs.INGEST_EXTRACT, chunk=done):
+                centers = np.empty((n, need), dtype=np.int32)
+                contexts = np.empty((n, need), dtype=np.int32)
+                for w in range(n):
+                    fill_and_cut(w, centers, contexts)
             shape = (n, self.steps_per_chunk, self.batch_size)
             yield centers.reshape(shape), contexts.reshape(shape)
             done += 1
@@ -445,6 +448,21 @@ def prefetch_chunks(iterator, depth: int = 2, to_device: bool = True):
     return _prefetch_gen(iterator, depth, to_device)
 
 
+def _get(q: queue.Queue, thread: threading.Thread):
+    """The next item of the queue. Bounded get + liveness check: if the
+    producer thread dies without enqueuing its sentinel/exception
+    (interpreter teardown, thread killed), the consumer must error out,
+    not block forever on an empty queue."""
+    while True:
+        try:
+            return q.get(timeout=0.5)
+        except queue.Empty:
+            if not thread.is_alive():
+                raise RuntimeError(
+                    "prefetch_chunks producer thread died without "
+                    "delivering a chunk, sentinel or exception")
+
+
 def _prefetch_gen(iterator, depth: int, to_device: bool):
     import jax.numpy as jnp
 
@@ -465,9 +483,10 @@ def _prefetch_gen(iterator, depth: int, to_device: bool):
 
     def produce():
         try:
-            for item in iterator:
+            for k, item in enumerate(iterator):
                 if to_device:
-                    item = tuple(jnp.asarray(a) for a in item)
+                    with obs.span(obs.INGEST_H2D, chunk=k):
+                        item = tuple(jnp.asarray(a) for a in item)
                 if not put(item):
                     return
             put(_SENTINEL)
@@ -478,19 +497,9 @@ def _prefetch_gen(iterator, depth: int, to_device: bool):
                               name="prefetch_chunks")
     thread.start()
     try:
-        while True:
-            # Bounded get + liveness check: if the producer thread dies
-            # without enqueuing its sentinel/exception (interpreter
-            # teardown, thread killed), the consumer must error out, not
-            # block forever on an empty queue.
-            try:
-                item = q.get(timeout=0.5)
-            except queue.Empty:
-                if not thread.is_alive():
-                    raise RuntimeError(
-                        "prefetch_chunks producer thread died without "
-                        "delivering a chunk, sentinel or exception")
-                continue
+        for k in itertools.count():
+            with obs.span(obs.INGEST_WAIT, chunk=k):
+                item = _get(q, thread)
             if item is _SENTINEL:
                 return
             if isinstance(item, BaseException):

@@ -100,6 +100,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core.sgns import sparse_row_grads_per_pair
 from repro.kernels.sgns_fused import _as_seed, fused_negative_ids
 from repro.kernels.mode import resolve_interpret
@@ -353,17 +354,22 @@ def resolve_schedule(hazard, num_slots: int = NUM_SLOTS):
             if g is None or all(bool(hazard[f]) is w for f, w in g)]
 
 
+def row_traffic(plan: PipelinePlan, hot_rows: int = 0) -> jax.Array:
+    """HBM row transfers one step under this plan actually moves, as an
+    int32 device scalar: each valid cold row is exactly one gather plus
+    one write-back, and a hot prefix of ``hot_rows`` rows moves in and
+    out once per step for both tables (the tiered kernel's
+    ``HOT_PREFIX_DMA_OPS`` bulk copies). The step computes it beside its
+    loss, for the ``sgns.rows_moved`` counter."""
+    return (2 * (jnp.sum(plan.n_w) + jnp.sum(plan.n_c))
+            + 4 * hot_rows).astype(jnp.int32)
+
+
 def plan_row_traffic(plan: PipelinePlan, hot_rows: int = 0) -> int:
-    """HBM row transfers one step under this plan actually moves: each
-    valid cold row is exactly one gather plus one write-back, and a hot
-    prefix of ``hot_rows`` rows moves in and out once per step for both
-    tables (the tiered kernel's ``HOT_PREFIX_DMA_OPS`` bulk copies).
-    This is the ``hbm_rows_per_step`` quantity the ``@zipf50k`` BENCH
-    rows gate on and ``repro.analysis.contracts`` certifies against the
-    committed baseline."""
-    return 2 * int(plan.n_w.sum() + plan.n_c.sum()) + 4 * int(hot_rows)
-
-
+    """:func:`row_traffic` read back: the ``hbm_rows_per_step`` quantity
+    the ``@zipf50k`` BENCH rows gate on and ``repro.analysis.contracts``
+    certifies against the committed baseline."""
+    return int(row_traffic(plan, hot_rows))
 
 
 # ---------------------------------------------------------------------------
@@ -676,6 +682,7 @@ def _pallas_call(n, V, dp, nblocks, blk, K, num_slots, kH, B, interpret):
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=int(min(max(need, 32 << 20), 120 << 20))),
         interpret=interpret,
+        name=obs.SGNS_KERNEL,
     )
 
 
@@ -718,27 +725,30 @@ def fused_pipe_rows_step(
     ring_depth: int,
     hot_rows: int,
     interpret: bool,
-) -> tuple[dict, jax.Array]:
+) -> tuple[dict, jax.Array, jax.Array]:
     """One step of the pipelined (``hot_rows = 0``) or tiered HBM kernel
     on tables already in the kernels' row layout
     (:func:`to_row_layout`) — the form a scan of steps carries, so that
     the layout is converted once per epoch rather than once per step.
-    ``hot_rows`` must lie in ``[0, V]``."""
+    ``hot_rows`` must lie in ``[0, V]``. Returns the tables, the mean
+    loss and the rows the kernel moves (:func:`row_traffic`)."""
     V, _, dp = tables["W"].shape
     B = centers.shape[0]
     K = negatives
-    neg_ids = fused_negative_ids(_as_seed(key), table["prob"], table["alias"],
-                                 (B, K))
-    plan = plan_blocks(centers, contexts, neg_ids, V, block_pairs,
-                       hot_rows=hot_rows, ring_depth=ring_depth)
-    meta, cnt = block_meta(plan, hot_rows > 0)
+    with jax.named_scope(obs.PLAN_SCOPE):
+        neg_ids = fused_negative_ids(_as_seed(key), table["prob"],
+                                     table["alias"], (B, K))
+        plan = plan_blocks(centers, contexts, neg_ids, V, block_pairs,
+                           hot_rows=hot_rows, ring_depth=ring_depth)
+        meta, cnt = block_meta(plan, hot_rows > 0)
+        rows = row_traffic(plan, hot_rows)
     run = _worker_kernel(V, dp, plan.nblocks, plan.block_pairs, K,
                          ring_depth, hot_rows, B, interpret)
     W, C, loss = run(jnp.reshape(lr, (1,)).astype(jnp.float32), cnt[None],
                      meta[None], tables["W"][None], tables["C"][None])
     # padded pairs were masked to exactly-zero loss, so the batch mean
     # divides by the true pair count
-    return {"W": W[0], "C": C[0]}, jnp.sum(loss) / B
+    return {"W": W[0], "C": C[0]}, jnp.sum(loss) / B, rows
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -751,7 +761,7 @@ def fused_pipe_step(params, centers, contexts, table, key, lr, *,
     interpret = resolve_interpret(interpret)
     d = params["W"].shape[1]
     rows = {k: to_row_layout(v, interpret) for k, v in params.items()}
-    rows, loss = fused_pipe_rows_step(
+    rows, loss, _ = fused_pipe_rows_step(
         rows, centers, contexts, table, key, lr, negatives=negatives,
         block_pairs=block_pairs, ring_depth=ring_depth, hot_rows=hot_rows,
         interpret=interpret)

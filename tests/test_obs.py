@@ -1,0 +1,231 @@
+"""The program's tracing (``repro.obs``): counters, the rows-moved count
+inside the step, the device scopes in the epoch's HLO and the ingestion
+spans in a profiler trace. CPU only: the kernels run interpreted."""
+
+import pathlib
+import re
+import sys
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro import obs
+from repro.core.async_trainer import AsyncShardTrainer
+from repro.core.engine import get_engine
+from repro.core.sgns import SGNSConfig
+from repro.data.pairs import build_noise_table
+from repro.kernels.sgns_fused import _as_seed, fused_negative_ids
+from repro.kernels.sgns_fused_pipe import (plan_blocks, plan_row_traffic,
+                                           row_traffic)
+
+N, V, D, S, B, BLK = 2, 300, 16, 3, 48, 16
+
+
+@pytest.fixture(autouse=True)
+def fresh_counters():
+    obs.reset()
+    yield
+    obs.reset()
+
+
+def _trainer(engine):
+    cfg = SGNSConfig(vocab_size=V, dim=D, negatives=4)
+    return AsyncShardTrainer(cfg=cfg, num_workers=N, total_steps=100,
+                             engine=engine)
+
+
+def _chunk(seed=0):
+    rng = np.random.default_rng(seed)
+    ids = (V * rng.power(0.3, (2, N, S, B))).astype(np.int32)  # Zipf-like
+    counts = np.bincount(ids.ravel(), minlength=V) + 1.0
+    table = jax.tree.map(lambda a: jnp.stack([a] * N),
+                         build_noise_table(counts, kind="alias"))
+    return jnp.asarray(ids[0]), jnp.asarray(ids[1]), table
+
+
+# ------------------------------------------------------------ counters
+def test_counters_sum_host_ints_and_device_arrays():
+    obs.count("a", 3)
+    obs.count("a", jnp.asarray([4, 5], jnp.int32))
+    obs.count("a", jnp.int32(2), times=10)
+    obs.count("b", 7, times=2)
+    assert obs.read("a") == 3 + 9 + 20
+    assert obs.read("b") == 14
+    assert obs.read("never") == 0
+    obs.reset()
+    assert obs.read("a") == 0
+
+
+def test_device_values_fold_on_the_device_without_overflow(monkeypatch):
+    monkeypatch.setattr(obs, "FOLD_EVERY", 4)
+    big = 2 ** 31 - 1
+    for _ in range(10):                     # two folds and two pending
+        obs.count("big", jnp.asarray([big, 1], jnp.int32), times=3)
+    assert sum(len(v) for v in obs._pending.values()) == 2
+    assert obs.read("big") == 3 * 10 * (big + 1)
+
+
+def test_counters_lose_no_update_across_threads(monkeypatch):
+    """Host and device counts from more threads than cores, with folds
+    racing the appends and the thread switch interval shortened."""
+    monkeypatch.setattr(obs, "FOLD_EVERY", 8)
+    one = jnp.ones((), jnp.int32)
+
+    def work(value):
+        for _ in range(300):
+            obs.count("t", value)
+
+    threads = [threading.Thread(target=work, args=(v,))
+               for v in [1, one] * 8]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert obs.read("t") == 16 * 300
+
+
+def test_names_of_spans_are_the_programs_own():
+    spans = [obs.INGEST_EXTRACT, obs.INGEST_H2D, obs.INGEST_WAIT,
+             obs.TRAIN_DISPATCH]
+    assert all(s.startswith("repro.") for s in spans)
+    with obs.span(obs.TRAIN_DISPATCH, chunk=3):     # not tracing: a no-op
+        pass
+
+
+# ------------------------------------------------------------ the step's count
+@pytest.mark.parametrize("engine,hot_rows", [
+    ("pallas_fused_pipe", 0), ("pallas_fused_tiered", 24)])
+def test_rows_moved_is_the_plan_row_traffic_of_every_step(engine, hot_rows):
+    """``sgns.rows_moved`` over one chunk is the sum over its steps of
+    ``plan_row_traffic`` recomputed from each step's own plan (the keys
+    split as the epoch splits them)."""
+    eng = get_engine(engine, block_pairs=BLK, **(
+        {"hot_rows": hot_rows} if hot_rows else {}))
+    tr = _trainer(eng)
+    c, x, table = _chunk()
+    key = jax.random.PRNGKey(7)
+    tr.epoch(tr.init(jax.random.PRNGKey(0)), c, x, table, key, step0=0)
+
+    expect = 0
+    for w, wkey in enumerate(jax.random.split(key, N)):
+        for s in range(S):
+            wkey, sub = jax.random.split(wkey)
+            negs = fused_negative_ids(_as_seed(sub), table["prob"][w],
+                                      table["alias"][w], (B, 4))
+            plan = plan_blocks(c[w, s], x[w, s], negs, V, BLK,
+                               hot_rows=hot_rows, ring_depth=2)
+            expect += plan_row_traffic(plan, hot_rows=hot_rows)
+    assert obs.read(obs.ROWS_MOVED) == expect
+    assert obs.read(obs.PAIRS) == N * S * B
+    # interpret mode: a row is d float32 wide
+    assert obs.read(obs.BYTES_MOVED) == expect * 4 * D
+
+
+def test_row_traffic_is_one_definition():
+    c, x, table = _chunk(1)
+    negs = fused_negative_ids(_as_seed(jax.random.PRNGKey(1)),
+                              table["prob"][0], table["alias"][0], (B, 4))
+    plan = plan_blocks(c[0, 0], x[0, 0], negs, V, BLK, hot_rows=8)
+    by_hand = 2 * int(plan.n_w.sum() + plan.n_c.sum()) + 4 * 8
+    assert plan_row_traffic(plan, hot_rows=8) == by_hand
+    assert jax.jit(row_traffic, static_argnums=1)(plan, 8) == by_hand
+
+
+@pytest.mark.parametrize("engine", [
+    "sparse:alias", "pallas_fused_pipe", "pallas_fused_tiered"])
+def test_epoch_returns_params_and_losses_of_the_steps(engine):
+    """``epoch`` still returns ``(params, losses)``, bit-identical to the
+    engine's own step scanned per worker under vmap (the epoch as it was
+    before it counted rows); an engine without a DMA kernel counts no
+    rows."""
+    eng = get_engine(engine, **({} if engine.startswith("sparse")
+                                else {"block_pairs": BLK}))
+    tr = _trainer(eng)
+    c, x, table = _chunk(2)
+    p0 = tr.init(jax.random.PRNGKey(0))
+    key = jax.random.PRNGKey(3)
+    out = tr.epoch(jax.tree.map(jnp.copy, p0), c, x, table, key, step0=5)
+    assert isinstance(out, tuple) and len(out) == 2
+    step = eng.make_step(tr.cfg, tr.total_steps)
+
+    def steps(params, centers, contexts, tab, wkey, step0):
+        def body(carry, xs):
+            p, k, i = carry
+            k, sub = jax.random.split(k)
+            p, loss = step(p, xs[0], xs[1], tab, sub, step0 + i)
+            return (p, k, i + 1), loss
+        (params, _, _), losses = jax.lax.scan(
+            body, (params, wkey, jnp.int32(0)), (centers, contexts))
+        return params, losses
+
+    ref = jax.jit(jax.vmap(steps))(p0, c, x, table, jax.random.split(key, N),
+                                   jnp.full((N,), 5, jnp.int32))
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(ref)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    rows = obs.read(obs.ROWS_MOVED)
+    assert (rows == 0) == engine.startswith("sparse")
+
+
+# ------------------------------------------------------------ device scopes
+def test_epoch_hlo_names_the_planner_and_the_layout_conversion():
+    """The lowered epoch's op names (its ``loc`` names, which become the
+    HLO ops' ``op_name`` metadata) carry both scopes, and the kernel is
+    under neither."""
+    tr = _trainer(get_engine("pallas_fused_tiered", block_pairs=BLK,
+                             hot_rows=24))
+    c, x, table = _chunk()
+    params = tr.init(jax.random.PRNGKey(0))
+    keys = jax.random.split(jax.random.PRNGKey(1), N)
+    text = tr._jit_epoch().lower(params, c, x, table, keys,
+                                 jnp.zeros((N,), jnp.int32)).as_text(
+        debug_info=True)
+    op_names = set(re.findall(r'loc\("([^"]+)"', text))
+    assert any(obs.PLAN_SCOPE in n and "sort" in n for n in op_names)
+    assert any(obs.LAYOUT_SCOPE in n for n in op_names)
+    assert any(obs.SGNS_KERNEL in n for n in op_names)
+    assert not any(obs.SGNS_KERNEL in n and (obs.PLAN_SCOPE in n or
+                                             obs.LAYOUT_SCOPE in n)
+                   for n in op_names)
+
+
+# ------------------------------------------------------------ host spans
+def test_ingestion_spans_carry_their_chunk_in_a_profiler_trace(tmp_path):
+    from jax.profiler import ProfileData
+
+    from repro.data.corpus import SemanticCorpusModel
+    from repro.data.pipeline import (PairChunkStream, make_worker_streams,
+                                     prefetch_chunks)
+    from repro.data.vocab import build_vocab
+
+    corpus = SemanticCorpusModel.create(vocab_size=200, seed=0).generate(
+        num_sentences=300, seed=1)
+    vocab = build_vocab(corpus, 200, min_count=1, max_size=None)
+    stream = PairChunkStream(
+        make_worker_streams(corpus, vocab, num_workers=2, strategy="shuffle",
+                            window=3, seed=2),
+        batch_size=16, steps_per_chunk=2, sentences_per_block=64)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        got = list(prefetch_chunks(stream.chunks(0, 2), depth=1))
+    finally:
+        jax.profiler.stop_trace()
+    assert len(got) == 2
+    f = sorted(pathlib.Path(tmp_path).rglob("*.xplane.pb"))[-1]
+    seen = {}
+    for plane in ProfileData.from_file(str(f)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro."):
+                    seen.setdefault(e.name, set()).add(dict(e.stats)["chunk"])
+    assert seen[obs.INGEST_EXTRACT] == {0, 1}
+    assert seen[obs.INGEST_H2D] == {0, 1}
+    assert {0, 1} <= seen[obs.INGEST_WAIT]      # and the sentinel's wait

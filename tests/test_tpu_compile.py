@@ -4,8 +4,10 @@ Nothing here runs on a chip: each test lowers and compiles with the TPU
 compiler for a ``v5e:2x2`` topology described without one attached, so
 what Mosaic or XLA would refuse (an unaligned slice, a vector gather, a
 program past the chip's 15.75 GB) fails here at no chip time. The HBM
-kernels compile at the paper's 300k×500 shape and at ``ZIPF50K``; the
-4-worker vmapped epoch of the tiered engine must fit one chip.
+kernels compile at the paper's 300k×500 shape and at ``ZIPF50K``, under
+the kernel's own name; the 4-worker vmapped epoch of the tiered engine
+must fit one chip, with its planner and layout scopes in the compiled
+program.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may hold the TPU library, and every test
@@ -24,6 +26,7 @@ from repro.core.async_trainer import AsyncShardTrainer
 from repro.core.engine import get_engine
 from repro.core.sgns import SGNSConfig
 from repro.kernels.sgns_fused_pipe import fused_pipe_step
+from repro.obs import LAYOUT_SCOPE, PLAN_SCOPE, SGNS_KERNEL
 
 V5E_HBM_BYTES = 15.75e9     # what one v5e chip gives a program
 PAPER = {"V": 300_000, "D": 500, "B": 512, "K": 5, "BLK": 256, "HOT": 256}
@@ -68,7 +71,9 @@ def _compile_step(one_chip, w, hot_rows):
         sds((B,), jnp.int32), sds((B,), jnp.int32),
         {"prob": sds((V,), jnp.float32), "alias": sds((V,), jnp.int32)},
         sds((2,), jnp.uint32), sds((), jnp.float32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{SGNS_KERNEL}." in text       # the kernel under its own name
     return compiled
 
 
@@ -98,6 +103,9 @@ def test_four_worker_tiered_epoch_fits_one_v5e(one_chip):
         sds((n, S, B), jnp.int32), sds((n, S, B), jnp.int32),
         {"prob": sds((n, V), jnp.float32), "alias": sds((n, V), jnp.int32)},
         sds((n, 2), jnp.uint32), sds((n,), jnp.int32)).compile()
+    text = compiled.as_text()
+    for name in (SGNS_KERNEL, PLAN_SCOPE, LAYOUT_SCOPE):
+        assert name in text, name
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
