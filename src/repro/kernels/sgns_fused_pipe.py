@@ -172,24 +172,30 @@ def _unique_rows(ids: jax.Array, vocab_size: int):
     ids: (nblocks, R) int32 in [0, V) ∪ {V} (V marks entries already
     routed elsewhere — the hot tier). Returns (u (nblocks, R), n
     (nblocks,)): ``u[b, :n[b]]`` is block b's sorted unique set of
-    ids < V and ``u[b, n[b]:] == V`` (past every real id, so
-    searchsorted lookups of valid ids never land on padding).
+    ids < V and ``u[b, n[b]:] == V`` (past every real id, so a lookup
+    by :func:`_rank_in` of a valid id never lands on padding). Two
+    sorts per block and no gather: the second sort moves the first
+    occurrences to the front in ascending order, every duplicate and
+    sentinel having been overwritten with V.
     """
+    V = jnp.int32(vocab_size)
     s = jnp.sort(ids, axis=1)
     first = jnp.concatenate(
         [jnp.ones(s.shape[:1] + (1,), bool), s[:, 1:] != s[:, :-1]], axis=1)
     # sentinel entries (== V) are not counted as unique rows
-    n = (first & (s < jnp.int32(vocab_size))).sum(axis=1).astype(jnp.int32)
-    # stable argsort floats the first-occurrences to the front, still in
-    # ascending id order; the duplicate/sentinel tail is overwritten with V
-    order = jnp.argsort(~first, axis=1, stable=True)
-    u = jnp.take_along_axis(s, order, axis=1)
-    col = jnp.arange(s.shape[1], dtype=jnp.int32)[None, :]
-    return jnp.where(col < n[:, None], u, jnp.int32(vocab_size)), n
+    keep = first & (s < V)
+    n = keep.sum(axis=1).astype(jnp.int32)
+    return jnp.sort(jnp.where(keep, s, V), axis=1), n
 
 
-_searchsorted_rows = jax.vmap(
-    functools.partial(jnp.searchsorted, side="left"))
+def _rank_in(u: jax.Array, q: jax.Array) -> jax.Array:
+    """Per-block left insertion index of each query in its sorted row:
+    ``out[b, i] = #{j : u[b, j] < q[b, i]}``, which is
+    ``searchsorted(u[b], q[b], side="left")``. One broadcast compare
+    reduced over the row, which XLA fuses without materializing it:
+    ``R_u·R_q`` compares per block, where a binary search is a loop of
+    dependent gathers that a TPU issues element by element."""
+    return jnp.sum(u[:, None, :] < q[:, :, None], axis=-1, dtype=jnp.int32)
 
 
 def plan_blocks(
@@ -215,6 +221,12 @@ def plan_blocks(
     regather hazards the schedule must serialize on; a deeper ring
     leaves more write-backs in flight, so the look-behind window grows
     with it).
+
+    No gathers and no loops: every buffer position is a count of the
+    smaller rows of its block's sorted unique set (:func:`_rank_in`),
+    every hazard a fused equality compare of two blocks' sets, so the
+    cost per block is ``R·R`` vector compares with ``R_C = blk·(K+1)``
+    — ``blk·(K+1)²`` compares per pair (9,216 at blk 256, K 5).
     """
     B = centers.shape[0]
     K = negatives.shape[1]
@@ -241,10 +253,8 @@ def plan_blocks(
     # hot elements look up the V sentinel → the first pad slot (clamped
     # to the buffer when a block is entirely cold, in which case no hot
     # lookups exist and the clamp is a no-op)
-    w_pos = jnp.minimum(_searchsorted_rows(uw, cold(cen)),
-                        uw.shape[1] - 1).astype(jnp.int32)
-    c_pos = jnp.minimum(_searchsorted_rows(uc, c_rows),
-                        uc.shape[1] - 1).astype(jnp.int32)
+    w_pos = jnp.minimum(_rank_in(uw, cold(cen)), uw.shape[1] - 1)
+    c_pos = jnp.minimum(_rank_in(uc, c_rows), uc.shape[1] - 1)
     cp_pos, cn_pos = c_pos[:, :blk], c_pos[:, blk:]
 
     # With dedup, written(b) == touched(b) per table (every gathered row
@@ -253,10 +263,10 @@ def plan_blocks(
     # writes, C rows with C writes — the tables are separate buffers.
     # The window covers the S-1 blocks whose write-backs a ring of S
     # slots can still have in flight when block b's gathers issue.
+    # Membership by one fused compare of the two padded sets (R·R per
+    # block pair), the sentinel V excluded on the probing side.
     def hit(u, m):
-        idx = _searchsorted_rows(u[:-m], u[m:])
-        found = jnp.take_along_axis(
-            u[:-m], jnp.minimum(idx, u.shape[1] - 1), axis=1) == u[m:]
+        found = (u[m:][:, :, None] == u[:-m][:, None, :]).any(axis=-1)
         return (found & (u[m:] < jnp.int32(V))).any(axis=1)
 
     hz = jnp.zeros((nblocks,), bool)

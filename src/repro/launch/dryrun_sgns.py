@@ -29,7 +29,7 @@ Cases:
                    b+1's gathers in flight while block b computes,
                    hazard-ordered by the pure-JAX block planner. Same
                    zero-collective assertion (the planner is local
-                   sort/searchsorted work, no communication).
+                   sort-and-compare work, no communication).
   async_fused_tiered— `pallas_fused_tiered` engine: the pipelined step
                    with frequency-tiered placement — the hot_rows
                    hottest rows (the frequency-sorted id prefix) pinned

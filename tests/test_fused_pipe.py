@@ -269,6 +269,74 @@ def test_planner_invariants_on_seeded_adversarial_streams():
                 ring_depth=rd)
 
 
+def _numpy_plan(c, x, n, V, blk, hot_rows, ring_depth):
+    """The planner's outputs built the plain way, block by block:
+    ``np.unique`` for the cold row sets, ``np.searchsorted`` for the
+    buffer positions, set intersections for the hazard flags."""
+    Bq, Kq = n.shape
+    nblocks = -(-Bq // blk)
+    pad = nblocks * blk - Bq
+    c, x, n = (np.concatenate([a, np.repeat(a[:1], pad, axis=0)])
+               for a in (c, x, n))
+    cold = lambda ids: np.where(ids < hot_rows, V, ids)
+    out = {f: [] for f in ("uw", "uc", "n_w", "n_c", "w_pos", "cp_pos",
+                           "cn_pos")}
+    for b in range(nblocks):
+        sl = slice(b * blk, (b + 1) * blk)
+        w_ids = cold(c[sl])
+        c_ids = cold(np.concatenate([x[sl], n[sl].reshape(-1)]))
+        for name, ids in (("w", w_ids), ("c", c_ids)):
+            u = np.unique(ids[ids < V])
+            out[f"n_{name}"].append(len(u))
+            u = np.concatenate([u, np.full(len(ids) - len(u), V)])
+            out[f"u{name}"].append(u)
+            pos = np.minimum(np.searchsorted(u, ids, side="left"),
+                             len(ids) - 1)
+            if name == "w":
+                out["w_pos"].append(pos)
+            else:
+                out["cp_pos"].append(pos[:blk])
+                out["cn_pos"].append(pos[blk:])
+    sets = [(set(out["uw"][b][:out["n_w"][b]]),
+             set(out["uc"][b][:out["n_c"][b]])) for b in range(nblocks)]
+    hazard = [any((sets[b][0] & sets[b - m][0]) or (sets[b][1] & sets[b - m][1])
+                  for m in range(1, min(ring_depth, b + 1)))
+              for b in range(nblocks)]
+    out = {f: np.asarray(v) for f, v in out.items()}
+    out["hazard"] = np.asarray(hazard, np.int32)
+    return out
+
+
+@pytest.mark.parametrize("ring_depth", [2, 3, 4])
+@pytest.mark.parametrize("hot", ["none", "one", "blk"])
+def test_planner_matches_numpy_reference(hot, ring_depth):
+    """Every index map of the plan equals the plain NumPy construction,
+    element for element, on duplicate-heavy seeded streams whose last
+    block is padded, with no hot tier, a one-row hot tier and a hot tier
+    as wide as a block. Zipf draws repeat rows within blocks and across
+    neighbours (hazards everywhere); block-local draws repeat rows only
+    within a block (padded unique sets, no hazard but the tail's)."""
+    rng = np.random.default_rng(1000 + 10 * ring_depth + len(hot))
+    zipf = lambda V, s: np.minimum(rng.zipf(1.3, s) - 1, V - 1)
+    local = lambda V, s: (np.arange(s[0]).reshape((-1,) + (1,) * (len(s) - 1))
+                          // 8 * 8 + rng.integers(0, 5, s)) % V
+    for draw, V, Bq, Kq, blk in [(zipf, 40, 45, 3, 8), (zipf, 300, 100, 5, 16),
+                                 (zipf, 12, 23, 2, 4), (zipf, 2000, 70, 4, 32),
+                                 (local, 400, 45, 3, 8)]:
+        hot_rows = {"none": 0, "one": 1, "blk": blk}[hot]
+        for _ in range(3):
+            c, x, n = (draw(V, s).astype(np.int32)
+                       for s in ((Bq,), (Bq,), (Bq, Kq)))
+            ref = _numpy_plan(c, x, n, V, blk, hot_rows, ring_depth)
+            p = _np_plan(_plan(c, x, n, V, blk, hot_rows=hot_rows,
+                               ring_depth=ring_depth))
+            assert p.mask[-1].sum() < blk          # a padded tail block
+            for f, want in ref.items():
+                got = getattr(p, f)
+                assert got.dtype == np.int32, f
+                np.testing.assert_array_equal(got, want, err_msg=f)
+
+
 try:
     from hypothesis import given, settings, strategies as st
     HAS_HYPOTHESIS = True

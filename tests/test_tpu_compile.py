@@ -7,7 +7,8 @@ program past the chip's 15.75 GB) fails here at no chip time. The HBM
 kernels compile at the paper's 300k×500 shape and at ``ZIPF50K``, under
 the kernel's own name; the 4-worker vmapped epoch of the tiered engine
 must fit one chip, with its planner and layout scopes in the compiled
-program.
+program, and a planner that compiles to no loop and no gather but the
+noise draw's.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may hold the TPU library, and every test
@@ -15,6 +16,7 @@ worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -87,6 +89,20 @@ def test_hbm_kernel_step_compiles_for_v5e(one_chip, workload, engine):
     assert mem.argument_size_in_bytes >= 2 * w["V"] * w["D"] * 4
 
 
+def _assert_planner_has_no_loop_or_gather(text, V):
+    """Under the planner's scope the compiled program has no ``while``
+    (a binary search compiles to one) and gathers only from the
+    vocabulary's ``(…, V)`` noise tables: the alias draw's lookups."""
+    shapes = dict(re.findall(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", text))
+    for line in text.splitlines():
+        if PLAN_SCOPE not in line:
+            continue
+        assert not re.search(r" while\(", line), line
+        gather = re.search(r" gather\((%[\w.\-]+),", line)
+        if gather:
+            assert shapes[gather[1]].split(",")[-1] == str(V), line
+
+
 def test_four_worker_tiered_epoch_fits_one_v5e(one_chip):
     """The paper's shape, 4 workers vmapped on one chip: the compiled
     epoch (tables donated, the kernel's row layout carried through the
@@ -106,6 +122,7 @@ def test_four_worker_tiered_epoch_fits_one_v5e(one_chip):
     text = compiled.as_text()
     for name in (SGNS_KERNEL, PLAN_SCOPE, LAYOUT_SCOPE):
         assert name in text, name
+    _assert_planner_has_no_loop_or_gather(text, V)
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
