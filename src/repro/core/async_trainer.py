@@ -43,8 +43,10 @@ from repro.core.sgns import SGNSConfig
 # ---------------------------------------------------------------------------
 def make_worker_epoch(cfg: SGNSConfig, total_steps: int, engine="sparse"):
     """Returns epoch_fn(params, centers (S,B), contexts (S,B), neg_table,
-    key, step0) -> (params, losses (S,), rows): ``rows`` (int32) is the
-    table rows the engine's kernel moved over the S steps.
+    key, step0) -> (params, losses (S,), counts): ``counts`` maps each
+    name of ``repro.obs.STEP_COUNTERS`` to its int32 sum over the S
+    steps (the table rows the engine's kernel moved, the row references
+    to its hot tier).
 
     ``engine`` (an :class:`repro.core.engine.UpdateEngine` or spec
     string) owns the whole per-step compute: negative draw, row grads,
@@ -59,21 +61,22 @@ def make_worker_epoch(cfg: SGNSConfig, total_steps: int, engine="sparse"):
 
     def epoch_fn(params, centers, contexts, neg_table, key, step0):
         def body(carry, xs):
-            params, key, i, moved = carry
+            params, key, i, totals = carry
             c_b, x_b = xs
             key, sub = jax.random.split(key)
-            params, loss, rows = step(params, c_b, x_b, neg_table, sub,
-                                      step0 + i)
-            return (params, key, i + 1, moved + rows), loss
+            params, loss, counts = step(params, c_b, x_b, neg_table, sub,
+                                        step0 + i)
+            totals = {n: totals[n] + counts[n] for n in totals}
+            return (params, key, i + 1, totals), loss
 
         with jax.named_scope(obs.LAYOUT_SCOPE):
             packed = engine.pack(params)
-        (params, _, _, moved), losses = jax.lax.scan(
-            body, (packed, key, jnp.int32(0), jnp.int32(0)),
-            (centers, contexts))
+        zeros = {n: jnp.int32(0) for n in obs.STEP_COUNTERS}
+        (params, _, _, totals), losses = jax.lax.scan(
+            body, (packed, key, jnp.int32(0), zeros), (centers, contexts))
         with jax.named_scope(obs.LAYOUT_SCOPE):
             params = engine.unpack(params, cfg)
-        return params, losses, moved
+        return params, losses, totals
 
     return epoch_fn
 
@@ -197,18 +200,19 @@ class AsyncShardTrainer:
     def epoch(self, params, centers, contexts, neg_table, key, step0=0):
         """params: (n,V,d) pytree; centers/contexts: (n,S,B);
         neg_table: (n,V) CDF or {'prob','alias'} of (n,V) alias tables.
-        Returns ``(params, losses)``; counts the chunk's pairs and the
-        rows (and bytes) its kernel moved (``repro.obs``), read back only
-        when a counter is read."""
+        Returns ``(params, losses)``; counts the chunk's pairs, the rows
+        (and bytes) its kernel moved and its references to hot rows
+        (``repro.obs``), read back only when a counter is read."""
         ids = {"step0": step0} if isinstance(step0, int) else {}
         with obs.span(obs.TRAIN_DISPATCH, **ids):
             keys = jax.random.split(key, self.num_workers)
             step0 = jnp.full((self.num_workers,), step0, dtype=jnp.int32)
-            params, losses, rows = self._jit_epoch()(
+            params, losses, counts = self._jit_epoch()(
                 params, centers, contexts, neg_table, keys, step0)
             obs.count(obs.PAIRS, centers.size)
-            obs.count(obs.ROWS_MOVED, rows)
-            obs.count(obs.BYTES_MOVED, rows,
+            for name, value in counts.items():
+                obs.count(name, value)
+            obs.count(obs.BYTES_MOVED, counts[obs.ROWS_MOVED],
                       times=self.engine.row_bytes(self.cfg))
         return params, losses
 

@@ -32,7 +32,9 @@ from repro.core.engine import get_engine
 from repro.core.merge import StackedModels, merge as merge_models
 from repro.core.schedule import plan_epoch
 from repro.data.corpus import Corpus
-from repro.data.pairs import stack_noise_tables
+from repro.data.graph import (Graph, degree_vocab, expected_walk_pairs,
+                              make_walk_streams, relabel_by_degree)
+from repro.data.pairs import build_noise_table, stack_noise_tables
 from repro.data.vocab import Vocab, build_vocab, union_vocab, UNK
 from repro.data.pipeline import (
     HostShardPlan, make_worker_streams, prefetch_chunks)
@@ -179,13 +181,14 @@ class TrainingSetup:
     cfg: SGNSConfig              # vocab_size bound to the union vocab
     plan: HostShardPlan
     engine: object               # resolved UpdateEngine
-    streams: list                # per-worker WorkerStream, union id space
+    streams: list                # per-worker WorkerStream (or WalkStream),
+                                 # union id space
     union_vocab: Vocab
     mask: np.ndarray             # (n, V_union) presence mask
     neg_table: object            # stacked per-worker noise tables
     sched: object                # EpochSchedule
     batch_size: int
-    sentences_per_block: int
+    sentences_per_block: int     # sentences (walks) per extraction block
     seed: int
     epochs: int
     vocab_s: float               # wall-clock of the vocab/noise build
@@ -265,6 +268,58 @@ def prepare_training(
         seed=seed, epochs=epochs, vocab_s=vocab_s)
 
 
+def prepare_graph_training(
+    graph: Graph,
+    num_workers: int,
+    cfg: SGNSConfig,
+    *,
+    epochs: int = 1,
+    batch_size: int = 512,
+    rate: float | None = None,
+    window: int | None = None,
+    walks_per_node: int = 80,
+    walk_length: int = 40,
+    seed: int = 0,
+    engine="sparse",
+    steps_per_chunk: int = 128,
+    walks_per_block: int = 1024,
+    process_index: int | None = None,
+    process_count: int | None = None,
+) -> TrainingSetup:
+    """:func:`prepare_training` for a graph (DeepWalk): the rows are the
+    graph's nodes in descending degree order, every worker's vocabulary
+    is all of them, its noise is degree^0.75, and its stream is a
+    :class:`repro.data.graph.WalkStream` of its sample of walk starts.
+    The schedule comes from the walk count and the expected pairs per
+    walk; no walk is generated here."""
+    rate = rate if rate is not None else 1.0 / num_workers
+    window = window if window is not None else cfg.window
+    engine = get_engine(engine)
+    plan = HostShardPlan.for_runtime(num_workers, process_index=process_index,
+                                     process_count=process_count)
+
+    t0 = time.perf_counter()
+    ordered, order = relabel_by_degree(graph)
+    union = degree_vocab(ordered, order)
+    cfg = SGNSConfig(**{**cfg.__dict__, "vocab_size": union.size})
+    noise = build_noise_table(union.counts, kind=engine.table_kind)
+    neg_table = jax.tree.map(lambda a: jnp.stack([a] * num_workers), noise)
+    vocab_s = time.perf_counter() - t0
+
+    streams = make_walk_streams(
+        ordered, num_workers, rate, walks_per_node=walks_per_node,
+        walk_length=walk_length, window=window, seed=seed)
+    min_pairs = int(expected_walk_pairs(walk_length, window)
+                    * min(s.num_walks for s in streams))
+    sched = plan_epoch(min_pairs, batch_size, epochs, steps_per_chunk)
+    return TrainingSetup(
+        cfg=cfg, plan=plan, engine=engine, streams=streams,
+        union_vocab=union, mask=np.ones((num_workers, union.size), bool),
+        neg_table=neg_table, sched=sched, batch_size=batch_size,
+        sentences_per_block=walks_per_block, seed=seed, epochs=epochs,
+        vocab_s=vocab_s)
+
+
 @dataclass
 class PipelineResult:
     strategy: str
@@ -308,16 +363,6 @@ def train_submodels(
     count can be simulated in one process (``tests/test_multihost.py``);
     with ``process_count == 1`` the path is bit-identical to the
     single-host stream."""
-    plan = HostShardPlan.for_runtime(num_workers, process_index=process_index,
-                                     process_count=process_count)
-    multihost = plan.process_count > 1
-    if multihost:
-        if backend != "shard_map" or mesh is None:
-            raise ValueError(
-                "multi-host ingestion (process_count > 1) requires "
-                "backend='shard_map' and a mesh")
-        plan.validate_for_mesh(mesh)
-
     setup = prepare_training(
         corpus, raw_vocab_size, strategy, num_workers, cfg,
         epochs=epochs, batch_size=batch_size, rate=rate, window=window,
@@ -327,9 +372,28 @@ def train_submodels(
         steps_per_chunk=steps_per_chunk,
         sentences_per_block=sentences_per_block,
         process_index=process_index, process_count=process_count)
+    return train_prepared(setup, strategy=strategy, backend=backend,
+                          mesh=mesh, prefetch=prefetch)
+
+
+def train_prepared(setup: TrainingSetup, *, strategy: str = "random",
+                   backend: str = "vmap", mesh=None,
+                   prefetch: int = 2) -> PipelineResult:
+    """The train loop over a prepared :class:`TrainingSetup` (text or
+    graph): this host's chunk stream, prefetched, through
+    :meth:`AsyncShardTrainer.epoch` for every chunk of every epoch."""
+    plan = setup.plan
+    multihost = plan.process_count > 1
+    if multihost:
+        if backend != "shard_map" or mesh is None:
+            raise ValueError(
+                "multi-host ingestion (process_count > 1) requires "
+                "backend='shard_map' and a mesh")
+        plan.validate_for_mesh(mesh)
     cfg, engine, sched = setup.cfg, setup.engine, setup.sched
     streams, union, mask = setup.streams, setup.union_vocab, setup.mask
     neg_table, t_vocab = setup.neg_table, setup.vocab_s
+    num_workers, seed = plan.num_workers, setup.seed
 
     trainer = AsyncShardTrainer(
         cfg=cfg, num_workers=num_workers, total_steps=sched.total_steps,
@@ -345,12 +409,13 @@ def train_submodels(
     # This host's ingestion: only its plan block of worker streams is
     # ever extracted (single-host: the block is all workers).
     chunk_stream = plan.chunk_stream(
-        streams, batch_size=batch_size, steps_per_chunk=sched.chunk_steps,
-        sentences_per_block=sentences_per_block)
+        streams, batch_size=setup.batch_size,
+        steps_per_chunk=sched.chunk_steps,
+        sentences_per_block=setup.sentences_per_block)
 
     losses = []
     t_train0 = time.perf_counter()
-    for epoch in range(epochs):
+    for epoch in range(setup.epochs):
         ep_key = _epoch_key(seed, _STREAM_ASYNC_DATA, epoch)
         ep_losses = []
         # Host extraction + H2D copy of chunk k+1 overlap the device's

@@ -84,6 +84,7 @@ from dataclasses import dataclass, replace
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import sgns
 from repro.core.sgns import SGNSConfig
 from repro.data.pairs import negative_sampler_fn
@@ -131,10 +132,12 @@ class UpdateEngine:
     def make_packed_step(self, cfg: SGNSConfig, total_steps: int):
         """:meth:`make_step` on packed tables — what a scan of steps
         carries, so that the layout is converted once per scan. Its step
-        returns ``(params, mean_loss, rows)``: ``rows`` (int32) counts
-        the table rows the step's kernel moves between HBM and VMEM by
-        DMA, 0 where no kernel moves rows by DMA."""
-        return _no_rows(self.make_step(cfg, total_steps))
+        returns ``(params, mean_loss, counts)``: ``counts`` maps each
+        name of ``repro.obs.STEP_COUNTERS`` to an int32 scalar, the
+        table rows the step's kernel moves between HBM and VMEM by DMA
+        and the row references to its hot tier, each 0 where the engine
+        has no such kernel or tier."""
+        return _no_counts(self.make_step(cfg, total_steps))
 
     def row_bytes(self, cfg: SGNSConfig) -> int:
         """Bytes of one table row as the packed step moves it."""
@@ -151,11 +154,11 @@ class UpdateEngine:
         return f"{self.name}:{self.sampler}"
 
 
-def _no_rows(step):
-    """``step`` with a count of 0 DMA-moved rows beside its loss."""
+def _no_counts(step):
+    """``step`` with its counters, all 0, beside its loss."""
     def packed(*args):
         params, loss = step(*args)
-        return params, loss, jnp.int32(0)
+        return params, loss, {n: jnp.int32(0) for n in obs.STEP_COUNTERS}
 
     return packed
 
@@ -362,10 +365,11 @@ class FusedPipePallasEngine(FusedHBMPallasEngine):
     def make_packed_step(self, cfg: SGNSConfig, total_steps: int):
         """One pipelined-kernel step on row-layout tables (multi-slot DMA
         ring, deduped row traffic, the hot tier when ``hot_rows > 0``),
-        counting the rows its kernel moves; ``sequential=True`` falls
-        back to the HBM oracle, whose rows are not counted."""
+        counting the rows its kernel moves and its hot-tier references;
+        ``sequential=True`` falls back to the HBM oracle, which counts
+        neither."""
         if self.sequential:
-            return _no_rows(FusedHBMPallasEngine.make_step(
+            return _no_counts(FusedHBMPallasEngine.make_step(
                 self, cfg, total_steps))
         from repro.kernels.sgns_fused_pipe import fused_pipe_rows_step
 

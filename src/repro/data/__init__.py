@@ -6,7 +6,8 @@ semantic structure (`corpus.SemanticCorpusModel`) so that the evaluation
 benchmarks (similarity / analogy / categorization) have exact gold data.
 Everything downstream (vocab building, subsampling, window extraction,
 negative-sampling tables, per-worker sample streams) is implemented in
-full, as it would be for real text.
+full, as it would be for real text. Graphs (``graph``) enter as an
+edge list and stream DeepWalk's random-walk pairs per worker.
 """
 
 from repro.data.corpus import SemanticCorpusModel, Corpus
@@ -19,6 +20,12 @@ from repro.data.pairs import (
     build_noise_table,
     stack_noise_tables,
     subsample_mask,
+)
+from repro.data.graph import (
+    Graph,
+    WalkStream,
+    make_walk_streams,
+    relabel_by_degree,
 )
 from repro.data.pipeline import (
     HostShardPlan,
@@ -47,4 +54,8 @@ __all__ = [
     "make_worker_streams",
     "prefetch_chunks",
     "stacked_pair_batches",
+    "Graph",
+    "WalkStream",
+    "make_walk_streams",
+    "relabel_by_degree",
 ]

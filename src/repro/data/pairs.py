@@ -35,6 +35,39 @@ def subsample_mask(
     return keep & (tokens != UNK)
 
 
+def window_pairs(
+    toks: np.ndarray, sent_id: np.ndarray, window: int,
+    rng: np.random.Generator,
+) -> tuple[np.ndarray, np.ndarray]:
+    """word2vec's pair cut of a token stream already filtered: a window
+    drawn per token from ``[1, window]``, pairs in both directions
+    within a sentence (``sent_id`` per token), then one permutation of
+    all the pairs. Draws the windows, then the permutation, from
+    ``rng``."""
+    n = len(toks)
+    if n == 0:
+        return np.zeros(0, np.int32), np.zeros(0, np.int32)
+
+    dyn = rng.integers(1, window + 1, size=n)
+    centers_parts, contexts_parts = [], []
+    for off in range(1, window + 1):
+        # pair (i, i+off) valid both directions when off <= dyn of the center
+        valid = np.arange(n - off)
+        same_sent = sent_id[valid] == sent_id[valid + off]
+        fwd = same_sent & (off <= dyn[valid])
+        bwd = same_sent & (off <= dyn[valid + off])
+        i = valid[fwd]
+        centers_parts.append(toks[i])
+        contexts_parts.append(toks[i + off])
+        j = valid[bwd]
+        centers_parts.append(toks[j + off])
+        contexts_parts.append(toks[j])
+    centers = np.concatenate(centers_parts).astype(np.int32)
+    contexts = np.concatenate(contexts_parts).astype(np.int32)
+    perm = rng.permutation(len(centers))
+    return centers[perm], contexts[perm]
+
+
 def extract_pairs(
     corpus: Corpus,
     vocab: Vocab,
@@ -61,29 +94,7 @@ def extract_pairs(
         np.arange(corpus.num_sentences, dtype=np.int64),
         np.diff(corpus.offsets),
     )
-    toks, sent_id = toks[keep], sent_id[keep]
-    n = len(toks)
-    if n == 0:
-        return np.zeros(0, np.int32), np.zeros(0, np.int32)
-
-    dyn = rng.integers(1, window + 1, size=n)
-    centers_parts, contexts_parts = [], []
-    for off in range(1, window + 1):
-        # pair (i, i+off) valid both directions when off <= dyn of the center
-        valid = np.arange(n - off)
-        same_sent = sent_id[valid] == sent_id[valid + off]
-        fwd = same_sent & (off <= dyn[valid])
-        bwd = same_sent & (off <= dyn[valid + off])
-        i = valid[fwd]
-        centers_parts.append(toks[i])
-        contexts_parts.append(toks[i + off])
-        j = valid[bwd]
-        centers_parts.append(toks[j + off])
-        contexts_parts.append(toks[j])
-    centers = np.concatenate(centers_parts).astype(np.int32)
-    contexts = np.concatenate(contexts_parts).astype(np.int32)
-    perm = rng.permutation(len(centers))
-    centers, contexts = centers[perm], contexts[perm]
+    centers, contexts = window_pairs(toks[keep], sent_id[keep], window, rng)
     if max_pairs is not None:
         centers, contexts = centers[:max_pairs], contexts[:max_pairs]
     return centers, contexts

@@ -375,6 +375,19 @@ def row_traffic(plan: PipelinePlan, hot_rows: int = 0) -> jax.Array:
             + 4 * hot_rows).astype(jnp.int32)
 
 
+def hot_touches(centers: jax.Array, contexts: jax.Array,
+                negatives: jax.Array, hot_rows: int) -> jax.Array:
+    """Row references of one step that fall in the hot tier, as an int32
+    device scalar: the centres, contexts and negatives (``K + 2`` per
+    pair, padding excluded) with ``id < hot_rows``. For the
+    ``sgns.hot_touches`` counter; 0 without a hot tier."""
+    if hot_rows <= 0:
+        return jnp.int32(0)
+    hot = jnp.int32(hot_rows)
+    return sum(jnp.sum(a < hot, dtype=jnp.int32)
+               for a in (centers, contexts, negatives))
+
+
 def plan_row_traffic(plan: PipelinePlan, hot_rows: int = 0) -> int:
     """:func:`row_traffic` read back: the ``hbm_rows_per_step`` quantity
     the ``@zipf50k`` BENCH rows gate on and ``repro.analysis.contracts``
@@ -735,13 +748,15 @@ def fused_pipe_rows_step(
     ring_depth: int,
     hot_rows: int,
     interpret: bool,
-) -> tuple[dict, jax.Array, jax.Array]:
+) -> tuple[dict, jax.Array, dict]:
     """One step of the pipelined (``hot_rows = 0``) or tiered HBM kernel
     on tables already in the kernels' row layout
     (:func:`to_row_layout`) — the form a scan of steps carries, so that
     the layout is converted once per epoch rather than once per step.
     ``hot_rows`` must lie in ``[0, V]``. Returns the tables, the mean
-    loss and the rows the kernel moves (:func:`row_traffic`)."""
+    loss and the step's counters (``repro.obs.STEP_COUNTERS``): the rows
+    the kernel moves (:func:`row_traffic`) and the references to hot
+    rows (:func:`hot_touches`)."""
     V, _, dp = tables["W"].shape
     B = centers.shape[0]
     K = negatives
@@ -751,14 +766,16 @@ def fused_pipe_rows_step(
         plan = plan_blocks(centers, contexts, neg_ids, V, block_pairs,
                            hot_rows=hot_rows, ring_depth=ring_depth)
         meta, cnt = block_meta(plan, hot_rows > 0)
-        rows = row_traffic(plan, hot_rows)
+        counts = {obs.ROWS_MOVED: row_traffic(plan, hot_rows),
+                  obs.HOT_TOUCHES: hot_touches(centers, contexts, neg_ids,
+                                               hot_rows)}
     run = _worker_kernel(V, dp, plan.nblocks, plan.block_pairs, K,
                          ring_depth, hot_rows, B, interpret)
     W, C, loss = run(jnp.reshape(lr, (1,)).astype(jnp.float32), cnt[None],
                      meta[None], tables["W"][None], tables["C"][None])
     # padded pairs were masked to exactly-zero loss, so the batch mean
     # divides by the true pair count
-    return {"W": W[0], "C": C[0]}, jnp.sum(loss) / B, rows
+    return {"W": W[0], "C": C[0]}, jnp.sum(loss) / B, counts
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -20,6 +20,15 @@ double-buffered successor
 block planner), and ``pallas_fused_tiered`` adds frequency-tiered
 placement on top (``--hot-rows`` hottest rows pinned VMEM-resident,
 cold rows behind a ``--ring-depth``-slot DMA ring).
+
+``--graph edges.npz`` trains node embeddings instead (DeepWalk): each
+worker's RANDOM SAMPLING (``--rate``) of the 80 walk starts per node
+streams walks of 40 nodes, whose pairs train its sub-model; the merged
+table's rows are the graph's nodes in descending degree order.
+
+  PYTHONPATH=src python -m repro.data.graph --out /tmp/edges.npz
+  PYTHONPATH=src python -m repro.launch.train_sgns --graph /tmp/edges.npz \
+      --workers 4 --rate 0.02 --epochs 1 --dim 64 --merge alir_pca
 """
 
 from __future__ import annotations
@@ -28,12 +37,15 @@ import argparse
 
 import numpy as np
 
-from repro.core.driver import run_pipeline, train_sync_baseline
+from repro.core.driver import (apply_merges, prepare_graph_training,
+                               run_pipeline, train_prepared,
+                               train_sync_baseline)
 from repro.core.engine import get_engine
 from repro.core.sgns import SGNSConfig
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import multihost_train_kwargs
 from repro.data.corpus import SemanticCorpusModel
+from repro.data.graph import Graph
 from repro.eval.benchmarks import BenchmarkSuite, evaluate_all
 from repro.checkpoint import save_checkpoint
 
@@ -103,6 +115,11 @@ def main(argv=None):
     ap.add_argument("--no-resume", action="store_true",
                     help="with --elastic-state: ignore existing "
                          "checkpoints and train from scratch")
+    ap.add_argument("--graph", default=None, metavar="EDGES_NPZ",
+                    help="train DeepWalk node embeddings on this edge "
+                         "list (src, dst, num_nodes; `python -m "
+                         "repro.data.graph` writes one) instead of the "
+                         "synthetic corpus")
     ap.add_argument("--save", default=None, help="checkpoint path (.npz)")
     ap.add_argument("--publish", default=None, metavar="DIR",
                     help="incrementally ALiR-fold the sub-models and "
@@ -134,6 +151,8 @@ def main(argv=None):
                             batch=args.batch)
     print(f"vmem: {est.summary()}")
     processes, train_kw = multihost_train_kwargs(args.workers, args.processes)
+    if args.graph:
+        return train_graph(args, processes, train_kw)
 
     gen = SemanticCorpusModel.create(vocab_size=args.vocab, seed=0)
     corpus = gen.generate(num_sentences=args.sentences, seed=1)
@@ -142,7 +161,6 @@ def main(argv=None):
                      negatives=args.negatives)
 
     if args.elastic_state:
-        from repro.core.driver import apply_merges
         from repro.elastic import train_submodels_elastic
 
         res = train_submodels_elastic(
@@ -204,12 +222,44 @@ def main(argv=None):
               f"--query <ids>")
 
     if args.save:
-        best = args.merge[-1]
-        emb, valid = res.merged[best]
-        save_checkpoint(args.save, {"embedding": emb, "valid": valid,
-                                    "word_ids": res.union_vocab.word_ids},
-                        extra={"method": best, "strategy": args.strategy})
-        print(f"saved merged embedding → {args.save}")
+        save_merged(res, args)
+
+
+def save_merged(res, args):
+    best = args.merge[-1]
+    emb, valid = res.merged[best]
+    save_checkpoint(args.save, {"embedding": emb, "valid": valid,
+                                "word_ids": res.union_vocab.word_ids},
+                    extra={"method": best, "strategy": args.strategy})
+    print(f"saved merged embedding → {args.save}")
+
+
+def train_graph(args, processes, train_kw):
+    """``--graph``: DeepWalk through the same trainer and merge."""
+    if args.elastic_state or args.baseline or args.publish:
+        raise SystemExit("--graph runs without --elastic-state, "
+                         "--baseline and --publish")
+    graph = Graph.load(args.graph)
+    cfg = SGNSConfig(vocab_size=0, dim=args.dim, window=args.window,
+                     negatives=args.negatives)
+    setup = prepare_graph_training(
+        graph, args.workers, cfg, epochs=args.epochs, batch_size=args.batch,
+        rate=args.rate, engine=args.engine,
+        process_index=args.process_index, process_count=processes)
+    res = train_prepared(setup, **train_kw)
+    res = apply_merges(res, tuple(args.merge), out_dim=cfg.dim,
+                       fan_in=args.merge_fan_in, shard=args.merge_shard)
+    print(f"graph {graph.num_nodes} nodes {graph.num_edges} edges "
+          f"workers={args.workers} engine={args.engine.describe()} "
+          f"train={res.timings['train_s']:.1f}s "
+          f"steps/epoch={res.timings['steps_per_epoch']} "
+          f"losses={['%.3f' % l for l in res.losses]}")
+    for m, (emb, _) in res.merged.items():
+        print(f"  {m:10s} table {emb.shape} "
+              f"merge={res.timings.get('merge_%s_s' % m, 0):.2f}s")
+    if args.save:
+        save_merged(res, args)
+    return res
 
 
 if __name__ == "__main__":

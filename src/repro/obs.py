@@ -36,6 +36,7 @@ import numpy as np
 INGEST_EXTRACT = "repro.ingest.extract"   # one chunk filled from the streams
 INGEST_H2D = "repro.ingest.h2d"           # one chunk's host→device copy
 INGEST_WAIT = "repro.ingest.wait"         # the consumer waiting for a chunk
+INGEST_WALK = "repro.ingest.walk"         # one block of graph walks and its pairs
 TRAIN_DISPATCH = "repro.train.dispatch"   # one chunk's epoch call enqueued
 
 # device scopes, for the planner's and the layout's shares of the step
@@ -46,10 +47,19 @@ LAYOUT_SCOPE = "sgns.layout"    # (V, d) <-> (V, 1, dp) once per chunk
 # ``step_nonkernel_share`` and ``kernel_dma_bw_share``
 SGNS_KERNEL = "sgns_hbm_pipe"
 
-# counters, read by ``rows_moved_per_pair`` and ``kernel_dma_bw_share``
+# counters, read by ``rows_moved_per_pair``, ``kernel_dma_bw_share``
+# and ``hot_row_share``
 ROWS_MOVED = "sgns.rows_moved"    # table rows the kernel moved HBM<->VMEM
 BYTES_MOVED = "sgns.bytes_moved"  # the same rows in bytes
 PAIRS = "sgns.pairs"              # skip-gram pairs trained
+HOT_TOUCHES = "sgns.hot_touches"  # row references (centre, context,
+                                  # negatives) to the hot tier's rows
+# the counters a training step computes on the device, beside its loss
+STEP_COUNTERS = (ROWS_MOVED, HOT_TOUCHES)
+
+# counters of the graph walk source, read by ``walk_host_share``
+WALK_NS = "graph.walk_ns"         # host ns inside ``repro.ingest.walk``
+GRAPH_PAIRS = "graph.pairs"       # pairs the walk source cut
 
 FOLD_EVERY = 256   # device values kept per counter before a device-side sum
 

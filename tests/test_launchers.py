@@ -50,6 +50,27 @@ def test_train_sgns_cli(capsys):
     assert "alir_pca" in out and "sim=" in out
 
 
+def test_train_sgns_graph_cli(tmp_path, capsys):
+    """``--graph``: an edge list written by ``python -m repro.data.graph``
+    trains DeepWalk sub-models through the same loop and merge, and the
+    saved table's rows are the nodes in descending degree order."""
+    from repro.data.graph import Graph, main as graph_main
+    from repro.launch.train_sgns import main
+    edges, saved = tmp_path / "edges.npz", tmp_path / "emb.npz"
+    graph_main(["--out", str(edges), "--nodes", "600", "--edges", "1500",
+                "--max-degree", "60"])
+    main(["--graph", str(edges), "--workers", "2", "--rate", "0.01",
+          "--epochs", "1", "--dim", "16", "--merge", "alir_pca",
+          "--save", str(saved)])
+    out = capsys.readouterr().out
+    assert "graph 600 nodes 1500 edges" in out and "(600, 16)" in out
+    deg = Graph.load(edges).degrees
+    from repro.checkpoint import load_checkpoint
+    word_ids = np.asarray(load_checkpoint(str(saved))[0]["word_ids"])
+    assert sorted(word_ids.tolist()) == list(range(600))
+    assert np.all(np.diff(deg[word_ids]) <= 0)
+
+
 def test_train_sgns_publish_then_serve_cli(tmp_path, capsys):
     """The full production loop at CLI granularity: train with --publish,
     then answer queries (merged and sub-model space) with the serve

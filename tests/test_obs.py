@@ -130,6 +130,31 @@ def test_rows_moved_is_the_plan_row_traffic_of_every_step(engine, hot_rows):
     assert obs.read(obs.BYTES_MOVED) == expect * 4 * D
 
 
+@pytest.mark.parametrize("engine,hot_rows", [
+    ("pallas_fused_pipe", 0), ("pallas_fused_tiered", 24)])
+def test_hot_touches_is_a_numpy_recount_of_every_step(engine, hot_rows):
+    """``sgns.hot_touches`` over one chunk is the count, in NumPy, of each
+    step's centres, contexts and replayed negatives with id < hot_rows;
+    0 for the engine without a hot tier."""
+    eng = get_engine(engine, block_pairs=BLK, **(
+        {"hot_rows": hot_rows} if hot_rows else {}))
+    tr = _trainer(eng)
+    c, x, table = _chunk(4)
+    key = jax.random.PRNGKey(11)
+    tr.epoch(tr.init(jax.random.PRNGKey(0)), c, x, table, key, step0=0)
+
+    expect = 0
+    for w, wkey in enumerate(jax.random.split(key, N)):
+        for s in range(S):
+            wkey, sub = jax.random.split(wkey)
+            negs = fused_negative_ids(_as_seed(sub), table["prob"][w],
+                                      table["alias"][w], (B, 4))
+            for ids in (c[w, s], x[w, s], negs):
+                expect += int(np.sum(np.asarray(ids) < hot_rows))
+    assert obs.read(obs.HOT_TOUCHES) == expect
+    assert (expect > 0) == (hot_rows > 0)
+
+
 def test_row_traffic_is_one_definition():
     c, x, table = _chunk(1)
     negs = fused_negative_ids(_as_seed(jax.random.PRNGKey(1)),
@@ -173,6 +198,8 @@ def test_epoch_returns_params_and_losses_of_the_steps(engine):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     rows = obs.read(obs.ROWS_MOVED)
     assert (rows == 0) == engine.startswith("sparse")
+    # the hot-tier counter rides the same epoch, which stays bit-identical
+    assert (obs.read(obs.HOT_TOUCHES) > 0) == (engine == "pallas_fused_tiered")
 
 
 # ------------------------------------------------------------ device scopes

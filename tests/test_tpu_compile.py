@@ -8,7 +8,7 @@ kernels compile at the paper's 300k×500 shape and at ``ZIPF50K``, under
 the kernel's own name; the 4-worker vmapped epoch of the tiered engine
 must fit one chip, with its planner and layout scopes in the compiled
 program, and a planner that compiles to no loop and no gather but the
-noise draw's.
+noise draw's; so must the DeepWalk cell's 5 workers at 1,138,499 × 128.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may hold the TPU library, and every test
@@ -103,28 +103,46 @@ def _assert_planner_has_no_loop_or_gather(text, V):
             assert shapes[gather[1]].split(",")[-1] == str(V), line
 
 
-def test_four_worker_tiered_epoch_fits_one_v5e(one_chip):
-    """The paper's shape, 4 workers vmapped on one chip: the compiled
-    epoch (tables donated, the kernel's row layout carried through the
-    scan) must fit the chip's memory."""
-    n, V, d, S, B = 4, PAPER["V"], PAPER["D"], 128, PAPER["B"]
+def _compile_tiered_epoch(one_chip, n, V, d, S=128, B=512):
     engine = get_engine("pallas_fused_tiered", interpret=False,
                         hot_rows=PAPER["HOT"], block_pairs=PAPER["BLK"])
     tr = AsyncShardTrainer(
         cfg=SGNSConfig(vocab_size=V, dim=d, window=10, negatives=PAPER["K"]),
         num_workers=n, total_steps=1000, engine=engine)
     sds = lambda s, t: _sds(one_chip, s, t)
-    compiled = tr._jit_epoch().lower(
+    return tr._jit_epoch().lower(
         {"W": sds((n, V, d), jnp.float32), "C": sds((n, V, d), jnp.float32)},
         sds((n, S, B), jnp.int32), sds((n, S, B), jnp.int32),
         {"prob": sds((n, V), jnp.float32), "alias": sds((n, V), jnp.int32)},
         sds((n, 2), jnp.uint32), sds((n,), jnp.int32)).compile()
-    text = compiled.as_text()
-    for name in (SGNS_KERNEL, PLAN_SCOPE, LAYOUT_SCOPE):
-        assert name in text, name
-    _assert_planner_has_no_loop_or_gather(text, V)
+
+
+def _assert_fits_with_tables_donated(compiled, n, V, d):
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert m.alias_size_in_bytes >= 2 * n * V * d * 4     # tables donated
     assert total <= V5E_HBM_BYTES, total
+
+
+def test_four_worker_tiered_epoch_fits_one_v5e(one_chip):
+    """The paper's shape, 4 workers vmapped on one chip: the compiled
+    epoch (tables donated, the kernel's row layout carried through the
+    scan) must fit the chip's memory."""
+    n, V, d = 4, PAPER["V"], PAPER["D"]
+    compiled = _compile_tiered_epoch(one_chip, n, V, d)
+    text = compiled.as_text()
+    for name in (SGNS_KERNEL, PLAN_SCOPE, LAYOUT_SCOPE):
+        assert name in text, name
+    _assert_planner_has_no_loop_or_gather(text, V)
+    _assert_fits_with_tables_donated(compiled, n, V, d)
+
+
+def test_five_worker_deepwalk_epoch_fits_one_v5e(one_chip):
+    """The DeepWalk cell's share of its deployment: 5 workers of the
+    1,138,499-node YouTube graph at d = 128 (no lane padding) on one
+    chip, the hot tier's counter summed in the epoch."""
+    n, V, d = 5, 1_138_499, 128
+    compiled = _compile_tiered_epoch(one_chip, n, V, d)
+    assert SGNS_KERNEL in compiled.as_text()
+    _assert_fits_with_tables_donated(compiled, n, V, d)
