@@ -26,8 +26,9 @@ with several chips, several hosts, or the dry-run's simulated mesh).
 
 from __future__ import annotations
 
+from collections.abc import Mapping
 from dataclasses import dataclass, field
-from functools import partial
+from functools import cached_property, partial
 
 import jax
 import jax.numpy as jnp
@@ -35,50 +36,156 @@ from jax.sharding import Mesh, PartitionSpec as P, NamedSharding
 
 from repro import obs
 from repro.core import sgns
-from repro.core.engine import get_engine
+from repro.core.engine import UpdateEngine, get_engine
 from repro.core.sgns import SGNSConfig
 
 # ---------------------------------------------------------------------------
 # Single-worker epoch: scan over a fixed number of steps.
 # ---------------------------------------------------------------------------
 def make_worker_epoch(cfg: SGNSConfig, total_steps: int, engine="sparse"):
-    """Returns epoch_fn(params, centers (S,B), contexts (S,B), neg_table,
-    key, step0) -> (params, losses (S,), counts): ``counts`` maps each
-    name of ``repro.obs.STEP_COUNTERS`` to its int32 sum over the S
-    steps (the table rows the engine's kernel moved, the row references
-    to its hot tier).
+    """Returns epoch_fn(tables, centers (S,B), contexts (S,B), neg_table,
+    key, step0) -> (tables, losses (S,), counts): ``tables`` are the
+    worker's W and C in the engine's packed layout (``engine.pack`` of
+    each ``(V, d)`` table), taken and returned as the scan carries them;
+    ``counts`` maps each name of ``repro.obs.STEP_COUNTERS`` to its
+    int32 sum over the S steps (the table rows the engine's kernel
+    moved, the row references to its hot tier).
 
     ``engine`` (an :class:`repro.core.engine.UpdateEngine` or spec
     string) owns the whole per-step compute: negative draw, row grads,
     parameter apply. ``neg_table`` is the worker's *own* unigram^0.75
     noise table in the layout ``engine.table_kind`` names — a ``(V,)``
     CDF or a ``{'prob', 'alias'}`` Vose table (each sub-model draws from
-    its own sample's noise distribution, paper §3.2). The scan carries
-    the tables in the engine's packed layout, converted once per call.
+    its own sample's noise distribution, paper §3.2).
     """
     engine = get_engine(engine)
     step = engine.make_packed_step(cfg, total_steps)
 
-    def epoch_fn(params, centers, contexts, neg_table, key, step0):
+    def epoch_fn(tables, centers, contexts, neg_table, key, step0):
         def body(carry, xs):
-            params, key, i, totals = carry
+            tables, key, i, totals = carry
             c_b, x_b = xs
             key, sub = jax.random.split(key)
-            params, loss, counts = step(params, c_b, x_b, neg_table, sub,
+            tables, loss, counts = step(tables, c_b, x_b, neg_table, sub,
                                         step0 + i)
             totals = {n: totals[n] + counts[n] for n in totals}
-            return (params, key, i + 1, totals), loss
+            return (tables, key, i + 1, totals), loss
 
-        with jax.named_scope(obs.LAYOUT_SCOPE):
-            packed = engine.pack(params)
         zeros = {n: jnp.int32(0) for n in obs.STEP_COUNTERS}
-        (params, _, _, totals), losses = jax.lax.scan(
-            body, (packed, key, jnp.int32(0), zeros), (centers, contexts))
-        with jax.named_scope(obs.LAYOUT_SCOPE):
-            params = engine.unpack(params, cfg)
-        return params, losses, totals
+        (tables, _, _, totals), losses = jax.lax.scan(
+            body, (tables, key, jnp.int32(0), zeros), (centers, contexts))
+        return tables, losses, totals
 
     return epoch_fn
+
+
+def converting(epoch_fn, engine: UpdateEngine, cfg: SGNSConfig):
+    """``epoch_fn`` of :func:`make_worker_epoch` on ``(V, d)`` tables:
+    packed before the scan and unpacked after it, inside one program."""
+    def fn(params, *args):
+        with jax.named_scope(obs.LAYOUT_SCOPE):
+            tables = {k: engine.pack(v) for k, v in params.items()}
+        tables, losses, counts = epoch_fn(tables, *args)
+        with jax.named_scope(obs.LAYOUT_SCOPE):
+            params = {k: engine.unpack(v, cfg) for k, v in tables.items()}
+        return params, losses, counts
+
+    return fn
+
+
+# ---------------------------------------------------------------------------
+# The trainer's tables, in the layout the engine's step carries
+# ---------------------------------------------------------------------------
+@dataclass(frozen=True)
+class TableLayout:
+    """How a trainer's stacked ``(n, V, d)`` tables convert to and from
+    its engine's packed layout: one small jitted program per direction,
+    one table per call, under the layout scope (``repro.obs``)."""
+
+    engine: UpdateEngine
+    cfg: SGNSConfig
+    sharding: NamedSharding | None = None   # the tables' (shard_map)
+
+    @cached_property
+    def converts(self) -> bool:
+        """Whether the packed layout differs from ``(n, V, d)``, seen in
+        the shape :meth:`UpdateEngine.pack` gives; where it does not,
+        nothing is ever converted."""
+        table = jax.ShapeDtypeStruct(
+            (1, self.cfg.vocab_size, self.cfg.dim), jnp.float32)
+        return jax.eval_shape(self.engine.pack, table).shape != table.shape
+
+    @cached_property
+    def pack(self):
+        return self._jit(self.engine.pack)
+
+    @cached_property
+    def unpack(self):
+        return self._jit(partial(self.engine.unpack, cfg=self.cfg))
+
+    def _jit(self, fn):
+        def convert(table):
+            with jax.named_scope(obs.LAYOUT_SCOPE):
+                return fn(table)
+
+        if self.sharding is None:
+            return jax.jit(convert)
+        return jax.jit(convert, out_shardings=self.sharding)
+
+
+@jax.tree_util.register_pytree_node_class
+class Tables(Mapping):
+    """Every worker's tables (``"W"``, ``"C"``), kept between chunks in
+    the layout the engine's step carries, so that consecutive
+    :meth:`AsyncShardTrainer.epoch` calls convert nothing.
+
+    ``tables["W"]`` is the ``(n, V, d)`` table: the first read converts
+    every table back, one at a time and each in place of its packed
+    form, so that both layouts of all tables are never held at once;
+    the next ``epoch`` packs them again. As a pytree its leaves are the
+    arrays in the layout they are in now: ``jax.block_until_ready``
+    waits for them and converts nothing. Each conversion of the whole
+    set counts once in ``repro.obs.RELAYOUTS``."""
+
+    def __init__(self, arrays, layout: TableLayout, packed: bool):
+        self._arrays = dict(arrays)
+        self._layout = layout
+        self._packed = packed
+
+    def __getitem__(self, name):
+        return self._as(packed=False)[name]
+
+    def __iter__(self):
+        return iter(self._arrays)
+
+    def __len__(self):
+        return len(self._arrays)
+
+    def packed(self) -> dict:
+        """The arrays in the engine's packed layout, packed first if
+        they are not."""
+        return dict(self._as(packed=True))
+
+    def _as(self, packed: bool) -> dict:
+        if self._packed != packed and self._layout.converts:
+            convert = self._layout.pack if packed else self._layout.unpack
+            for name in list(self._arrays):
+                # Wait for each conversion before dropping the table it
+                # read: the next one then reuses its memory.
+                self._arrays[name] = convert(
+                    self._arrays[name]).block_until_ready()
+            obs.count(obs.RELAYOUTS, 1)
+        self._packed = packed
+        return self._arrays
+
+    def tree_flatten(self):
+        return (tuple(self._arrays.values()),
+                (tuple(self._arrays), self._layout, self._packed))
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        names, layout, packed = aux
+        return cls(zip(names, children), layout, packed)
 
 
 # ---------------------------------------------------------------------------
@@ -102,6 +209,10 @@ class AsyncShardTrainer:
     own workers' extracted rows and the trainer assembles the global
     ``(n, ...)`` device arrays (zero inter-host parameter traffic — the
     only multi-host exchange is the input assembly itself).
+
+    The trainer owns its tables' layout: :meth:`epoch` returns them as
+    :class:`Tables` in the engine's packed layout and takes them back
+    as they are, converting only when the tables are read (``layout``).
     """
 
     cfg: SGNSConfig
@@ -114,10 +225,16 @@ class AsyncShardTrainer:
     _jitted: object = field(default=None, init=False, repr=False, compare=False)
     _jitted_single: object = field(default=None, init=False, repr=False,
                                    compare=False)
+    layout: TableLayout = field(default=None, init=False, repr=False,
+                                compare=False)
 
     def __post_init__(self):
         self.engine = get_engine(self.engine)
         self.engine.validate(vocab_size=self.cfg.vocab_size)
+        sharding = (NamedSharding(self.mesh, P("worker"))
+                    if self.backend == "shard_map" and self.mesh is not None
+                    else None)
+        self.layout = TableLayout(self.engine, self.cfg, sharding)
         if self.plan is not None:
             if self.plan.num_workers != self.num_workers:
                 raise ValueError(
@@ -198,17 +315,23 @@ class AsyncShardTrainer:
             neg_table)
 
     def epoch(self, params, centers, contexts, neg_table, key, step0=0):
-        """params: (n,V,d) pytree; centers/contexts: (n,S,B);
-        neg_table: (n,V) CDF or {'prob','alias'} of (n,V) alias tables.
-        Returns ``(params, losses)``; counts the chunk's pairs, the rows
-        (and bytes) its kernel moved and its references to hot rows
+        """params: the ``{"W", "C"}`` (n,V,d) tables, or the
+        :class:`Tables` a previous call returned; centers/contexts:
+        (n,S,B); neg_table: (n,V) CDF or {'prob','alias'} of (n,V) alias
+        tables. Returns ``(tables, losses)``, the tables as
+        :class:`Tables` in the engine's packed layout (packed here only
+        if ``params`` were not); counts the chunk's pairs, the rows (and
+        bytes) its kernel moved and its references to hot rows
         (``repro.obs``), read back only when a counter is read."""
         ids = {"step0": step0} if isinstance(step0, int) else {}
         with obs.span(obs.TRAIN_DISPATCH, **ids):
+            if not isinstance(params, Tables):
+                params = Tables(params, self.layout, packed=False)
             keys = jax.random.split(key, self.num_workers)
             step0 = jnp.full((self.num_workers,), step0, dtype=jnp.int32)
-            params, losses, counts = self._jit_epoch()(
-                params, centers, contexts, neg_table, keys, step0)
+            tables, losses, counts = self._jit_epoch()(
+                params.packed(), centers, contexts, neg_table, keys, step0)
+            params = Tables(tables, self.layout, packed=True)
             obs.count(obs.PAIRS, centers.size)
             for name, value in counts.items():
                 obs.count(name, value)
@@ -231,14 +354,16 @@ class AsyncShardTrainer:
         guaranteed bit-identical, so elasticity equivalence is defined
         against this path, not against :meth:`epoch`)."""
         if self._jitted_single is None:
-            object.__setattr__(self, "_jitted_single",
-                               jax.jit(self._epoch_fn()))
+            object.__setattr__(self, "_jitted_single", jax.jit(
+                converting(self._epoch_fn(), self.engine, self.cfg)))
         params, losses, _ = self._jitted_single(
             params, centers, contexts, neg_table, key, jnp.int32(step0))
         return params, losses
 
     def lower_epoch(self, steps: int, batch: int):
-        """Lower the sharded epoch for the dry-run, ShapeDtypeStruct only."""
+        """Lower the sharded epoch for the dry-run, ShapeDtypeStruct only
+        (the tables in the engine's packed layout, as :meth:`epoch`
+        passes them)."""
         assert self.mesh is not None
         n, V, d = self.num_workers, self.cfg.vocab_size, self.cfg.dim
         spec = P("worker")
@@ -248,7 +373,9 @@ class AsyncShardTrainer:
             neg = {"prob": sh((n, V), jnp.float32), "alias": sh((n, V), jnp.int32)}
         else:
             neg = sh((n, V), jnp.float32)       # per-worker negative CDFs
-        params = {"W": sh((n, V, d), jnp.float32), "C": sh((n, V, d), jnp.float32)}
+        table = jax.eval_shape(self.engine.pack,
+                               jax.ShapeDtypeStruct((n, V, d), jnp.float32))
+        params = {k: sh(table.shape, table.dtype) for k in ("W", "C")}
         args = (
             params,
             sh((n, steps, batch), jnp.int32),   # centers

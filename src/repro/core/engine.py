@@ -120,18 +120,18 @@ class UpdateEngine:
         step_idx) -> (params, mean_loss)``."""
         raise NotImplementedError
 
-    def pack(self, params: dict) -> dict:
-        """``(V, d)`` tables → the layout :meth:`make_packed_step`'s
+    def pack(self, table: jax.Array) -> jax.Array:
+        """A ``(..., V, d)`` table → the layout :meth:`make_packed_step`'s
         step carries (identity unless a kernel needs its own layout)."""
-        return params
+        return table
 
-    def unpack(self, packed: dict, cfg: SGNSConfig) -> dict:
+    def unpack(self, table: jax.Array, cfg: SGNSConfig) -> jax.Array:
         """Inverse of :meth:`pack`."""
-        return packed
+        return table
 
     def make_packed_step(self, cfg: SGNSConfig, total_steps: int):
         """:meth:`make_step` on packed tables — what a scan of steps
-        carries, so that the layout is converted once per scan. Its step
+        carries, so that the layout is converted outside it. Its step
         returns ``(params, mean_loss, counts)``: ``counts`` maps each
         name of ``repro.obs.STEP_COUNTERS`` to an int32 scalar, the
         table rows the step's kernel moves between HBM and VMEM by DMA
@@ -345,22 +345,21 @@ class FusedPipePallasEngine(FusedHBMPallasEngine):
 
     hot_rows = 0    # the tiered engine's field; the pure pipeline has none
 
-    def pack(self, params):
-        """Tables → the kernels' ``(V, 1, dp)`` row layout
+    def pack(self, table):
+        """A table → the kernels' ``(..., V, 1, dp)`` row layout
         (:func:`repro.kernels.sgns_fused_pipe.to_row_layout`)."""
         if self.sequential:
-            return params
+            return table
         from repro.kernels.sgns_fused_pipe import to_row_layout
 
-        interpret = resolve_interpret(self.interpret)
-        return {k: to_row_layout(v, interpret) for k, v in params.items()}
+        return to_row_layout(table, resolve_interpret(self.interpret))
 
-    def unpack(self, packed, cfg):
+    def unpack(self, table, cfg):
         if self.sequential:
-            return packed
+            return table
         from repro.kernels.sgns_fused_pipe import from_row_layout
 
-        return {k: from_row_layout(v, cfg.dim) for k, v in packed.items()}
+        return from_row_layout(table, cfg.dim)
 
     def make_packed_step(self, cfg: SGNSConfig, total_steps: int):
         """One pipelined-kernel step on row-layout tables (multi-slot DMA
@@ -392,8 +391,9 @@ class FusedPipePallasEngine(FusedHBMPallasEngine):
         packed_step = self.make_packed_step(cfg, total_steps)
 
         def step(params, *args):
-            packed, loss, _ = packed_step(self.pack(params), *args)
-            return self.unpack(packed, cfg), loss
+            packed = {k: self.pack(v) for k, v in params.items()}
+            packed, loss, _ = packed_step(packed, *args)
+            return {k: self.unpack(v, cfg) for k, v in packed.items()}, loss
 
         return step
 

@@ -751,8 +751,8 @@ def fused_pipe_rows_step(
 ) -> tuple[dict, jax.Array, dict]:
     """One step of the pipelined (``hot_rows = 0``) or tiered HBM kernel
     on tables already in the kernels' row layout
-    (:func:`to_row_layout`) — the form a scan of steps carries, so that
-    the layout is converted once per epoch rather than once per step.
+    (:func:`to_row_layout`) — the form a scan of steps carries, and the
+    trainer keeps across chunks, so that no step converts the layout.
     ``hot_rows`` must lie in ``[0, V]``. Returns the tables, the mean
     loss and the step's counters (``repro.obs.STEP_COUNTERS``): the rows
     the kernel moves (:func:`row_traffic`) and the references to hot

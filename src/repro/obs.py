@@ -41,7 +41,7 @@ TRAIN_DISPATCH = "repro.train.dispatch"   # one chunk's epoch call enqueued
 
 # device scopes, for the planner's and the layout's shares of the step
 PLAN_SCOPE = "sgns.plan"        # negative replay, block planner, metadata
-LAYOUT_SCOPE = "sgns.layout"    # (V, d) <-> (V, 1, dp) once per chunk
+LAYOUT_SCOPE = "sgns.layout"    # (V, d) <-> (V, 1, dp), when the trainer converts
 
 # the SGNS Mosaic kernel, read by ``sgns_kernel_roofline``,
 # ``step_nonkernel_share`` and ``kernel_dma_bw_share``
@@ -56,6 +56,11 @@ HOT_TOUCHES = "sgns.hot_touches"  # row references (centre, context,
                                   # negatives) to the hot tier's rows
 # the counters a training step computes on the device, beside its loss
 STEP_COUNTERS = (ROWS_MOVED, HOT_TOUCHES)
+
+# the trainer's conversions of its tables between the (n, V, d) layout
+# and the engine's packed one, each of a whole set of tables; no
+# metric reads it yet
+RELAYOUTS = "train.relayouts"
 
 # counters of the graph walk source, read by ``walk_host_share``
 WALK_NS = "graph.walk_ns"         # host ns inside ``repro.ingest.walk``

@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core.async_trainer import AsyncShardTrainer
+from repro.core.async_trainer import (AsyncShardTrainer, converting,
+                                     make_worker_epoch)
 from repro.core.engine import get_engine
 from repro.core.sgns import SGNSConfig
 from repro.data.pairs import build_noise_table
@@ -194,34 +195,96 @@ def test_epoch_returns_params_and_losses_of_the_steps(engine):
 
     ref = jax.jit(jax.vmap(steps))(p0, c, x, table, jax.random.split(key, N),
                                    jnp.full((N,), 5, jnp.int32))
-    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(ref)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for t in ("W", "C"):
+        np.testing.assert_array_equal(np.asarray(out[0][t]),
+                                      np.asarray(ref[0][t]))
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(ref[1]))
     rows = obs.read(obs.ROWS_MOVED)
     assert (rows == 0) == engine.startswith("sparse")
     # the hot-tier counter rides the same epoch, which stays bit-identical
     assert (obs.read(obs.HOT_TOUCHES) > 0) == (engine == "pallas_fused_tiered")
 
 
+# ------------------------------------------------ the tables across chunks
+@pytest.mark.parametrize("engine", ["sparse:alias", "pallas_fused_tiered"])
+def test_tables_stay_packed_across_chunks_and_convert_on_read(engine):
+    """Three chunks through ``epoch``, W and C read after the first:
+    tables, losses and step counters bit-identical to the program that
+    converts around every chunk; one pack before chunk 1, one unpack at
+    the read, one pack before chunk 2, none before chunk 3 (none at all
+    where the engine's layout is the plain one); ``block_until_ready``
+    waits for the tables themselves and converts nothing."""
+    tiered = engine == "pallas_fused_tiered"
+    eng = get_engine(engine, **({"block_pairs": BLK, "hot_rows": 24}
+                                if tiered else {}))
+    tr = _trainer(eng)
+    p0 = tr.init(jax.random.PRNGKey(0))
+    every_chunk = jax.jit(jax.vmap(converting(
+        make_worker_epoch(tr.cfg, tr.total_steps, eng), eng, tr.cfg)))
+    params, ref = jax.tree.map(jnp.copy, p0), p0
+    ref_counts = {n: 0 for n in obs.STEP_COUNTERS}
+    relayouts = []
+    for k in range(3):
+        c, x, table = _chunk(k)
+        key = jax.random.PRNGKey(10 + k)
+        params, losses = tr.epoch(params, c, x, table, key, step0=k * S)
+        relayouts.append(obs.read(obs.RELAYOUTS))
+        ref, ref_losses, counts = every_chunk(
+            ref, c, x, table, jax.random.split(key, N),
+            jnp.full((N,), k * S, jnp.int32))
+        ref_counts = {n: ref_counts[n] + int(counts[n].sum())
+                      for n in ref_counts}
+        np.testing.assert_array_equal(np.asarray(losses),
+                                      np.asarray(ref_losses))
+        if k == 0:
+            for t in ("W", "C"):
+                np.testing.assert_array_equal(np.asarray(params[t]),
+                                              np.asarray(ref[t]))
+            # converted in place: the leaves are the (n, V, d) tables now
+            assert [a.shape for a in jax.tree.leaves(params)] == [(N, V, D)] * 2
+            relayouts.append(obs.read(obs.RELAYOUTS))
+    assert relayouts == ([1, 2, 3, 3] if tiered else [0] * 4)
+
+    leaves = jax.tree.leaves(params)
+    assert jax.block_until_ready(params) is params
+    assert all(a.is_ready() for a in leaves)
+    assert [a.shape for a in leaves] == [(N, V, 1, D) if tiered
+                                         else (N, V, D)] * 2
+    assert obs.read(obs.RELAYOUTS) == relayouts[-1]
+    for t in ("W", "C"):
+        np.testing.assert_array_equal(np.asarray(params[t]),
+                                      np.asarray(ref[t]))
+    for n in obs.STEP_COUNTERS:
+        assert obs.read(n) == ref_counts[n]
+    assert (ref_counts[obs.ROWS_MOVED] > 0) == tiered
+
+
 # ------------------------------------------------------------ device scopes
 def test_epoch_hlo_names_the_planner_and_the_layout_conversion():
     """The lowered epoch's op names (its ``loc`` names, which become the
-    HLO ops' ``op_name`` metadata) carry both scopes, and the kernel is
-    under neither."""
+    HLO ops' ``op_name`` metadata) carry the planner's scope, and the
+    kernel is not under it; the epoch converts no layout, and both
+    stand-alone conversions carry the layout scope."""
     tr = _trainer(get_engine("pallas_fused_tiered", block_pairs=BLK,
                              hot_rows=24))
     c, x, table = _chunk()
     params = tr.init(jax.random.PRNGKey(0))
+    packed = {k: tr.layout.pack(v) for k, v in params.items()}
     keys = jax.random.split(jax.random.PRNGKey(1), N)
-    text = tr._jit_epoch().lower(params, c, x, table, keys,
+    text = tr._jit_epoch().lower(packed, c, x, table, keys,
                                  jnp.zeros((N,), jnp.int32)).as_text(
         debug_info=True)
-    op_names = set(re.findall(r'loc\("([^"]+)"', text))
+    names = lambda text: set(re.findall(r'loc\("([^"]+)"', text))
+    op_names = names(text)
     assert any(obs.PLAN_SCOPE in n and "sort" in n for n in op_names)
-    assert any(obs.LAYOUT_SCOPE in n for n in op_names)
+    assert not any(obs.LAYOUT_SCOPE in n for n in op_names)
     assert any(obs.SGNS_KERNEL in n for n in op_names)
-    assert not any(obs.SGNS_KERNEL in n and (obs.PLAN_SCOPE in n or
-                                             obs.LAYOUT_SCOPE in n)
+    assert not any(obs.SGNS_KERNEL in n and obs.PLAN_SCOPE in n
                    for n in op_names)
+    for convert, table in ((tr.layout.pack, params["W"]),
+                           (tr.layout.unpack, packed["W"])):
+        text = convert.lower(table).as_text(debug_info=True)
+        assert any(obs.LAYOUT_SCOPE in n for n in names(text))
 
 
 # ------------------------------------------------------------ host spans

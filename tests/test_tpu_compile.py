@@ -6,9 +6,12 @@ what Mosaic or XLA would refuse (an unaligned slice, a vector gather, a
 program past the chip's 15.75 GB) fails here at no chip time. The HBM
 kernels compile at the paper's 300k×500 shape and at ``ZIPF50K``, under
 the kernel's own name; the 4-worker vmapped epoch of the tiered engine
-must fit one chip, with its planner and layout scopes in the compiled
-program, and a planner that compiles to no loop and no gather but the
-noise draw's; so must the DeepWalk cell's 5 workers at 1,138,499 × 128.
+must fit one chip, with its planner's scope in the compiled program, a
+planner that compiles to no loop and no gather but the noise draw's,
+and, as it takes and returns the tables in the kernel's row layout, no
+pass over a table outside its loop and almost no scratch memory; so must
+the DeepWalk cell's 5 workers at 1,138,499 × 128. The trainer's
+stand-alone layout conversions carry the layout scope.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may hold the TPU library, and every test
@@ -103,15 +106,23 @@ def _assert_planner_has_no_loop_or_gather(text, V):
             assert shapes[gather[1]].split(",")[-1] == str(V), line
 
 
-def _compile_tiered_epoch(one_chip, n, V, d, S=128, B=512):
+def _tiered_trainer(n, V, d):
     engine = get_engine("pallas_fused_tiered", interpret=False,
                         hot_rows=PAPER["HOT"], block_pairs=PAPER["BLK"])
-    tr = AsyncShardTrainer(
+    return AsyncShardTrainer(
         cfg=SGNSConfig(vocab_size=V, dim=d, window=10, negatives=PAPER["K"]),
         num_workers=n, total_steps=1000, engine=engine)
+
+
+def _compile_tiered_epoch(one_chip, n, V, d, S=128, B=512):
+    """The trainer's epoch compiled as it runs: the tables in the
+    engine's packed layout, taken and returned."""
+    tr = _tiered_trainer(n, V, d)
     sds = lambda s, t: _sds(one_chip, s, t)
+    table = jax.eval_shape(tr.engine.pack,
+                           jax.ShapeDtypeStruct((n, V, d), jnp.float32))
     return tr._jit_epoch().lower(
-        {"W": sds((n, V, d), jnp.float32), "C": sds((n, V, d), jnp.float32)},
+        {"W": sds(table.shape, table.dtype), "C": sds(table.shape, table.dtype)},
         sds((n, S, B), jnp.int32), sds((n, S, B), jnp.int32),
         {"prob": sds((n, V), jnp.float32), "alias": sds((n, V), jnp.int32)},
         sds((n, 2), jnp.uint32), sds((n,), jnp.int32)).compile()
@@ -125,17 +136,42 @@ def _assert_fits_with_tables_donated(compiled, n, V, d):
     assert total <= V5E_HBM_BYTES, total
 
 
+def _assert_entry_moves_no_table(compiled, n, V):
+    """Outside its loop the epoch makes no pass over a table (no copy,
+    pad, reshape, broadcast or slice of an ``(n, V, …)`` array: only the
+    parameters, the ``while`` and its results), and its scratch memory
+    is no table's: under 64 MiB."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    ops = re.findall(rf"(%[\w.\-]+) = \w+\[{n},{V}(?:,\d+)*\]\S* ([\w\-]+)\(",
+                     entry)
+    assert len(ops) >= 4, ops         # two tables in, two out of the loop
+    for name, op in ops:
+        assert op in ("parameter", "get-tuple-element"), (name, op)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 64 * 2 ** 20, temp
+
+
 def test_four_worker_tiered_epoch_fits_one_v5e(one_chip):
     """The paper's shape, 4 workers vmapped on one chip: the compiled
-    epoch (tables donated, the kernel's row layout carried through the
-    scan) must fit the chip's memory."""
+    epoch (tables donated, in the kernel's row layout) must fit the
+    chip's memory and convert no table; the trainer's stand-alone
+    conversions carry the layout scope."""
     n, V, d = 4, PAPER["V"], PAPER["D"]
     compiled = _compile_tiered_epoch(one_chip, n, V, d)
     text = compiled.as_text()
-    for name in (SGNS_KERNEL, PLAN_SCOPE, LAYOUT_SCOPE):
+    for name in (SGNS_KERNEL, PLAN_SCOPE):
         assert name in text, name
+    assert LAYOUT_SCOPE not in text
     _assert_planner_has_no_loop_or_gather(text, V)
     _assert_fits_with_tables_donated(compiled, n, V, d)
+    _assert_entry_moves_no_table(compiled, n, V)
+    layout = _tiered_trainer(n, V, d).layout
+    plain = _sds(one_chip, (n, V, d), jnp.float32)
+    packed = _sds(one_chip, (n, V, 1, 512), jnp.float32)
+    for convert, table in ((layout.pack, plain), (layout.unpack, packed)):
+        assert LAYOUT_SCOPE in convert.lower(table).compile().as_text()
 
 
 def test_five_worker_deepwalk_epoch_fits_one_v5e(one_chip):
@@ -146,3 +182,4 @@ def test_five_worker_deepwalk_epoch_fits_one_v5e(one_chip):
     compiled = _compile_tiered_epoch(one_chip, n, V, d)
     assert SGNS_KERNEL in compiled.as_text()
     _assert_fits_with_tables_donated(compiled, n, V, d)
+    _assert_entry_moves_no_table(compiled, n, V)
