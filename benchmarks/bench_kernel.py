@@ -2,11 +2,9 @@
 Pallas-kernel equivalence check (interpret mode; Mosaic on TPU), and an
 update-engine smoke sweep — one timed step per registered engine, so the
 benchmark artifact shows every step path (dense / sparse / pallas /
-pallas_fused / pallas_fused_hbm / pallas_fused_pipe /
-pallas_fused_tiered) side by side, including the blocked HBM-streaming
-engines' bit-equivalence against the per-block sparse reference (the
-pipelined and tiered engines must match it — and therefore the
-unpipelined chain — bit for bit).
+pallas_fused_tiered) side by side, including the fused engine's
+bit-equivalence against the per-block sparse reference, with and
+without its hot tier.
 
 A **hot-fraction sweep** times ``pallas_fused_tiered`` over a ladder of
 ``hot_rows`` settings on a Zipfian pair stream — the VMEM-budget vs
@@ -67,8 +65,8 @@ def hot_sweep(cfg, params, counts, hots, B=1024, iters=3, seed=7):
     """Time ``pallas_fused_tiered`` at a ladder of ``hot_rows`` settings
     on a Zipfian pair stream (uniform ids would starve the hot tier).
     Returns ``[{"hot_rows": k, "us": µs_per_step}, ...]`` — the
-    VMEM-budget/speed trade-off curve; ``hot_rows=0`` is the pure
-    pipeline baseline of the same kernel family."""
+    VMEM-budget/speed trade-off curve; ``hot_rows=0`` is the kernel's
+    pure-DMA baseline."""
     rng = np.random.default_rng(seed)
     V = cfg.vocab_size
     c = zipf_ids(rng, V, B)
@@ -108,51 +106,29 @@ def run(B=1024, K=5, D=512, V=50_000, quick=False, engines=ENGINE_NAMES):
     lr_, dwr, _, _ = ref.sgns_row_grads_ref(w, cp, cn)
     err = float(jnp.max(jnp.abs(dwk - dwr)))
 
-    # fused engine vs sparse reference, identical negatives (replayed
-    # from the kernel's counter PRNG) — end-to-end step equivalence
+    # the fused engine vs the per-block sparse reference on the same
+    # replayed negatives, at hot_rows 0 (pure DMA) and its default: the
+    # step must be *bit-identical* (the hot prefix is genuinely touched:
+    # the noise draw is Zipfian over frequency-ranked ids)
     counts = rng.zipf(1.3, V).astype(np.float64)
-    eng_f = get_engine("pallas_fused")
+    eng_t = get_engine("pallas_fused_tiered")
     table = build_noise_table(counts, kind="alias")
     key = jax.random.PRNGKey(9)
-    pf, _ = eng_f.make_step(cfg, 1000)(
-        jax.tree.map(jnp.copy, params), c, x, table, key, jnp.int32(0))
-    ids = eng_f.sample(table, key, (B, K))
-    ps, _ = sgns.train_step_sparse(jax.tree.map(jnp.copy, params), c, x, ids,
-                                   jnp.float32(cfg.lr))
-    fused_err = float(jnp.max(jnp.abs(pf["W"] - ps["W"])))
-
-    # HBM-blocked fused engine vs the per-block sparse reference on the
-    # same replayed negatives — the blocked step must be *bit-identical*
-    eng_h = get_engine("pallas_fused_hbm")
-    blk = eng_h.block_pairs
-    ph, _ = eng_h.make_step(cfg, 1000)(
-        jax.tree.map(jnp.copy, params), c, x, table, key, jnp.int32(0))
+    ids = eng_t.sample(table, key, (B, K))
+    blk = eng_t.block_pairs
     sparse_jit = jax.jit(sgns.train_step_sparse)
     pr = jax.tree.map(jnp.copy, params)
     lr0 = sgns.linear_lr(jnp.int32(0), 1000, cfg)
     for b0 in range(0, B, blk):
         pr, _ = sparse_jit(pr, c[b0:b0 + blk], x[b0:b0 + blk],
                            ids[b0:b0 + blk], lr0)
-    hbm_err = float(max(jnp.max(jnp.abs(ph["W"] - pr["W"])),
-                        jnp.max(jnp.abs(ph["C"] - pr["C"]))))
-
-    # pipelined HBM engine vs the same per-block sparse reference — the
-    # DMA pipeline (dedup + overlap + hazard ordering) must not move a
-    # single bit relative to the serial chain
-    eng_p = get_engine("pallas_fused_pipe")
-    pp, _ = eng_p.make_step(cfg, 1000)(
-        jax.tree.map(jnp.copy, params), c, x, table, key, jnp.int32(0))
-    pipe_err = float(max(jnp.max(jnp.abs(pp["W"] - pr["W"])),
-                         jnp.max(jnp.abs(pp["C"] - pr["C"]))))
-
-    # frequency-tiered engine vs the same reference — tier routing must
-    # be bit-invisible too (the hot prefix is genuinely touched: the
-    # noise draw is Zipfian over frequency-ranked ids)
-    eng_t = get_engine("pallas_fused_tiered")
-    pt, _ = eng_t.make_step(cfg, 1000)(
-        jax.tree.map(jnp.copy, params), c, x, table, key, jnp.int32(0))
-    tiered_err = float(max(jnp.max(jnp.abs(pt["W"] - pr["W"])),
-                           jnp.max(jnp.abs(pt["C"] - pr["C"]))))
+    tiered_err = 0.0
+    for hot in (0, eng_t.hot_rows):
+        pt, _ = get_engine(eng_t, hot_rows=hot).make_step(cfg, 1000)(
+            jax.tree.map(jnp.copy, params), c, x, table, key, jnp.int32(0))
+        tiered_err = max(tiered_err, float(max(
+            jnp.max(jnp.abs(pt["W"] - pr["W"])),
+            jnp.max(jnp.abs(pt["C"] - pr["C"])))))
 
     engine_us = engine_sweep(cfg, params, c, x, counts,
                              iters=3 if quick else 10, specs=engines)
@@ -165,9 +141,6 @@ def run(B=1024, K=5, D=512, V=50_000, quick=False, engines=ENGINE_NAMES):
         "us_dense_step": us_dense,
         "pairs_per_s_sparse": B / (us_sparse / 1e6),
         "kernel_max_err": err,
-        "fused_vs_sparse_err": fused_err,
-        "fused_hbm_vs_sparse_err": hbm_err,
-        "fused_pipe_vs_sparse_err": pipe_err,
         "fused_tiered_vs_sparse_err": tiered_err,
         "engine_us": engine_us,
         "tiered_hot_sweep": sweep,
@@ -177,7 +150,7 @@ def run(B=1024, K=5, D=512, V=50_000, quick=False, engines=ENGINE_NAMES):
 
 def main(quick=False, engine=None):
     """``engine`` (name or spec string) restricts the sweep to one
-    engine — ``python -m benchmarks.bench_kernel --engine pallas_fused``."""
+    engine — ``python -m benchmarks.bench_kernel --engine sparse``."""
     if engine is not None:
         get_engine(engine)                  # validate the spec up front
     specs = ENGINE_NAMES if engine is None else (engine,)
@@ -190,17 +163,9 @@ def main(quick=False, engine=None):
           f"(materializes (V,d) grad — the path the sparse step replaces)")
     print(f"pallas kernel vs oracle max|Δ| = {r['kernel_max_err']:.2e} "
           f"(interpret mode)")
-    print(f"pallas_fused step vs sparse ref max|Δ| = "
-          f"{r['fused_vs_sparse_err']:.2e} (same in-kernel negatives)")
-    print(f"pallas_fused_hbm step vs per-block sparse ref max|Δ| = "
-          f"{r['fused_hbm_vs_sparse_err']:.2e} "
-          f"(HBM tables, DMA-gathered rows; bit-identical by contract)")
-    print(f"pallas_fused_pipe step vs per-block sparse ref max|Δ| = "
-          f"{r['fused_pipe_vs_sparse_err']:.2e} "
-          f"(pipelined DMA, deduped rows; bit-identical by contract)")
     print(f"pallas_fused_tiered step vs per-block sparse ref max|Δ| = "
-          f"{r['fused_tiered_vs_sparse_err']:.2e} "
-          f"(VMEM hot prefix + cold DMA ring; bit-identical by contract)")
+          f"{r['fused_tiered_vs_sparse_err']:.2e} (hot_rows 0 and default: "
+          f"VMEM hot prefix + cold DMA ring; bit-identical by contract)")
     for name, us in r["engine_us"].items():
         print(f"engine {name:12s}: {us:9.1f} µs/step "
               f"({r['B'] / (us / 1e6):.2e} pairs/s)")
@@ -238,8 +203,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--engine", default=None,
                     help="time only this engine's step (dense | sparse | "
-                         "pallas | pallas_fused | pallas_fused_hbm | "
-                         "pallas_fused_pipe | pallas_fused_tiered)")
+                         "pallas | pallas_fused_tiered)")
     ap.add_argument("--hot-sweep", action="store_true",
                     help="run only the fine-grained pallas_fused_tiered "
                          "hot-fraction ladder (VMEM budget vs µs/step)")

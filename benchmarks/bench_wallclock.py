@@ -9,20 +9,18 @@ On one CPU device we measure real compute and report:
   * merge times (PCA / ALiR), the paper's "few minutes" claim;
   * near-linear scaling of training time with corpus fraction (Fig 2);
   * one wall-clock row PER UPDATE ENGINE (dense/sparse/pallas/
-    pallas_fused/pallas_fused_hbm/pallas_fused_pipe/pallas_fused_tiered)
-    through the full streamed driver, a pair of ``<engine>@zipf50k``
-    direct-step rows (V=50k×512, Zipfian ids) carrying the
-    planner-derived HBM row-traffic columns the tiered engine
-    optimizes (see ``zipf_kernel_rows``), plus one ``serve`` row for
-    the read path (``benchmarks.bench_serve``), one ``elastic_resume``
-    row and one ``merge_tree`` row (the reduction-tree merge's
-    critical-path wallclock, ``benchmarks.bench_merge.merge_tree_row``)
-    — written to ``BENCH_wallclock.json``
-    (CI uploads
-    it as an artifact next to the CSV summary; override the path with
-    ``REPRO_BENCH_WALLCLOCK_JSON``). The committed repo-root
-    ``BENCH_wallclock.json`` is the regression BASELINE the CI
-    bench-gate compares fresh rows against
+    pallas_fused_tiered) through the full streamed driver, a pair of
+    ``@zipf50k`` direct-step rows (V=50k×512, Zipfian ids; the fused
+    engine at ``hot_rows`` 0 and 2048) carrying the planner-derived HBM
+    row-traffic columns the hot tier optimizes (see
+    ``zipf_kernel_rows``), plus one ``serve`` row for the read path
+    (``benchmarks.bench_serve``), one ``elastic_resume`` row and one
+    ``merge_tree`` row (the reduction-tree merge's critical-path
+    wallclock, ``benchmarks.bench_merge.merge_tree_row``) — written to
+    ``BENCH_wallclock.json`` (CI uploads it as an artifact next to the
+    CSV summary; override the path with ``REPRO_BENCH_WALLCLOCK_JSON``).
+    The committed repo-root ``BENCH_wallclock.json`` is the regression
+    BASELINE the CI bench-gate compares fresh rows against
     (``python -m benchmarks.check_regression``).
 """
 
@@ -136,23 +134,24 @@ def _elastic_row(quick=False, steps=None):
 
 
 def zipf_kernel_rows(quick=False):
-    """Direct-step rows for the two pipelined HBM engines on a Zipfian
+    """Direct-step rows for the fused engine without and with its hot
+    tier (``hot_rows`` 0 and ``ZIPF50K["HOT"]``) on a Zipfian
     paper-shape workload (V=50k, d=512, power-law ids): wall-clock plus
     the planner-derived **HBM row traffic** each step actually moves
     (``hbm_rows_per_step`` / ``hbm_mb_per_step``).
 
     The traffic column is the point. Interpret mode executes DMAs as
     plain memcpys with no latency/bandwidth model, so the quantity the
-    tiered engine optimizes — HBM round-trips — costs almost nothing
-    there and the two engines' interpret wall-clocks land within
+    hot tier optimizes — HBM round-trips — costs almost nothing
+    there and the two rows' interpret wall-clocks land within
     machine noise of each other. The traffic numbers are exact and
     deterministic (summed from the block plans): the hot tier drops
     every hot-row gather/write-back from every block — per-block dedup
     already collapses within-block repeats, so the tier's win is the
     cross-block recurrence, ~1.5x less HBM row traffic (a 35% cut) at
     this skew and batch — which is the term real DMA latency converts
-    into step time on hardware. Rows land in the same gated JSON as
-    ``<engine>@zipf50k``.
+    into step time on hardware. Rows land in the same gated JSON under
+    the labels of ``ZIPF50K_ROWS``.
 
     The workload itself (seeds, id streams, planner traffic) lives in
     ``repro.analysis.workloads`` — the single definition this bench
@@ -161,13 +160,13 @@ def zipf_kernel_rows(quick=False):
     import jax
     import jax.numpy as jnp
 
-    from repro.analysis.workloads import ZIPF50K, zipf50k_ids
+    from repro.analysis.workloads import ZIPF50K, ZIPF50K_ROWS, zipf50k_ids
     from repro.core import sgns
     from repro.core.engine import get_engine
     from repro.kernels.sgns_fused_pipe import plan_blocks, plan_row_traffic
 
     V, D, B, K = ZIPF50K["V"], ZIPF50K["D"], ZIPF50K["B"], ZIPF50K["K"]
-    BLK, HOT = ZIPF50K["BLK"], ZIPF50K["HOT"]
+    BLK = ZIPF50K["BLK"]
     steps = 2 if quick else 4
     cfg = sgns.SGNSConfig(vocab_size=V, dim=D, negatives=K)
     params = sgns.init_params(jax.random.PRNGKey(0), cfg)
@@ -178,9 +177,9 @@ def zipf_kernel_rows(quick=False):
         return plan_row_traffic(plan, hot_rows=hot)
 
     rows = []
-    for name, kw in (("pallas_fused_pipe", {}),
-                     ("pallas_fused_tiered", {"hot_rows": HOT})):
-        eng = get_engine(name, block_pairs=BLK, **kw)
+    for label, hot in ZIPF50K_ROWS.items():
+        eng = get_engine("pallas_fused_tiered", block_pairs=BLK,
+                         hot_rows=hot)
         step = jax.jit(eng.make_step(cfg, total_steps=1000))
         pp = jax.tree.map(jnp.copy, params)
         pp, loss = step(pp, c, x, table, key, jnp.int32(0))  # compile+warm
@@ -189,9 +188,9 @@ def zipf_kernel_rows(quick=False):
             for i in range(steps):
                 pp, loss = step(pp, c, x, table, key, jnp.int32(1 + i))
             jax.block_until_ready(loss)
-        n_rows = hbm_rows(kw.get("hot_rows", 0))
+        n_rows = hbm_rows(hot)
         rows.append({
-            "engine": f"{name}@zipf50k",
+            "engine": label,
             "workers": 1,
             "steps_per_epoch": steps,
             "batch": B,

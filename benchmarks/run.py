@@ -6,9 +6,9 @@
   bench_wallclock — Table 4 + Fig 2 (training/merge wall-clock, scaling)
   bench_oov       — Fig 3   (missing-vocabulary reconstruction)
   bench_kernel    — SGNS step micro-bench + Pallas/oracle check +
-                    update-engine sweep (dense/sparse/pallas/pallas_fused/
-                    pallas_fused_hbm/_pipe/_tiered, incl. the HBM-blocked
-                    bit-equivalences and the tiered hot-fraction ladder)
+                    update-engine sweep (dense/sparse/pallas/
+                    pallas_fused_tiered, incl. the fused engine's
+                    bit-equivalence and its hot-fraction ladder)
   bench_serve     — serving tier (p50/p99 lookup latency, coalesced
                     batch size, cache hit rate under concurrent clients)
   roofline_table  — §Roofline terms from the dry-run sweeps
@@ -81,12 +81,9 @@ def main(argv=None) -> None:
         lambda rows: "alias_speedup@V=%d=%.1fx" % (
             rows[-1]["V"], rows[-1]["speedup"]))
     run("kernel_sgns", bench_kernel.main,
-        lambda r: "pairs_per_s=%.2e;fused_err=%.1e;fused_hbm_err=%.1e;"
-                  "fused_pipe_err=%.1e;fused_tiered_err=%.1e;engines=%s;"
+        lambda r: "pairs_per_s=%.2e;fused_tiered_err=%.1e;engines=%s;"
                   "hot_sweep=%s" % (
-            r["pairs_per_s_sparse"], r["fused_vs_sparse_err"],
-            r["fused_hbm_vs_sparse_err"], r["fused_pipe_vs_sparse_err"],
-            r["fused_tiered_vs_sparse_err"],
+            r["pairs_per_s_sparse"], r["fused_tiered_vs_sparse_err"],
             "|".join("%s:%.0fus" % (n, us)
                      for n, us in r["engine_us"].items()),
             "|".join("%d:%.0fus" % (h["hot_rows"], h["us"])

@@ -4,8 +4,8 @@
     python chip_smoke.py                # one chip
     python chip_smoke.py --four-chips   # one host with four chips
 
-One chip: the HBM kernels of ``pallas_fused_pipe`` and
-``pallas_fused_tiered`` take one step at 300k×500 and are checked
+One chip: the HBM kernel of ``pallas_fused_tiered``, at ``hot_rows`` 0
+(pure DMA) and 256, takes one step at 300k×500 and is checked
 against the per-block ``train_step_sparse`` reference fed the replayed
 negatives; then ``run_pipeline`` trains 4 workers at the shape of
 ``configs/sgns_wiki.py`` (d = 500, window 10, K = 5) over a generated
@@ -81,17 +81,17 @@ def sgns_config():
 # ---------------------------------------------------------------------------
 def check_kernels_against_reference(V: int, d: int, B: int, blk: int,
                                     K: int, hot_rows: int = 256) -> None:
-    """One step of each HBM kernel against the per-block sparse
-    reference on the replayed counter-PRNG negatives."""
+    """One step of the HBM kernel, without and with its hot tier,
+    against the per-block sparse reference on the replayed counter-PRNG
+    negatives."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.core.sgns import train_step_sparse
     from repro.data.pairs import build_noise_table
-    from repro.kernels.sgns_fused import _as_seed, fused_negative_ids
-    from repro.kernels.sgns_fused_pipe import sgns_fused_pipe_step
-    from repro.kernels.sgns_fused_tiered import sgns_fused_tiered_step
+    from repro.kernels.alias_draw import _as_seed, fused_negative_ids
+    from repro.kernels.sgns_fused_pipe import fused_pipe_step
 
     rng = np.random.default_rng(0)
     zipf = 1.0 / np.arange(1, V + 1) ** 1.05
@@ -117,16 +117,12 @@ def check_kernels_against_reference(V: int, d: int, B: int, blk: int,
                       ids[b0:b0 + blk], lr)
     ref = jax.tree.map(np.asarray, ref)
 
-    runs = {
-        "pallas_fused_pipe": lambda: sgns_fused_pipe_step(
-            params, c, x, table, key, lr, negatives=K, block_pairs=blk),
-        "pallas_fused_tiered": lambda: sgns_fused_tiered_step(
-            params, c, x, table, key, lr, negatives=K, block_pairs=blk,
-            hot_rows=hot_rows),
-    }
-    for name, run in runs.items():
+    for hot in (0, hot_rows):
+        name = f"pallas_fused_tiered hot_rows={hot}"
         t0 = time.perf_counter()
-        out, loss = run()
+        out, loss = fused_pipe_step(
+            params, c, x, table, key, lr, negatives=K, block_pairs=blk,
+            ring_depth=2, hot_rows=hot)
         out = jax.tree.map(np.asarray, out)
         secs = time.perf_counter() - t0
         for t in ("W", "C"):

@@ -14,17 +14,14 @@ sentences at a time into fixed-shape chunks and prefetched to the device
 while it trains — no epoch of pairs is ever materialized in host memory.
 The per-step compute is an update engine (``--engine``): the default
 ``sparse:alias`` draws negatives from the O(1) alias sampler;
-``pallas_fused`` moves the draw inside the step kernel;
-``pallas_fused_hbm`` additionally keeps the (V, d) tables HBM-resident
-and DMA-streams only each pair block's touched rows — the engine family
-sized for exactly this example's 100k×500 (and the paper's 300k×500)
-tables; ``pallas_fused_pipe`` is its double-buffered successor (each
-touched row deduped to one DMA per block, gathers overlapped with
-compute behind a hazard-ordering planner); ``pallas_fused_tiered`` adds
-frequency-tiered placement on top — the ``--hot-rows`` hottest rows
-(the frequency-sorted id prefix) pinned VMEM-resident so the Zipfian
-bulk of row traffic never touches DMA, cold rows behind a
-``--ring-depth``-slot ring; ``sparse:cdf`` is the binary-search oracle.
+``pallas_fused_tiered`` runs the whole step in one kernel with the
+(V, d) tables HBM-resident — the engine sized for exactly this
+example's 100k×500 (and the paper's 300k×500) tables: each touched row
+deduped to one DMA per pair block, gathers overlapped with compute
+behind a hazard-ordering planner on a ``--ring-depth``-slot ring, and
+the ``--hot-rows`` hottest rows (the frequency-sorted id prefix) pinned
+VMEM-resident so the Zipfian bulk of row traffic never touches DMA (0
+is the pure-DMA case); ``sparse:cdf`` is the binary-search oracle.
 """
 
 import argparse
@@ -50,14 +47,13 @@ def main():
     ap.add_argument("--epochs", type=int, default=3)
     ap.add_argument("--engine", default="sparse:alias",
                     help="update engine (dense | sparse | pallas | "
-                         "pallas_fused | pallas_fused_hbm | "
-                         "pallas_fused_pipe | pallas_fused_tiered, "
-                         "optional ':cdf'/':alias' suffix)")
+                         "pallas_fused_tiered, optional ':cdf'/':alias' "
+                         "suffix)")
     ap.add_argument("--hot-rows", type=int, default=None,
                     help="pallas_fused_tiered: VMEM-pinned hot-prefix "
                          "rows per table (default 256)")
     ap.add_argument("--ring-depth", type=int, default=None,
-                    help="pallas_fused_pipe/_tiered: cold-row DMA ring "
+                    help="pallas_fused_tiered: cold-row DMA ring "
                          "slots (default 2)")
     ap.add_argument("--steps-per-chunk", type=int, default=128,
                     help="steps per fixed-shape streamed chunk")

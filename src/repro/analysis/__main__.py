@@ -8,10 +8,9 @@
 2. **contracts** — zero-collective + table-donation-aliasing
    certification for every registered engine, and the ``@zipf50k``
    planner-traffic ↔ bench-baseline cross-check.
-3. **vmem** — each engine's reference operating shape fits the default
-   16 MiB budget (``pallas_fused`` at its VMEM-resident dev shape; the
-   HBM family at the paper shape), and the known-over-budget config is
-   rejected.
+3. **vmem** — each engine fits the default 16 MiB budget at the paper
+   shape, and the known-over-budget config (the fused engine with the
+   whole table as its hot prefix) is rejected.
 4. **lint** — the repo-specific AST rules over ``src/repro``.
 
 ``--quick`` shrinks the dma-model bounds for fast local iteration;
@@ -41,44 +40,37 @@ def _run_contracts(baseline: str) -> bool:
     return contracts.main(["--baseline", baseline]) == 0
 
 
-# (engine spec, shape kwargs) — the reference operating point each
-# engine must fit in DEFAULT_VMEM_BUDGET_BYTES. The paper shape is
-# V=300k × d=500; pallas_fused is certified at its dev shape because
-# VMEM-resident tables at the paper shape are exactly the cliff the
-# HBM family exists to dodge (asserted over-budget below).
+# The reference operating point every engine must fit in
+# DEFAULT_VMEM_BUDGET_BYTES: the paper shape, V=300k × d=500.
 _PAPER = dict(vocab_size=300_000, dim=500, negatives=5, batch=1024)
-_VMEM_REFERENCE = [
-    ("dense", _PAPER),
-    ("sparse", _PAPER),
-    ("pallas", _PAPER),
-    ("pallas_fused", dict(vocab_size=4_000, dim=128, negatives=5,
-                          batch=512)),
-    ("pallas_fused_hbm", _PAPER),
-    ("pallas_fused_pipe", _PAPER),
-    ("pallas_fused_tiered:alias", _PAPER),
-]
+_VMEM_REFERENCE = ["dense", "sparse", "pallas", "pallas_fused_tiered:alias"]
 
 
 def _run_vmem() -> bool:
     from repro.analysis.vmem import VmemBudgetError, check_vmem_budget
+    from repro.core.engine import get_engine
 
     ok = True
-    for spec, shape in _VMEM_REFERENCE:
+    for spec in _VMEM_REFERENCE:
         try:
-            est = check_vmem_budget(spec, **shape)
+            est = check_vmem_budget(spec, **_PAPER)
             print(f"vmem: {est.summary()} ✓")
         except VmemBudgetError as e:
             ok = False
             print(f"vmem: FAILED {e}")
     # The cliff itself must still be caught: VMEM-resident tables at
-    # the paper shape have to be rejected, not waved through.
+    # the paper shape (a hot prefix of all V rows) have to be rejected,
+    # not waved through.
+    resident = get_engine("pallas_fused_tiered",
+                          hot_rows=_PAPER["vocab_size"])
     try:
-        check_vmem_budget("pallas_fused", **_PAPER)
+        check_vmem_budget(resident, **_PAPER)
         ok = False
-        print("vmem: FAILED pallas_fused at the paper shape was NOT "
+        print("vmem: FAILED pallas_fused_tiered at hot_rows=V was NOT "
               "rejected — the estimator lost the VMEM cliff")
     except VmemBudgetError:
-        print("vmem: pallas_fused at paper shape correctly rejected ✓")
+        print("vmem: pallas_fused_tiered at hot_rows=V correctly "
+              "rejected ✓")
     return ok
 
 

@@ -241,7 +241,7 @@ def certify_bench_traffic(
     shared workload definition and certify it matches the committed
     baseline rows the CI bench gate compares against — the planner and
     the gated numbers cannot drift apart silently."""
-    from repro.analysis.workloads import ZIPF50K, zipf50k_row_traffic
+    from repro.analysis.workloads import ZIPF50K_ROWS, zipf50k_row_traffic
 
     with open(baseline_path) as f:
         rows = {r["engine"]: r for r in json.load(f)
@@ -250,9 +250,7 @@ def certify_bench_traffic(
         raise ContractViolation(
             f"no @zipf50k traffic rows found in {baseline_path}")
     reports = []
-    for name, hot in (("pallas_fused_pipe", 0),
-                      ("pallas_fused_tiered", ZIPF50K["HOT"])):
-        key = f"{name}@zipf50k"
+    for key, hot in ZIPF50K_ROWS.items():
         if key not in rows:
             raise ContractViolation(f"baseline row {key!r} missing from "
                                     f"{baseline_path}")

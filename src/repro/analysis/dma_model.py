@@ -1,8 +1,8 @@
 """Bounded-exhaustive model checker for the fused-pipeline DMA schedule.
 
 ``kernels/sgns_fused_pipe.kernel_schedule`` is the static expansion of
-the per-position template (``position_template``) that both pipelined
-kernels (``pallas_fused_pipe`` and ``pallas_fused_tiered``) loop over:
+the per-position template (``position_template``) that the kernel of
+the ``pallas_fused_tiered`` engine loops over, at every ``hot_rows``:
 an unrolled sequence of ``(op, block, slot, guard)`` events whose
 guards are resolved against the planner's hazard flags. Its safety argument — matched start/wait
 pairs under every hazard outcome, slot-recycling waits serializing

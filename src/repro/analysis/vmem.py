@@ -4,11 +4,11 @@ Every Pallas engine config implies a VMEM-resident working set the
 Mosaic compiler will demand at lowering time: scratch rings, pinned
 table prefixes, whole resident tables, plan operands. On CI (interpret
 mode) an over-budget config runs fine and only fails when compiled for
-a TPU. For the pipe and tiered engines the terms are the kernel's own
-scratch sizes (``sgns_fused_pipe.vmem_terms``) at the chip's lane-padded
-row width; ``tests/test_tpu_compile.py`` compiles them for a described
-v5e, which checks the real allocation. This pass computes
-the footprint *statically* from the engine dials
+a TPU. For the ``pallas_fused_tiered`` engine the terms are the
+kernel's own scratch sizes (``sgns_fused_pipe.vmem_terms``) at the
+chip's lane-padded row width; ``tests/test_tpu_compile.py`` compiles
+them for a described v5e, which checks the real allocation. This pass
+computes the footprint *statically* from the engine dials
 ``(block_pairs, ring_depth, hot_rows)`` and the model shape
 ``(V, d, K, B)``, so bad configs are rejected at plan time:
 
@@ -22,11 +22,11 @@ the footprint *statically* from the engine dials
 The estimate models the terms the kernels actually allocate (scratch
 shapes + VMEM-spec operands), not XLA's transient buffers — it is a
 lower bound designed to catch the catastrophic misconfigurations
-(VMEM-resident tables past the cliff, a deep ring × huge blocks, a hot
-prefix larger than the budget), with headroom left to the real
-compiler.
+(a deep ring × huge blocks, a hot prefix larger than the budget — at
+``hot_rows = V``, VMEM-resident tables past the cliff), with headroom
+left to the real compiler.
 
-Standalone: ``python -m repro.analysis.vmem --engine pallas_fused_pipe
+Standalone: ``python -m repro.analysis.vmem --engine pallas_fused_tiered
 --vocab 300000 --dim 500``.
 """
 
@@ -75,7 +75,6 @@ def estimate_vmem(engine, *, vocab_size: int, dim: int, negatives: int,
     (XLA manages placement) and estimate to zero.
     """
     from repro.core.engine import get_engine
-    from repro.kernels.sgns_fused_hbm import _pick_block_pairs
 
     eng = get_engine(engine)
     V, d, K, B = vocab_size, dim, negatives, batch
@@ -94,21 +93,11 @@ def estimate_vmem(engine, *, vocab_size: int, dim: int, negatives: int,
         bt = eng.block_b or _pick_block_b(Bp, K, d)
         shape["block_b"] = bt
         terms["grad_tiles"] = 2 * bt * (K + 2) * d * F32
-    elif name == "pallas_fused":
-        # whole tables + noise tables resident, plus the gathered rows
-        # and their updates for the full batch
-        terms["resident_tables"] = 2 * V * d * F32
-        terms["noise_tables"] = 2 * V * F32
-        terms["batch_rows"] = 2 * B * (K + 2) * d * F32
-    elif name == "pallas_fused_hbm":
-        blk = _pick_block_pairs(B, eng.block_pairs)
-        shape["block_pairs"] = blk
-        terms["row_scratch"] = (blk * (K + 2) + 1) * d * F32
-        terms["noise_tables"] = 2 * V * F32
-    elif name in ("pallas_fused_pipe", "pallas_fused_tiered"):
+    elif name == "pallas_fused_tiered":
         # the kernel's own scratch sizes, at the chip's lane-padded row
         # width (its per-block plan metadata lives in HBM and SMEM)
-        from repro.kernels.sgns_fused_pipe import row_width, vmem_terms
+        from repro.kernels.sgns_fused_pipe import (
+            _pick_block_pairs, row_width, vmem_terms)
         blk = _pick_block_pairs(B, eng.block_pairs)
         hot = max(0, min(int(eng.hot_rows), V))
         shape.update(block_pairs=blk, ring_depth=eng.ring_depth, hot_rows=hot)
@@ -129,15 +118,9 @@ def check_vmem_budget(engine, *, vocab_size: int, dim: int, negatives: int,
     est = estimate_vmem(engine, vocab_size=vocab_size, dim=dim,
                         negatives=negatives, batch=batch)
     if est.total_bytes > budget_bytes:
-        advice = {
-            "pallas_fused": "use the HBM-resident family "
-                            "(pallas_fused_hbm/_pipe/_tiered) past the "
-                            "VMEM cliff",
-            "pallas_fused_hbm": "reduce block_pairs",
-            "pallas_fused_pipe": "reduce block_pairs or ring_depth",
-            "pallas_fused_tiered": "reduce hot_rows, block_pairs or "
-                                   "ring_depth",
-        }.get(est.engine.split(":")[0], "reduce the blocking dials")
+        advice = ("reduce hot_rows, block_pairs or ring_depth"
+                  if est.engine.startswith("pallas_fused_tiered:")
+                  else "reduce the blocking dials")
         raise VmemBudgetError(
             f"VMEM budget exceeded: {est.summary()} > "
             f"{budget_bytes / 2 ** 20:.1f} MiB budget — {advice}")

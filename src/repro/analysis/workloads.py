@@ -20,19 +20,24 @@ import numpy as np
 ZIPF50K = {"V": 50_000, "D": 512, "B": 8192, "K": 5, "BLK": 128,
            "HOT": 2048}
 
+# The ``@zipf50k`` rows, by label: the fused engine's ``hot_rows`` for
+# each (0 is the pure-DMA case).
+ZIPF50K_ROWS = {"pallas_fused_tiered[hot_rows=0]@zipf50k": 0,
+                "pallas_fused_tiered@zipf50k": ZIPF50K["HOT"]}
+
 
 def zipf50k_ids():
     """The workload's deterministic id streams: ``(centers, contexts,
     negatives, noise_table, key)``. Power-law ids over the
     frequency-sorted vocab (``choice`` keeps the mid-frequency strata
     populated, unlike a raw Zipf draw whose mass all lands on a handful
-    of head ids); negatives are the replayed counter-PRNG draw the
-    fused kernels perform in-kernel."""
+    of head ids); negatives are the replayed counter-PRNG draw of the
+    fused kernel's planner."""
     import jax
     import jax.numpy as jnp
 
     from repro.data.pairs import build_noise_table
-    from repro.kernels.sgns_fused import _as_seed, fused_negative_ids
+    from repro.kernels.alias_draw import _as_seed, fused_negative_ids
 
     V, B, K = ZIPF50K["V"], ZIPF50K["B"], ZIPF50K["K"]
     rng = np.random.default_rng(11)

@@ -2,7 +2,7 @@
 
 * :mod:`repro.core.sampling`       — EQUAL PARTITIONING / RANDOM SAMPLING / SHUFFLE
 * :mod:`repro.core.sgns`           — SGNS objective + dense/sparse steps
-* :mod:`repro.core.engine`         — UpdateEngine registry (dense/sparse/pallas/pallas_fused)
+* :mod:`repro.core.engine`         — UpdateEngine registry (dense/sparse/pallas/pallas_fused_tiered)
 * :mod:`repro.core.schedule`       — epoch/chunk/total-steps derivation
 * :mod:`repro.core.async_trainer`  — zero-collective async training + sync baseline
 * :mod:`repro.core.merge`          — Concat / PCA / ALiR (+ OOV reconstruction)

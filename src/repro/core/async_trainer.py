@@ -200,9 +200,7 @@ class AsyncShardTrainer:
     axis; the compiled step contains no collectives.
     ``engine`` — an :class:`repro.core.engine.UpdateEngine` or spec
     string (``"dense"`` / ``"sparse"`` / ``"pallas"`` /
-    ``"pallas_fused"`` / ``"pallas_fused_hbm"`` /
-    ``"pallas_fused_pipe"`` / ``"pallas_fused_tiered"``, optionally
-    ``":cdf"`` / ``":alias"``) that owns the per-step compute; resolved
+    ``"pallas_fused_tiered"``, optionally ``":cdf"`` / ``":alias"``) that owns the per-step compute; resolved
     once at construction.
     ``plan`` — optional :class:`repro.data.pipeline.HostShardPlan` for
     multi-host ingestion: this host feeds :meth:`device_chunk` only its

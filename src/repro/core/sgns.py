@@ -15,7 +15,7 @@ Two step functions with identical math:
 
 These are the primitives behind the update engines in
 :mod:`repro.core.engine` (``dense`` / ``sparse`` / ``pallas`` /
-``pallas_fused``) — trainers select an engine rather than calling these
+``pallas_fused_tiered``) — trainers select an engine rather than calling these
 directly.
 
 Initialization matches word2vec: W ~ U(−0.5/d, 0.5/d), C = 0.

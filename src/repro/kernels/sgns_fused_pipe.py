@@ -1,12 +1,9 @@
-"""Pipelined HBM-blocked fused SGNS step: overlapped DMA, deduped rows.
+"""The fused SGNS step against HBM-resident tables: a pipelined DMA ring,
+deduped rows and a frequency-tiered hot prefix, in one Pallas kernel.
 
-``sgns_fused_hbm.py`` made the paper's 300k×500 sub-model shape feasible
-by keeping the ``(V, d)`` tables HBM-resident and DMA-streaming each
-pair block's touched rows — but its memory pipeline is fully serial:
-every row gather and every RMW scatter is issued start→wait, one row at
-a time, so the compute units idle through all of the step's DMA latency
-(the remaining hot-path item on ROADMAP). This module replaces that loop
-with a **multi-slot DMA pipeline** in a single ``pallas_call`` per step:
+The paper's 300k×500 sub-model tables do not fit VMEM, so they stay
+HBM-resident and each pair block's touched rows are DMA-streamed through
+a **multi-slot DMA pipeline** in a single ``pallas_call`` per step:
 
 * a ring of ``ring_depth`` VMEM row-buffer pairs (one ``(R_W, d)`` W
   buffer + one ``(R_C, d)`` C buffer per slot) with per-slot DMA
@@ -18,11 +15,9 @@ with a **multi-slot DMA pipeline** in a single ``pallas_call`` per step:
 * **touched-row dedup**: each block gathers every row it touches
   exactly once (the unique centers for W; the unique contexts ∪
   negatives for C), applies all of its updates to the VMEM-resident
-  copy, and writes each row back exactly once. This removes the
-  per-duplicate gathers *and* the entire read-modify-write round-trip
-  of the unpipelined kernel — per-block HBM traffic drops from
-  ``3·blk·(K+2)`` row transfers to ``2·R`` where ``R ≤ blk·(K+2)`` is
-  the unique-row count;
+  copy, and writes each row back exactly once — per-block HBM traffic
+  is ``2·R`` row transfers, where ``R ≤ blk·(K+2)`` is the unique-row
+  count;
 * a **pure-JAX block planner** (:func:`plan_blocks`) that computes the
   dedup, the pair→buffer-slot index maps, and the scatter-before-
   regather **hazard flags** outside the kernel, and a per-position
@@ -49,30 +44,30 @@ else the slot-recycling default"), so every DMA is started and waited
 exactly once under every hazard vector — the ``ring_depth = 2``
 schedule degenerates to the original complementary ``pl.when`` pairs.
 
-**Frequency tiers** (engine ``pallas_fused_tiered``,
-``kernels/sgns_fused_tiered.py``): vocab ids are frequency-sorted, so
-:func:`plan_blocks` can route the ``hot_rows`` hottest rows (ids
-``< hot_rows``) out of the DMA pipeline entirely — hot ids are dropped
-from the gather/scatter lists and from the hazard row sets (dedup and
-hazards are computed over **cold rows only**), and their buffer
-positions point at a masked pad slot. The tiered kernel serves hot rows
-from a pinned VMEM-resident copy of the table prefix instead; this
-module's planner/schedule stay the single source of truth for the cold
-path. ``hot_rows = 0`` (the ``pallas_fused_pipe`` engine) is the pure
-pipeline.
+**Frequency tiers** (engine ``pallas_fused_tiered``): vocab ids are
+frequency-sorted, so :func:`plan_blocks` can route the ``hot_rows``
+hottest rows (ids ``< hot_rows``) out of the DMA pipeline entirely — hot
+ids are dropped from the gather/scatter lists and from the hazard row
+sets (dedup and hazards are computed over **cold rows only**), and their
+buffer positions point at a masked pad slot. The kernel serves hot rows
+from a pinned VMEM-resident copy of the table prefix instead, bulk-DMA'd
+in once per step and written back once. ``hot_rows = 0`` is the pure-DMA
+case, ``hot_rows = V`` the pure-resident one (zero per-block row DMAs).
 
-Bit-equivalence contract (same as the unpipelined engine): identical
-results to running :func:`repro.core.sgns.train_step_sparse` once per
-pair block on the replayed counter-PRNG negatives. Dedup preserves it
-exactly: the reference's scatter-add applies duplicate-row updates
-sequentially in pair order, and the in-VMEM ``.at[pos].add`` applies the
-same addends to the same base values in the same order before the row is
-written back once. The negative draw uses the same replayable counter
-PRNG (:func:`repro.kernels.sgns_fused.fused_negative_ids`); the planner
-replays it outside the kernel because the dedup needs the ids — the one
-deliberate trade against the in-kernel draw: negative *ids* now exist as
-planner metadata (O(B·K) int32, KBs) so that negative *rows* (MBs) move
-exactly once.
+Bit-equivalence contract: identical results to running
+:func:`repro.core.sgns.train_step_sparse` once per pair block on the
+replayed counter-PRNG negatives, at every ``hot_rows`` and
+``ring_depth``. Dedup preserves it exactly: the reference's scatter-add
+applies duplicate-row updates sequentially in pair order, and the
+in-VMEM ``.at[pos].add`` applies the same addends to the same base
+values in the same order before the row is written back once. Tier
+routing preserves it too: each row id belongs to exactly one tier, so
+each row receives the reference's update sequence through exactly one
+path. The negative draw is the replayable counter PRNG of
+:func:`repro.kernels.alias_draw.fused_negative_ids`; the planner replays
+it outside the kernel because the dedup needs the ids — negative *ids*
+exist as planner metadata (O(B·K) int32, KBs) so that negative *rows*
+(MBs) move exactly once.
 
 Hardware notes: every DMA is started on a slot semaphore and waited
 exactly once, with matched start/wait structure under every hazard
@@ -84,10 +79,7 @@ scalars read from SMEM (the per-block metadata is DMA'd into an SMEM
 ring), rows moved one at a time between the DMA ring and the compute
 rows, the schedule walked as a loop over positions
 (:func:`position_template`), and vmap folded into the kernel's worker
-grid (:func:`_worker_kernel`). ``sequential=True`` (word2vec's per-pair
-apply order) is inherently unpipelineable and is served by the
-unpipelined kernel, in interpret mode only — see
-:class:`repro.core.engine.FusedPipePallasEngine`.
+grid (:func:`_worker_kernel`).
 """
 
 from __future__ import annotations
@@ -102,9 +94,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
 from repro.core.sgns import sparse_row_grads_per_pair
-from repro.kernels.sgns_fused import _as_seed, fused_negative_ids
+from repro.kernels.alias_draw import _as_seed, fused_negative_ids
 from repro.kernels.mode import resolve_interpret
-from repro.kernels.sgns_fused_hbm import _pick_block_pairs
 
 NUM_SLOTS = 2   # default ring depth: gathers of b+1 overlap scatters of b
 
@@ -154,6 +145,14 @@ class PipelinePlan(NamedTuple):
     @property
     def block_pairs(self) -> int:
         return self.w_pos.shape[1]
+
+
+def _pick_block_pairs(B: int, block_pairs: int) -> int:
+    """The block size: ``block_pairs`` clamped to the batch. A batch
+    that is not a multiple is padded to a whole number of blocks —
+    never a degradation to tiny blocks (a prime B with a divisor-only
+    rule would make B single-pair blocks)."""
+    return max(1, min(int(block_pairs), B))
 
 
 def _pad_to_blocks(x: jax.Array, nblocks: int, blk: int) -> jax.Array:
@@ -783,43 +782,24 @@ def fused_pipe_rows_step(
 def fused_pipe_step(params, centers, contexts, table, key, lr, *,
                     negatives, block_pairs, ring_depth, hot_rows,
                     interpret=None):
-    """:func:`fused_pipe_rows_step` on ``(V, d)`` tables: converts to the
-    row layout and back around the one step."""
+    """One SGNS step on ``(V, d)`` tables: :func:`fused_pipe_rows_step`
+    with the row layout converted around it, for tests and tools.
+
+    params: ``{"W": (V, d), "C": (V, d)}``; centers/contexts ``(B,)``
+    int32; table: ``{"prob": (V,), "alias": (V,)}`` Vose alias table of
+    the worker's unigram^0.75 noise distribution; key: a ``(2,)`` PRNG
+    key. Returns ``(params', mean_loss)`` — bit-identical in interpret
+    mode to ``train_step_sparse`` run once per ``block_pairs`` block on
+    the ids :func:`~repro.kernels.alias_draw.fused_negative_ids` yields
+    for the same key. ``hot_rows`` is clamped to ``[0, V]``: 0 is the
+    pure-DMA case, ``V`` the pure-resident one. ``interpret=None``
+    compiles with Mosaic on a TPU and interprets elsewhere.
+    """
     interpret = resolve_interpret(interpret)
-    d = params["W"].shape[1]
+    V, d = params["W"].shape
     rows = {k: to_row_layout(v, interpret) for k, v in params.items()}
     rows, loss, _ = fused_pipe_rows_step(
         rows, centers, contexts, table, key, lr, negatives=negatives,
-        block_pairs=block_pairs, ring_depth=ring_depth, hot_rows=hot_rows,
-        interpret=interpret)
+        block_pairs=block_pairs, ring_depth=ring_depth,
+        hot_rows=max(0, min(int(hot_rows), V)), interpret=interpret)
     return {k: from_row_layout(v, d) for k, v in rows.items()}, loss
-
-
-def sgns_fused_pipe_step(
-    params: dict,
-    centers: jax.Array,
-    contexts: jax.Array,
-    table: dict,
-    key: jax.Array,
-    lr: jax.Array,
-    *,
-    negatives: int = 5,
-    block_pairs: int = 256,
-    ring_depth: int = NUM_SLOTS,
-    interpret: bool | None = None,
-) -> tuple[dict, jax.Array]:
-    """One SGNS step through the pipelined HBM engine.
-
-    Same contract as :func:`repro.kernels.sgns_fused_hbm.sgns_fused_hbm_step`
-    with ``sequential=False`` — and bit-identical to it in interpret mode
-    (and therefore to the per-block ``train_step_sparse`` reference on
-    the replayed negatives) at every ``ring_depth``: the planner replays
-    the same counter-PRNG draw, and the schedule's hazard guards
-    preserve the chain's read-after-write semantics exactly. One
-    ``pallas_call`` covers the whole batch. ``interpret=None`` compiles
-    with Mosaic on a TPU and interprets elsewhere.
-    """
-    return fused_pipe_step(params, centers, contexts, table, key, lr,
-                           negatives=negatives, block_pairs=block_pairs,
-                           ring_depth=ring_depth, hot_rows=0,
-                           interpret=interpret)

@@ -14,28 +14,17 @@ Cases:
                    the O(log V) CDF binary search. Compare this row's
                    HLO cost against `async` (ROADMAP item 4) — same
                    zero-collective property, less per-step HLO.
-  async_fused    — `pallas_fused` engine: the alias draw moves *inside*
-                   the step kernel; negative ids and (B,K) logit/grad
-                   intermediates never appear as HBM arrays.
-  async_fused_hbm— `pallas_fused_hbm` engine: the fused step with the
-                   (V, d) tables *HBM-resident* — a grid of pair blocks
-                   DMA-gathers/scatters only the touched rows, which is
-                   what makes the 300k×500 sub-model shape of this very
-                   dry-run feasible per worker. Same zero-collective
-                   assertion as every async engine.
-  async_fused_pipe— `pallas_fused_pipe` engine: the HBM-resident step
-                   with the double-buffered DMA pipeline — deduped row
-                   gathers/write-backs on a 2-slot VMEM ring, block
+  async_pallas   — `pallas` engine: the sparse step with the VMEM-tile
+                   row-grad kernel.
+  async_fused_tiered— `pallas_fused_tiered` engine: the whole step in one
+                   kernel with the (V, d) tables *HBM-resident* — deduped
+                   row gathers/write-backs on a VMEM DMA ring, block
                    b+1's gathers in flight while block b computes,
-                   hazard-ordered by the pure-JAX block planner. Same
-                   zero-collective assertion (the planner is local
-                   sort-and-compare work, no communication).
-  async_fused_tiered— `pallas_fused_tiered` engine: the pipelined step
-                   with frequency-tiered placement — the hot_rows
-                   hottest rows (the frequency-sorted id prefix) pinned
-                   VMEM-resident for the whole step, cold rows behind
-                   the same DMA ring. Per-worker tables are private, so
-                   the hot tier needs no synchronization: the same
+                   hazard-ordered by the pure-JAX block planner, and the
+                   hot_rows hottest rows (the frequency-sorted id
+                   prefix) pinned VMEM-resident for the whole step.
+                   Per-worker tables are private and the planner is
+                   local sort-and-compare work, so the same
                    zero-collective assertion holds.
   sync           — the synchronized strawman (Hogwild/MLLib stand-in):
                    data-parallel minibatch SGNS, dense-gradient psum
@@ -77,9 +66,6 @@ ASYNC_ENGINES = {
     "async": "sparse",            # inverse-CDF draw (the PR-1 baseline)
     "async_alias": "sparse:alias",
     "async_pallas": "pallas",
-    "async_fused": "pallas_fused",
-    "async_fused_hbm": "pallas_fused_hbm",
-    "async_fused_pipe": "pallas_fused_pipe",
     "async_fused_tiered": "pallas_fused_tiered",
 }
 
@@ -146,9 +132,7 @@ def run(case: str, mesh, workers=WORKERS, steps=STEPS, batch=BATCH,
         steps = max(steps // k, 1) * k
     if case in ASYNC_ENGINES:
         # static VMEM footprint at this run's shape: report always,
-        # enforce when a budget is given (async_fused is legitimately
-        # over-budget at the 300k×500 shape — exactly why the
-        # HBM-resident family exists — so the default is report-only)
+        # enforce when a budget is given
         from repro.analysis.vmem import check_vmem_budget, estimate_vmem
 
         if vmem_budget_mb:
@@ -213,9 +197,7 @@ def compare_sampler_paths(rows: list[dict]) -> None:
     is purely the per-chip compute/memory roofline terms."""
     by_case = {r["arch"]: r for r in rows}
     base = by_case.get("sgns-async")
-    for other in ("sgns-async_alias", "sgns-async_fused",
-                  "sgns-async_fused_hbm", "sgns-async_fused_pipe",
-                  "sgns-async_fused_tiered"):
+    for other in ("sgns-async_alias", "sgns-async_fused_tiered"):
         r = by_case.get(other)
         if not (base and r):
             continue
@@ -233,7 +215,6 @@ def main(argv=None):
                     default="async,async_alias,sync,local_sgd_8,"
                             "local_sgd_64,merge_alir_iter",
                     help="comma list; also available: async_pallas, "
-                         "async_fused, async_fused_hbm, async_fused_pipe, "
                          "async_fused_tiered")
     ap.add_argument("--workers", type=int, default=WORKERS)
     ap.add_argument("--steps", type=int, default=STEPS)
@@ -246,9 +227,7 @@ def main(argv=None):
                          "(no case lowering — the CI multi-host smoke)")
     ap.add_argument("--vmem-budget-mb", type=float, default=0.0,
                     help="reject async cases whose static VMEM estimate "
-                         "exceeds this budget (0 = report only; "
-                         "async_fused at the 300k×500 dry-run shape is "
-                         "over any realistic budget by design)")
+                         "exceeds this budget (0 = report only)")
     args = ap.parse_args(argv)
     processes = (args.processes if args.processes is not None
                  else jax.process_count())

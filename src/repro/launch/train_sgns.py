@@ -6,20 +6,14 @@
 
 Runs the full pipeline on the synthetic corpus (see DESIGN.md §4) and
 prints paper-style scores + timings. ``--engine`` selects the per-step
-update engine (``sparse``, ``dense``, ``pallas``, ``pallas_fused``,
-``pallas_fused_hbm``, ``pallas_fused_pipe``, ``pallas_fused_tiered``,
+update engine (``sparse``, ``dense``, ``pallas``, ``pallas_fused_tiered``,
 optionally with a sampler suffix like ``sparse:alias``); Pallas engines
-run in interpret mode on CPU. On a TPU, ``pallas``, ``pallas_fused_pipe``
-and ``pallas_fused_tiered`` compile under Mosaic, while ``pallas_fused``
-and ``pallas_fused_hbm`` raise (no Mosaic lowering).
-``pallas_fused_hbm`` keeps the parameter tables HBM-resident and
-DMA-streams only the touched rows per pair block — the serial oracle
-for paper-scale (300k×500) sub-models; ``pallas_fused_pipe`` is its
-double-buffered successor
-(deduped row DMAs overlapped with compute behind a hazard-ordering
-block planner), and ``pallas_fused_tiered`` adds frequency-tiered
-placement on top (``--hot-rows`` hottest rows pinned VMEM-resident,
-cold rows behind a ``--ring-depth``-slot DMA ring).
+run in interpret mode on CPU and compile under Mosaic on a TPU.
+``pallas_fused_tiered`` keeps the parameter tables HBM-resident for
+paper-scale (300k×500) sub-models: deduped row DMAs overlapped with
+compute behind a hazard-ordering block planner, cold rows behind a
+``--ring-depth``-slot DMA ring and the ``--hot-rows`` hottest rows
+pinned VMEM-resident (0 is the pure-DMA case).
 
 ``--graph edges.npz`` trains node embeddings instead (DeepWalk): each
 worker's RANDOM SAMPLING (``--rate``) of the 80 walk starts per node
@@ -79,15 +73,14 @@ def main(argv=None):
                     help="also train the synchronized baseline")
     ap.add_argument("--engine", default="sparse",
                     help="update engine: dense | sparse | pallas | "
-                         "pallas_fused | pallas_fused_hbm | "
-                         "pallas_fused_pipe | pallas_fused_tiered, "
-                         "optionally ':cdf'/':alias' (e.g. sparse:alias)")
+                         "pallas_fused_tiered, optionally ':cdf'/':alias' "
+                         "(e.g. sparse:alias)")
     ap.add_argument("--hot-rows", type=int, default=None,
                     help="pallas_fused_tiered: rows of the frequency-"
                          "sorted id prefix pinned VMEM-resident per "
-                         "table (default 256; 0 = pure pipeline)")
+                         "table (default 256; 0 = pure DMA)")
     ap.add_argument("--ring-depth", type=int, default=None,
-                    help="pallas_fused_pipe/_tiered: VMEM row-buffer "
+                    help="pallas_fused_tiered: VMEM row-buffer "
                          "ring slots for the cold-row DMA pipeline "
                          "(default 2)")
     ap.add_argument("--processes", type=int, default=None,

@@ -195,7 +195,8 @@ def test_bench_traffic_certificate_and_tamper_detection(tmp_path):
     row is caught."""
     reports = certify_bench_traffic("BENCH_wallclock.json")
     assert {r.engine for r in reports} == {
-        "pallas_fused_pipe@zipf50k", "pallas_fused_tiered@zipf50k"}
+        "pallas_fused_tiered[hot_rows=0]@zipf50k",
+        "pallas_fused_tiered@zipf50k"}
     rows = [r for r in json.load(open("BENCH_wallclock.json"))]
     for r in rows:
         if r.get("engine") == "pallas_fused_tiered@zipf50k":
@@ -213,10 +214,10 @@ def test_vmem_estimates_scale_with_dials():
         assert estimate_vmem(eng, **shape).total_bytes == 0
     from repro.core.engine import get_engine
 
-    pipe2 = estimate_vmem(get_engine("pallas_fused_pipe"), **shape)
-    pipe4 = estimate_vmem(get_engine("pallas_fused_pipe", ring_depth=4),
+    ring2 = estimate_vmem(get_engine("pallas_fused_tiered"), **shape)
+    ring4 = estimate_vmem(get_engine("pallas_fused_tiered", ring_depth=4),
                           **shape)
-    assert pipe4.total_bytes > pipe2.total_bytes
+    assert ring4.total_bytes > ring2.total_bytes
     t0 = estimate_vmem(get_engine("pallas_fused_tiered", hot_rows=0),
                        **shape)
     t1 = estimate_vmem(get_engine("pallas_fused_tiered", hot_rows=4096),
@@ -226,14 +227,17 @@ def test_vmem_estimates_scale_with_dials():
 
 
 def test_vmem_budget_rejects_resident_tables_at_paper_shape():
+    from repro.core.engine import get_engine
+
     paper = dict(vocab_size=300_000, dim=500, negatives=5, batch=1024)
-    with pytest.raises(VmemBudgetError, match="HBM-resident"):
-        check_vmem_budget("pallas_fused", **paper)
-    # the HBM family exists exactly to fit this shape
-    for eng in ("pallas_fused_hbm", "pallas_fused_pipe",
-                "pallas_fused_tiered"):
-        est = check_vmem_budget(eng, **paper)
-        assert est.total_bytes <= 16 * 2 ** 20
+    # a hot prefix of all V rows holds both tables in VMEM
+    with pytest.raises(VmemBudgetError, match="hot_rows"):
+        check_vmem_budget(get_engine("pallas_fused_tiered",
+                                     hot_rows=paper["vocab_size"]), **paper)
+    # HBM-resident tables exist exactly to fit this shape
+    est = check_vmem_budget(get_engine("pallas_fused_tiered", hot_rows=256),
+                            **paper)
+    assert est.total_bytes <= 16 * 2 ** 20
 
 
 def test_vmem_budget_rejects_oversized_dials():

@@ -1,6 +1,6 @@
 """Update-engine abstraction: registry, cross-engine equivalence, the
-fused kernel's in-kernel negative draw (replay + chi-square), and the
-zero-collective property of every async engine."""
+fused engine's counter-PRNG negative draw (chi-square, determinism),
+and the zero-collective property of every async engine."""
 
 import jax
 import jax.numpy as jnp
@@ -9,11 +9,10 @@ import pytest
 
 from repro.core import sgns
 from repro.core.engine import (
-    ENGINE_NAMES, FusedPallasEngine, UpdateEngine, get_engine)
+    ENGINE_NAMES, FusedTieredPallasEngine, UpdateEngine, get_engine)
 from repro.core.sgns import SGNSConfig
 from repro.data.pairs import build_noise_table, unigram_noise_probs
-from repro.kernels.sgns_fused import (
-    fused_negative_ids, sample_negatives_fused, sgns_fused_step)
+from repro.kernels.alias_draw import _as_seed, fused_negative_ids
 
 
 def _zipf_counts(V, seed=0):
@@ -49,6 +48,7 @@ def _params(cfg, seed=1):
 
 # ------------------------------------------------------------------ registry
 def test_registry_resolves_all_names():
+    assert ENGINE_NAMES == ("dense", "sparse", "pallas", "pallas_fused_tiered")
     for name in ENGINE_NAMES:
         eng = get_engine(name)
         assert isinstance(eng, UpdateEngine)
@@ -71,9 +71,9 @@ def test_registry_rejects_unknown():
 
 
 def test_fused_engine_is_alias_only():
-    assert FusedPallasEngine().table_kind == "alias"
+    assert FusedTieredPallasEngine().table_kind == "alias"
     with pytest.raises(ValueError, match="alias"):
-        get_engine("pallas_fused:cdf")
+        get_engine("pallas_fused_tiered:cdf")
 
 
 def test_engines_hashable_and_value_equal():
@@ -87,15 +87,15 @@ def test_engine_rejects_bad_dials_at_construction():
     """Shape-free dial errors surface at get_engine time with a clear
     message, not as a cryptic kernel failure mid-epoch."""
     with pytest.raises(ValueError, match="ring_depth >= 2"):
-        get_engine("pallas_fused_pipe", ring_depth=1)
+        get_engine("pallas_fused_tiered", ring_depth=1)
     with pytest.raises(ValueError, match="ring_depth >= 2"):
         get_engine("pallas_fused_tiered", ring_depth=0)
     with pytest.raises(ValueError, match="hot_rows >= 0"):
         get_engine("pallas_fused_tiered", hot_rows=-1)
     with pytest.raises(ValueError, match="block_pairs >= 1"):
-        get_engine("pallas_fused_hbm", block_pairs=0)
+        get_engine("pallas_fused_tiered", block_pairs=0)
     with pytest.raises(ValueError, match="block_pairs >= 1"):
-        get_engine("pallas_fused_pipe", block_pairs=-3)
+        get_engine("pallas_fused_tiered", block_pairs=-3)
 
 
 def test_trainer_rejects_hot_tier_larger_than_vocab(cfg):
@@ -136,27 +136,6 @@ def test_dense_sparse_pallas_steps_identical(cfg, batch, tables):
                                    atol=1e-5)
 
 
-def test_fused_step_matches_sparse_with_replayed_negatives(cfg, batch, tables):
-    """pallas_fused ≡ sparse when the sparse step is fed the exact ids
-    the kernel's counter PRNG drew (replayed via fused_negative_ids)."""
-    tabs, _ = tables
-    c, x = batch
-    key = jax.random.PRNGKey(11)
-    lr = jnp.float32(0.04)
-    p0 = _params(cfg)
-    pf, loss_f = sgns_fused_step(jax.tree.map(jnp.copy, p0), c, x,
-                                 tabs["alias"], key, lr,
-                                 negatives=cfg.negatives, interpret=True)
-    ids = fused_negative_ids(key.astype(jnp.uint32), tabs["alias"]["prob"],
-                             tabs["alias"]["alias"],
-                             (c.shape[0], cfg.negatives))
-    ps, loss_s = sgns.train_step_sparse(jax.tree.map(jnp.copy, p0), c, x,
-                                        ids, lr)
-    np.testing.assert_allclose(float(loss_f), float(loss_s), rtol=1e-5)
-    np.testing.assert_allclose(pf["W"], ps["W"], atol=1e-6)
-    np.testing.assert_allclose(pf["C"], ps["C"], atol=1e-6)
-
-
 def test_all_engines_converge_through_trainer(cfg, tables):
     """Whole-epoch equivalence up to sampling seed: every engine trains
     the same data to a loss below the (k+1)·log2 init plateau, and the
@@ -187,18 +166,25 @@ def test_all_engines_converge_through_trainer(cfg, tables):
     np.testing.assert_allclose(losses["sparse"], losses["dense"], rtol=1e-4)
     np.testing.assert_allclose(losses["pallas"], losses["dense"], rtol=1e-4)
     # fused draws its own negatives: same ballpark, not bitwise
-    assert abs(losses["pallas_fused"].mean() - losses["dense"].mean()) < 0.5
+    assert abs(losses["pallas_fused_tiered"].mean()
+               - losses["dense"].mean()) < 0.5
 
 
-# ------------------------------------------------- in-kernel negative draw
+# ----------------------------------------------- the fused engine's draw
+def _fused_draw(table, key, shape):
+    return fused_negative_ids(_as_seed(key), table["prob"], table["alias"],
+                              shape)
+
+
 def test_fused_draw_chi_square_matches_unigram_075(tables):
-    """Chi-square goodness-of-fit of the *in-kernel* draws (interpret
-    mode, via the standalone sampler kernel) against unigram^0.75."""
+    """Chi-square goodness-of-fit of the fused engine's counter-PRNG
+    draws (``fused_negative_ids``, what its planner replays) against
+    unigram^0.75."""
     tabs, counts = tables
     p = unigram_noise_probs(counts)
     N = 400_000
-    draws = np.asarray(sample_negatives_fused(
-        tabs["alias"], jax.random.PRNGKey(123), (N,), interpret=True))
+    draws = np.asarray(_fused_draw(tabs["alias"], jax.random.PRNGKey(123),
+                                   (N,)))
     assert draws.min() >= 0 and draws.max() < len(p)
     obs = np.bincount(draws, minlength=len(p)).astype(np.float64)
     exp = p * N
@@ -216,57 +202,53 @@ def test_fused_draw_chi_square_matches_unigram_075(tables):
 def test_fused_draw_deterministic_and_seed_sensitive(tables):
     tabs, _ = tables
     k1, k2 = jax.random.PRNGKey(0), jax.random.PRNGKey(1)
-    a = sample_negatives_fused(tabs["alias"], k1, (64, 5))
-    b = sample_negatives_fused(tabs["alias"], k1, (64, 5))
-    c = sample_negatives_fused(tabs["alias"], k2, (64, 5))
+    a = _fused_draw(tabs["alias"], k1, (64, 5))
+    b = _fused_draw(tabs["alias"], k1, (64, 5))
+    c = _fused_draw(tabs["alias"], k2, (64, 5))
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert not np.array_equal(np.asarray(a), np.asarray(c))
     assert a.dtype == jnp.int32
 
 
-def test_fused_draw_replay_matches_kernel(tables):
-    """The pure-jnp replay (fused_negative_ids) is bit-identical to the
-    in-kernel draw — the property the equivalence tests stand on."""
-    tabs, _ = tables
-    key = jax.random.PRNGKey(21)
-    in_kernel = sample_negatives_fused(tabs["alias"], key, (32, 7))
-    replay = fused_negative_ids(key.astype(jnp.uint32), tabs["alias"]["prob"],
-                                tabs["alias"]["alias"], (32, 7))
-    np.testing.assert_array_equal(np.asarray(in_kernel), np.asarray(replay))
-
-
 def test_fused_steps_draw_fresh_negatives_each_scan_step(cfg, tables):
     """Across an epoch scan the per-step key split must decorrelate the
-    in-kernel draws (a stuck counter/seed would reuse one negative set)."""
+    fused draws (a stuck counter/seed would reuse one negative set)."""
     tabs, _ = tables
-    ids = [np.asarray(fused_negative_ids(
-        jax.random.split(jax.random.PRNGKey(5), 3)[i].astype(jnp.uint32),
-        tabs["alias"]["prob"], tabs["alias"]["alias"], (16, 4)))
-        for i in range(3)]
+    ids = [np.asarray(_fused_draw(
+        tabs["alias"], jax.random.split(jax.random.PRNGKey(5), 3)[i],
+        (16, 4))) for i in range(3)]
     assert not np.array_equal(ids[0], ids[1])
     assert not np.array_equal(ids[1], ids[2])
 
 
 # --------------------------------------------------------- no collectives
-# Every registered engine × every sampler layout it supports. The single
-# source of truth for the paper's headline property — the per-engine
-# ad-hoc checks that used to live in test_system / test_alias are gone.
+# Every registered engine × every sampler layout it supports (and the
+# fused engine with and without its hot tier). The single source of
+# truth for the paper's headline property — the per-engine ad-hoc
+# checks that used to live in test_system / test_alias are gone.
 ASYNC_ENGINE_SPECS = (
     "dense:cdf", "dense:alias",
     "sparse:cdf", "sparse:alias",
     "pallas:cdf", "pallas:alias",
-    "pallas_fused:alias",            # fused engines sample in-kernel:
-    "pallas_fused_hbm:alias",        # alias is their only layout
-    "pallas_fused_pipe:alias",       # planner replays the same draw —
-                                     # sort/searchsorted, no collectives
-    "pallas_fused_tiered:alias",     # hot tier is per-worker-private:
+    "pallas_fused_tiered:alias@0",   # alias is its only layout; the
+                                     # planner replays the same draw —
+                                     # sort and compare, no collectives
+    "pallas_fused_tiered:alias@64",  # hot tier is per-worker-private:
                                      # no synchronization to add
 )
 
 
+def _async_engine(spec):
+    """``"name:sampler"``, or ``"name:sampler@hot_rows"`` for the fused
+    engine."""
+    spec, _, hot = spec.partition("@")
+    return get_engine(spec, hot_rows=int(hot)) if hot else get_engine(spec)
+
+
 def test_collective_spec_matrix_covers_registry():
     """A new engine registered without a row here must fail loudly."""
-    assert {s.split(":")[0] for s in ASYNC_ENGINE_SPECS} == set(ENGINE_NAMES)
+    assert {_async_engine(s).name for s in ASYNC_ENGINE_SPECS} \
+        == set(ENGINE_NAMES)
 
 
 @pytest.mark.parametrize("spec", ASYNC_ENGINE_SPECS)
@@ -276,10 +258,8 @@ def test_async_engine_epoch_is_collective_free(cfg, spec):
     structured op-walk over the lowered epoch + table-donation aliasing
     of the step) — no duplicated regexes in tests."""
     from repro.analysis.contracts import certify_engine_contracts
-    from repro.core.engine import get_engine
 
-    eng = get_engine(spec, hot_rows=64) \
-        if spec.startswith("pallas_fused_tiered") else get_engine(spec)
+    eng = _async_engine(spec)
     rep = certify_engine_contracts(
         eng, vocab_size=cfg.vocab_size, dim=cfg.dim,
         negatives=cfg.negatives, steps=4, batch=64)

@@ -1,12 +1,8 @@
-"""Pipelined HBM-blocked fused SGNS engine: block-planner invariants
-(hypothesis property tests on adversarial pair streams), the static
-pipeline schedule's ordering guarantees, and interpret-mode
-bit-equivalence of ``pallas_fused_pipe`` against the per-block sparse
-reference at a shape past the VMEM envelope (``slow`` marker, like the
-unpipelined engine's equivalence tests).
-
-The planner/schedule tests run entirely without Pallas — they are pure
-functions of the pair stream — so they live in the tier-1 gate.
+"""The fused kernel's pipeline, without Pallas: block-planner
+invariants (hypothesis property tests on adversarial pair streams, a
+plain NumPy construction of every index map) and the static pipeline
+schedule's ordering guarantees. The kernel's bit-equivalence against
+the per-block sparse reference lives in ``tests/test_fused_tiered.py``.
 """
 
 import jax
@@ -14,20 +10,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import sgns
-from repro.core.engine import (
-    FusedHBMPallasEngine, FusedPipePallasEngine, get_engine)
-from repro.core.sgns import SGNSConfig
-from repro.data.pairs import build_noise_table
-from repro.kernels.sgns_fused import fused_negative_ids
 from repro.kernels.sgns_fused_pipe import (
-    NUM_SLOTS, kernel_schedule, plan_blocks, resolve_schedule,
-    sgns_fused_pipe_step)
-
-# Past the VMEM-resident kernel's envelope, like tests/test_fused_hbm.py:
-# 2 tables × 34_000 × 64 × 4 B ≈ 17.4 MB > ~16 MB VMEM.
-V_BIG, D_BIG = 34_000, 64
-B, K = 64, 4
+    NUM_SLOTS, _pick_block_pairs, kernel_schedule, plan_blocks,
+    resolve_schedule)
 
 
 def _plan(centers, contexts, negs, V, blk, **kw):
@@ -41,6 +26,14 @@ def _np_plan(plan):
 
 
 # --------------------------------------------------------------- planner
+def test_pick_block_pairs_clamps_to_batch():
+    assert _pick_block_pairs(96, 256) == 96
+    assert _pick_block_pairs(96, 32) == 32
+    assert _pick_block_pairs(96, 50) == 50         # remainder → padded tail
+    assert _pick_block_pairs(97, 50) == 50         # prime batch: NOT 1
+    assert _pick_block_pairs(8, 0) == 1
+
+
 def test_planner_shapes_and_padding():
     rng = np.random.default_rng(0)
     V, blk, Bq, Kq = 50, 8, 19, 3          # 19 = 2 full blocks + tail 3
@@ -359,129 +352,3 @@ if HAS_HYPOTHESIS:
             st.lists(ids, min_size=Kq, max_size=Kq),
             min_size=Bq, max_size=Bq)), np.int32)
         _assert_planner_invariants(c, x, n, V, blk, ring_depth=rd)
-
-
-# ------------------------------------------------------------- equivalence
-@pytest.fixture(scope="module")
-def cfg():
-    return SGNSConfig(vocab_size=V_BIG, dim=D_BIG, negatives=K)
-
-
-@pytest.fixture(scope="module")
-def world(cfg):
-    rng = np.random.default_rng(0)
-    params = {
-        "W": jnp.asarray(0.01 * rng.normal(size=(V_BIG, D_BIG)), jnp.float32),
-        "C": jnp.asarray(0.01 * rng.normal(size=(V_BIG, D_BIG)), jnp.float32),
-    }
-    c = jnp.asarray(rng.integers(0, V_BIG, B, dtype=np.int32))
-    x = jnp.asarray(rng.integers(0, V_BIG, B, dtype=np.int32))
-    # duplicates within a block: dedup + in-VMEM accumulation must match
-    # the reference's duplicate-accumulating scatter-add bit for bit
-    c = c.at[1].set(c[0])
-    x = x.at[3].set(x[2])
-    counts = rng.zipf(1.3, V_BIG).astype(np.float64)
-    table = build_noise_table(counts, kind="alias")
-    return params, c, x, table
-
-
-def _sparse_blocked(params, c, x, ids, lr, blk):
-    step = jax.jit(sgns.train_step_sparse)
-    params = jax.tree.map(jnp.copy, params)
-    for b0 in range(0, c.shape[0], blk):
-        params, _ = step(params, c[b0:b0 + blk], x[b0:b0 + blk],
-                         ids[b0:b0 + blk], lr)
-    return params
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("blk,ring", [(16, 2), (40, 2), (16, 3)])
-def test_pipe_bit_identical_to_per_block_sparse(cfg, world, blk, ring):
-    """Past the VMEM envelope: the pipelined step ≡ the per-block sparse
-    reference on the replayed negatives, bit for bit — including when
-    the batch pads to a partial final block and at a deepened ring."""
-    params, c, x, table = world
-    key = jax.random.PRNGKey(11)
-    lr = jnp.float32(0.025)
-    ph, _ = sgns_fused_pipe_step(
-        jax.tree.map(jnp.copy, params), c, x, table, key, lr,
-        negatives=K, block_pairs=blk, ring_depth=ring, interpret=True)
-    ids = fused_negative_ids(key.astype(jnp.uint32), table["prob"],
-                             table["alias"], (B, K))
-    pr = _sparse_blocked(params, c, x, ids, lr, blk)
-    np.testing.assert_array_equal(np.asarray(ph["W"]), np.asarray(pr["W"]))
-    np.testing.assert_array_equal(np.asarray(ph["C"]), np.asarray(pr["C"]))
-
-
-@pytest.mark.slow
-def test_pipe_bit_identical_to_unpipelined_hbm_engine(cfg, world):
-    """pallas_fused_pipe ≡ pallas_fused_hbm at the engine level: the DMA
-    pipeline must not move a single bit relative to the serial chain."""
-    params, c, x, table = world
-    key = jax.random.PRNGKey(5)
-    kw = dict(block_pairs=16, interpret=True)
-    sp = get_engine("pallas_fused_pipe", **kw).make_step(cfg, 1000)
-    sh = get_engine("pallas_fused_hbm", **kw).make_step(cfg, 1000)
-    pp, lp = sp(jax.tree.map(jnp.copy, params), c, x, table, key, jnp.int32(2))
-    ph, lh = sh(jax.tree.map(jnp.copy, params), c, x, table, key, jnp.int32(2))
-    np.testing.assert_array_equal(np.asarray(pp["W"]), np.asarray(ph["W"]))
-    np.testing.assert_array_equal(np.asarray(pp["C"]), np.asarray(ph["C"]))
-    assert float(lp) == pytest.approx(float(lh), rel=1e-6)
-
-
-@pytest.mark.slow
-def test_pipe_sequential_falls_back_to_per_pair_oracle(cfg, world):
-    """sequential=True on the pipe engine runs the unpipelined per-pair
-    kernel — bit-identical to the hbm engine's sequential path."""
-    params, c, x, table = world
-    B2 = 16
-    key = jax.random.PRNGKey(23)
-    pe = get_engine("pallas_fused_pipe", block_pairs=8, sequential=True,
-                    interpret=True)
-    he = get_engine("pallas_fused_hbm", block_pairs=8, sequential=True,
-                    interpret=True)
-    pp, _ = pe.make_step(cfg, 1000)(jax.tree.map(jnp.copy, params),
-                                    c[:B2], x[:B2], table, key, jnp.int32(0))
-    ph, _ = he.make_step(cfg, 1000)(jax.tree.map(jnp.copy, params),
-                                    c[:B2], x[:B2], table, key, jnp.int32(0))
-    np.testing.assert_array_equal(np.asarray(pp["W"]), np.asarray(ph["W"]))
-    np.testing.assert_array_equal(np.asarray(pp["C"]), np.asarray(ph["C"]))
-
-
-# ------------------------------------------------------------ engine wiring
-def test_engine_fields_and_registry():
-    eng = get_engine("pallas_fused_pipe")
-    assert isinstance(eng, FusedPipePallasEngine)
-    assert isinstance(eng, FusedHBMPallasEngine)    # inherits hbm fields
-    assert eng.table_kind == "alias"
-    assert eng.block_pairs == 256 and eng.sequential is False
-    assert eng.ring_depth == 2
-    assert get_engine("pallas_fused_pipe", block_pairs=64).block_pairs == 64
-    assert get_engine("pallas_fused_pipe", ring_depth=3).ring_depth == 3
-    with pytest.raises(ValueError, match="alias"):
-        get_engine("pallas_fused_pipe:cdf")
-    with pytest.raises(ValueError, match="ring_depth"):
-        get_engine("pallas_fused_pipe", ring_depth=1)
-
-
-def test_trainer_epoch_trains_with_pipe_engine():
-    """AsyncShardTrainer (vmap backend, scan over steps) runs the
-    pipelined engine end to end and the loss drops below the init
-    plateau — the wiring the driver and CLIs sit on."""
-    from repro.core.async_trainer import AsyncShardTrainer
-
-    cfg = SGNSConfig(vocab_size=150, dim=32, negatives=4)
-    rng = np.random.default_rng(0)
-    n, S, Bt = 2, 12, 64
-    c = jnp.asarray(rng.integers(0, 30, (n, S, Bt)), jnp.int32)
-    x = jnp.asarray((np.asarray(c) + 1) % 30, jnp.int32)
-    counts = rng.zipf(1.3, cfg.vocab_size).astype(np.float64)
-    table = jax.tree.map(lambda a: jnp.stack([a, a]),
-                         build_noise_table(counts, kind="alias"))
-    tr = AsyncShardTrainer(cfg=cfg, num_workers=n, total_steps=S,
-                           engine=get_engine("pallas_fused_pipe",
-                                             block_pairs=16))
-    p = tr.init(jax.random.PRNGKey(0))
-    p, losses = tr.epoch(p, c, x, table, jax.random.PRNGKey(4))
-    assert np.isfinite(np.asarray(losses)).all()
-    assert float(losses[:, -1].mean()) < (cfg.negatives + 1) * np.log(2)

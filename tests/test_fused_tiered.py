@@ -1,13 +1,10 @@
-"""Frequency-tiered hot/cold fused SGNS engine: tier-routing planner
+"""The fused SGNS engine ``pallas_fused_tiered``: tier-routing planner
 invariants (every touched row served by exactly one tier, hot rows never
 in the DMA lists, cold-side dedup/hazard contract intact — unit +
 hypothesis property tests), engine wiring, and interpret-mode
-bit-equivalence of ``pallas_fused_tiered`` against the sparse reference
-and ``pallas_fused_hbm`` at a shape past the VMEM envelope, swept over
-hot fractions {0, small, all} (``slow`` marker).
-
-The planner tests run entirely without Pallas — they are pure functions
-of the pair stream — so they live in the tier-1 gate.
+bit-equivalence of the kernel against the per-block sparse reference at
+a shape past the VMEM envelope, over hot fractions {0, small, all},
+block sizes and ring depths.
 """
 
 import jax
@@ -17,20 +14,16 @@ import pytest
 
 from repro.core import sgns
 from repro.core.engine import (
-    FusedPipePallasEngine, FusedTieredPallasEngine, get_engine)
+    FusedTieredPallasEngine, UpdateEngine, get_engine)
 from repro.core.sgns import SGNSConfig
 from repro.data.pairs import build_noise_table
-from repro.kernels.sgns_fused import fused_negative_ids
-from repro.kernels.sgns_fused_pipe import plan_blocks, resolve_schedule
-from repro.kernels.sgns_fused_tiered import sgns_fused_tiered_step
+from repro.kernels.alias_draw import _as_seed, fused_negative_ids
+from repro.kernels.sgns_fused_pipe import (
+    fused_pipe_step, plan_blocks, resolve_schedule)
 
-# Past the VMEM-resident kernel's envelope, like tests/test_fused_pipe.py:
-# 2 tables × 34_000 × 64 × 4 B ≈ 17.4 MB > ~16 MB VMEM.
+# Past the VMEM envelope: 2 tables × 34_000 × 64 × 4 B ≈ 17.4 MB > ~16 MB.
 V_BIG, D_BIG = 34_000, 64
 B, K = 64, 4
-# hot fractions for the slow sweep: pure-pipe, a small non-aligned hot
-# set, and pure-resident (hot_rows covers the whole vocab)
-HOT_SWEEP = (0, 257, V_BIG)
 
 
 def _plan(centers, contexts, negs, V, blk, **kw):
@@ -210,12 +203,7 @@ if HAS_HYPOTHESIS:
 
 # ------------------------------------------------------------- equivalence
 @pytest.fixture(scope="module")
-def cfg():
-    return SGNSConfig(vocab_size=V_BIG, dim=D_BIG, negatives=K)
-
-
-@pytest.fixture(scope="module")
-def world(cfg):
+def world():
     rng = np.random.default_rng(0)
     params = {
         "W": jnp.asarray(0.01 * rng.normal(size=(V_BIG, D_BIG)), jnp.float32),
@@ -236,103 +224,78 @@ def world(cfg):
 
 
 def _sparse_blocked(params, c, x, ids, lr, blk):
+    """The reference: one jitted sparse step per pair block (a single
+    plain sparse step when one block covers the batch), and the batch
+    mean of the blocks' losses."""
     step = jax.jit(sgns.train_step_sparse)
     params = jax.tree.map(jnp.copy, params)
+    loss_sum = 0.0
     for b0 in range(0, c.shape[0], blk):
-        params, _ = step(params, c[b0:b0 + blk], x[b0:b0 + blk],
-                         ids[b0:b0 + blk], lr)
-    return params
+        params, loss = step(params, c[b0:b0 + blk], x[b0:b0 + blk],
+                            ids[b0:b0 + blk], lr)
+        loss_sum += float(loss) * c[b0:b0 + blk].shape[0]
+    return params, loss_sum / c.shape[0]
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("hot", HOT_SWEEP)
-def test_tiered_bit_identical_to_per_block_sparse(cfg, world, hot):
-    """Past the VMEM envelope: the tiered step ≡ the per-block sparse
+@pytest.mark.parametrize("hot,blk,ring", [
+    # a non-dividing block (64 = 40 + a padded tail of 24) at every hot
+    # fraction: pure DMA, a small non-aligned hot set, pure-resident
+    (0, 40, 2), (257, 40, 2), (V_BIG, 40, 2),
+    # four blocks, two ring depths: hazards and the deeper look-behind
+    (0, 16, 2), (0, 16, 3), (257, 16, 2), (257, 16, 3), (V_BIG, 16, 2),
+    # one block covering the whole batch
+    (257, B, 2),
+])
+def test_tiered_bit_identical_to_per_block_sparse(world, hot, blk, ring):
+    """Past the VMEM envelope: the kernel's step ≡ the per-block sparse
     reference on the replayed negatives, bit for bit, at every hot
-    fraction from pure-pipe to pure-resident."""
+    fraction from pure DMA to pure-resident, block size and ring
+    depth."""
     params, c, x, table = world
     key = jax.random.PRNGKey(11)
     lr = jnp.float32(0.025)
-    blk = 40                                   # non-dividing: padded tail
-    pt, _ = sgns_fused_tiered_step(
+    pt, loss = fused_pipe_step(
         jax.tree.map(jnp.copy, params), c, x, table, key, lr,
-        negatives=K, block_pairs=blk, hot_rows=hot, interpret=True)
-    ids = fused_negative_ids(key.astype(jnp.uint32), table["prob"],
-                             table["alias"], (B, K))
-    pr = _sparse_blocked(params, c, x, ids, lr, blk)
+        negatives=K, block_pairs=blk, ring_depth=ring, hot_rows=hot,
+        interpret=True)
+    ids = fused_negative_ids(_as_seed(key), table["prob"], table["alias"],
+                             (B, K))
+    pr, ref_loss = _sparse_blocked(params, c, x, ids, lr, blk)
     np.testing.assert_array_equal(np.asarray(pt["W"]), np.asarray(pr["W"]))
     np.testing.assert_array_equal(np.asarray(pt["C"]), np.asarray(pr["C"]))
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("hot,ring", [(0, 2), (257, 2), (257, 3),
-                                      (V_BIG, 2)])
-def test_tiered_bit_identical_to_unpipelined_hbm_engine(cfg, world, hot,
-                                                        ring):
-    """pallas_fused_tiered ≡ pallas_fused_hbm at the engine level: tier
-    routing and ring depth must not move a single bit relative to the
-    serial chain, at every hot fraction."""
-    params, c, x, table = world
-    key = jax.random.PRNGKey(5)
-    st_t = get_engine("pallas_fused_tiered", block_pairs=16, hot_rows=hot,
-                      ring_depth=ring, interpret=True).make_step(cfg, 1000)
-    st_h = get_engine("pallas_fused_hbm", block_pairs=16,
-                      interpret=True).make_step(cfg, 1000)
-    pt, lt = st_t(jax.tree.map(jnp.copy, params), c, x, table, key,
-                  jnp.int32(2))
-    ph, lh = st_h(jax.tree.map(jnp.copy, params), c, x, table, key,
-                  jnp.int32(2))
-    np.testing.assert_array_equal(np.asarray(pt["W"]), np.asarray(ph["W"]))
-    np.testing.assert_array_equal(np.asarray(pt["C"]), np.asarray(ph["C"]))
-    assert float(lt) == pytest.approx(float(lh), rel=1e-6)
+    assert float(loss) == pytest.approx(ref_loss, rel=1e-5)
 
 
 # ------------------------------------------------------------ engine wiring
 def test_engine_fields_and_registry():
     eng = get_engine("pallas_fused_tiered")
     assert isinstance(eng, FusedTieredPallasEngine)
-    assert isinstance(eng, FusedPipePallasEngine)   # inherits the pipeline
+    assert FusedTieredPallasEngine.__bases__ == (UpdateEngine,)
     assert eng.table_kind == "alias"
-    assert eng.hot_rows == 256 and eng.ring_depth == 2
+    assert eng.describe() == "pallas_fused_tiered:alias"
+    assert (eng.block_pairs, eng.ring_depth, eng.hot_rows) == (256, 2, 256)
+    assert not hasattr(eng, "sequential")
+    assert get_engine("pallas_fused_tiered", block_pairs=64).block_pairs == 64
     assert get_engine("pallas_fused_tiered", hot_rows=1024).hot_rows == 1024
+    assert get_engine("pallas_fused_tiered", hot_rows=0).hot_rows == 0
     assert get_engine("pallas_fused_tiered", ring_depth=4).ring_depth == 4
+    assert get_engine(eng, ring_depth=3).ring_depth == 3
     with pytest.raises(ValueError, match="alias"):
         get_engine("pallas_fused_tiered:cdf")
     with pytest.raises(ValueError, match="hot_rows"):
         get_engine("pallas_fused_tiered", hot_rows=-1)
     with pytest.raises(ValueError, match="ring_depth"):
         get_engine("pallas_fused_tiered", ring_depth=1)
+    with pytest.raises(ValueError, match="block_pairs"):
+        get_engine("pallas_fused_tiered", block_pairs=0)
 
 
-def test_tiered_sequential_falls_back_to_per_pair_oracle():
-    """sequential=True on the tiered engine runs the unpipelined
-    per-pair kernel — bit-identical to the hbm engine's sequential
-    path (tiers don't apply: per-pair order is inherently serial)."""
-    cfg = SGNSConfig(vocab_size=120, dim=16, negatives=3)
-    rng = np.random.default_rng(2)
-    params = {"W": jnp.asarray(0.01 * rng.normal(size=(120, 16)), jnp.float32),
-              "C": jnp.asarray(0.01 * rng.normal(size=(120, 16)), jnp.float32)}
-    c = jnp.asarray(rng.integers(0, 120, 16, dtype=np.int32))
-    x = jnp.asarray(rng.integers(0, 120, 16, dtype=np.int32))
-    table = build_noise_table(rng.zipf(1.3, 120).astype(np.float64),
-                              kind="alias")
-    key = jax.random.PRNGKey(23)
-    te = get_engine("pallas_fused_tiered", block_pairs=8, sequential=True,
-                    interpret=True)
-    he = get_engine("pallas_fused_hbm", block_pairs=8, sequential=True,
-                    interpret=True)
-    pt, _ = te.make_step(cfg, 1000)(jax.tree.map(jnp.copy, params),
-                                    c, x, table, key, jnp.int32(0))
-    ph, _ = he.make_step(cfg, 1000)(jax.tree.map(jnp.copy, params),
-                                    c, x, table, key, jnp.int32(0))
-    np.testing.assert_array_equal(np.asarray(pt["W"]), np.asarray(ph["W"]))
-    np.testing.assert_array_equal(np.asarray(pt["C"]), np.asarray(ph["C"]))
-
-
-def test_trainer_epoch_trains_with_tiered_engine():
-    """AsyncShardTrainer (vmap backend, scan over steps) runs the tiered
-    engine end to end and the loss drops below the init plateau — the
-    wiring the driver and CLIs sit on."""
+@pytest.mark.parametrize("hot_rows", [0, 8])
+def test_trainer_epoch_trains_with_tiered_engine(hot_rows):
+    """AsyncShardTrainer (vmap backend, scan over steps) runs the fused
+    engine end to end, without and with its hot tier, and the loss
+    drops below the init plateau — the wiring the driver and CLIs sit
+    on."""
     from repro.core.async_trainer import AsyncShardTrainer
 
     cfg = SGNSConfig(vocab_size=150, dim=32, negatives=4)
@@ -345,7 +308,8 @@ def test_trainer_epoch_trains_with_tiered_engine():
                          build_noise_table(counts, kind="alias"))
     tr = AsyncShardTrainer(cfg=cfg, num_workers=n, total_steps=S,
                            engine=get_engine("pallas_fused_tiered",
-                                             block_pairs=16, hot_rows=8))
+                                             block_pairs=16,
+                                             hot_rows=hot_rows))
     p = tr.init(jax.random.PRNGKey(0))
     p, losses = tr.epoch(p, c, x, table, jax.random.PRNGKey(4))
     assert np.isfinite(np.asarray(losses)).all()

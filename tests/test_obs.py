@@ -18,7 +18,7 @@ from repro.core.async_trainer import (AsyncShardTrainer, converting,
 from repro.core.engine import get_engine
 from repro.core.sgns import SGNSConfig
 from repro.data.pairs import build_noise_table
-from repro.kernels.sgns_fused import _as_seed, fused_negative_ids
+from repro.kernels.alias_draw import _as_seed, fused_negative_ids
 from repro.kernels.sgns_fused_pipe import (plan_blocks, plan_row_traffic,
                                            row_traffic)
 
@@ -103,14 +103,13 @@ def test_names_of_spans_are_the_programs_own():
 
 
 # ------------------------------------------------------------ the step's count
-@pytest.mark.parametrize("engine,hot_rows", [
-    ("pallas_fused_pipe", 0), ("pallas_fused_tiered", 24)])
-def test_rows_moved_is_the_plan_row_traffic_of_every_step(engine, hot_rows):
+@pytest.mark.parametrize("hot_rows", [0, 24])
+def test_rows_moved_is_the_plan_row_traffic_of_every_step(hot_rows):
     """``sgns.rows_moved`` over one chunk is the sum over its steps of
     ``plan_row_traffic`` recomputed from each step's own plan (the keys
     split as the epoch splits them)."""
-    eng = get_engine(engine, block_pairs=BLK, **(
-        {"hot_rows": hot_rows} if hot_rows else {}))
+    eng = get_engine("pallas_fused_tiered", block_pairs=BLK,
+                     hot_rows=hot_rows)
     tr = _trainer(eng)
     c, x, table = _chunk()
     key = jax.random.PRNGKey(7)
@@ -131,14 +130,13 @@ def test_rows_moved_is_the_plan_row_traffic_of_every_step(engine, hot_rows):
     assert obs.read(obs.BYTES_MOVED) == expect * 4 * D
 
 
-@pytest.mark.parametrize("engine,hot_rows", [
-    ("pallas_fused_pipe", 0), ("pallas_fused_tiered", 24)])
-def test_hot_touches_is_a_numpy_recount_of_every_step(engine, hot_rows):
+@pytest.mark.parametrize("hot_rows", [0, 24])
+def test_hot_touches_is_a_numpy_recount_of_every_step(hot_rows):
     """``sgns.hot_touches`` over one chunk is the count, in NumPy, of each
     step's centres, contexts and replayed negatives with id < hot_rows;
-    0 for the engine without a hot tier."""
-    eng = get_engine(engine, block_pairs=BLK, **(
-        {"hot_rows": hot_rows} if hot_rows else {}))
+    0 without a hot tier."""
+    eng = get_engine("pallas_fused_tiered", block_pairs=BLK,
+                     hot_rows=hot_rows)
     tr = _trainer(eng)
     c, x, table = _chunk(4)
     key = jax.random.PRNGKey(11)
@@ -166,15 +164,17 @@ def test_row_traffic_is_one_definition():
     assert jax.jit(row_traffic, static_argnums=1)(plan, 8) == by_hand
 
 
-@pytest.mark.parametrize("engine", [
-    "sparse:alias", "pallas_fused_pipe", "pallas_fused_tiered"])
-def test_epoch_returns_params_and_losses_of_the_steps(engine):
+@pytest.mark.parametrize("engine,hot_rows", [
+    ("sparse:alias", None), ("pallas_fused_tiered", 0),
+    ("pallas_fused_tiered", 256)])
+def test_epoch_returns_params_and_losses_of_the_steps(engine, hot_rows):
     """``epoch`` still returns ``(params, losses)``, bit-identical to the
     engine's own step scanned per worker under vmap (the epoch as it was
     before it counted rows); an engine without a DMA kernel counts no
     rows."""
-    eng = get_engine(engine, **({} if engine.startswith("sparse")
-                                else {"block_pairs": BLK}))
+    eng = get_engine(engine, **({} if hot_rows is None
+                                else {"block_pairs": BLK,
+                                      "hot_rows": hot_rows}))
     tr = _trainer(eng)
     c, x, table = _chunk(2)
     p0 = tr.init(jax.random.PRNGKey(0))
@@ -202,7 +202,7 @@ def test_epoch_returns_params_and_losses_of_the_steps(engine):
     rows = obs.read(obs.ROWS_MOVED)
     assert (rows == 0) == engine.startswith("sparse")
     # the hot-tier counter rides the same epoch, which stays bit-identical
-    assert (obs.read(obs.HOT_TOUCHES) > 0) == (engine == "pallas_fused_tiered")
+    assert (obs.read(obs.HOT_TOUCHES) > 0) == bool(hot_rows)
 
 
 # ------------------------------------------------ the tables across chunks

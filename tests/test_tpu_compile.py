@@ -83,11 +83,12 @@ def _compile_step(one_chip, w, hot_rows):
 
 
 @pytest.mark.parametrize("workload", ["paper", "zipf50k"])
-@pytest.mark.parametrize("engine", ["pallas_fused_pipe", "pallas_fused_tiered"])
-def test_hbm_kernel_step_compiles_for_v5e(one_chip, workload, engine):
+@pytest.mark.parametrize("hot_tier", [False, True])
+def test_hbm_kernel_step_compiles_for_v5e(one_chip, workload, hot_tier):
+    """The ``pallas_fused_tiered`` kernel at ``hot_rows = 0`` (pure DMA)
+    and at the workload's hot tier."""
     w = PAPER if workload == "paper" else ZIPF50K
-    hot = w["HOT"] if engine == "pallas_fused_tiered" else 0
-    compiled = _compile_step(one_chip, w, hot)
+    compiled = _compile_step(one_chip, w, w["HOT"] if hot_tier else 0)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 2 * w["V"] * w["D"] * 4
 
